@@ -611,12 +611,14 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = build_parser().parse_args(argv)
 
     if args.cmd == "daemon":
+        from . import compile_cache
         from .api.server import APIServer
         from .daemon import Daemon
         from .monitor.server import MonitorServer
         from .utils.logging import setup as logging_setup
 
         logging_setup(os.environ.get("CILIUM_TPU_LOG_LEVEL", "info"))
+        compile_cache.enable()
 
         daemon = Daemon(
             state_dir=args.state, conntrack=not args.no_conntrack,

@@ -22,12 +22,12 @@ users meets long before a poisoned device program:
   verdict the full path would also have denied.
 
 - ``Watchdog``: a monitor thread that bounds how long the daemon can
-  block on a wedged dispatch (r05's bench round died to exactly this).
+  block on a wedged dispatch.
   A batch whose completion pull exceeds ``dispatch_stall_ms`` is
   abandoned THROUGH the PR 6 quarantine — degraded result, CT-epoch
   bump, breaker accounting — and ``result()`` unblocks with a verdict
-  per flow. Registered external waits (attach, compile) ride the same
-  sweep via ``watching()``.
+  per flow. Registered external waits (compile) ride the same sweep
+  via ``watching()``.
 
 Both halves are deterministically injectable: ``SITE_QUEUE_FULL``
 forces the gate over budget, ``SITE_STALL`` fires a synthetic stall
@@ -290,8 +290,8 @@ class Watchdog:
       while the wedged XLA pull is left to die on its own thread.
       In-flight batches nobody is pulling are NOT stalls — lazy
       completion is the pipeline's normal shape.
-    - registered external waits (``watching(site)``): attach and
-      compile stalls ride the same sweep; one metric + breaker note
+    - registered external waits (``watching(site)``): compile
+      stalls ride the same sweep; one metric + breaker note
       per stalled op.
     - ``SITE_STALL`` injection: with the hub armed, every sweep probes
       the site, so a chaos round drives the whole detect → classify →
@@ -334,8 +334,8 @@ class Watchdog:
     # -- external waits ------------------------------------------------
     @contextmanager
     def watching(self, site: str):
-        """Register an external operation (attach handshake, policy
-        compile) for the sweep: if it outlives the stall budget it is
+        """Register an external operation (policy compile) for the
+        sweep: if it outlives the stall budget it is
         counted and classified like a stuck dispatch. The operation
         itself is not interrupted — the point is that the stall becomes
         VISIBLE (metric + breaker) instead of a silent hang."""
@@ -403,7 +403,7 @@ class Watchdog:
                     value = pipe._quarantine(inf)
                     inf.pending._value = value
                     inf.pending._event.set()
-        # registered external waits (attach / compile)
+        # registered external waits (compile)
         with self._lock:
             stuck = [
                 e for e in self._external.values()

@@ -3,7 +3,10 @@
 Reference: pkg/launcher (the generic restarting subprocess supervisor
 the agent uses for cilium-node-monitor, cilium-health and cilium-envoy)
 and pkg/envoy/envoy.go:121-143 (the restart loop: if the child exits
-while the agent is running, relaunch it after a pause)."""
+while the agent is running, relaunch it after a pause).
+
+Sidecars start with ``JAX_PLATFORMS=cpu``: a chip belongs to one
+process, and the agent holds it."""
 
 from __future__ import annotations
 
@@ -53,6 +56,10 @@ class ChildLauncher:
 
         env = dict(os.environ)
         env["CILIUM_TPU_PARENT_PID"] = str(os.getpid())
+        # one process per chip: the agent holds the accelerator, so a
+        # sidecar that touches JAX (the L7 proxy dispatches large
+        # request batches to a device) must stay on the host CPU
+        env["JAX_PLATFORMS"] = "cpu"
         # _lock guards the child Popen handle; _spawn runs only on
         # start and on crash-restart (rare), and racing spawns would
         # leak sidecars — accepted hold

@@ -1,7 +1,7 @@
 """Real interface plumbing + real packets through the enforcement
 front-end.
 
-Closes the 'virtual interface' gap (VERDICT r04 missing #7): the CNI
+Closes the 'virtual interface' gap: the CNI
 layer creates ACTUAL veth pairs into ACTUAL network namespaces
 (plugins/netns.py — the cilium-cni.go interface sequence), container
 processes send REAL UDP packets, and the wire front-end

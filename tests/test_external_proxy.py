@@ -223,6 +223,23 @@ class TestLauncher:
         finally:
             launcher.stop()
 
+    def test_sidecar_stays_off_the_accelerator(self, monkeypatch):
+        """The agent holds the chip; a sidecar opening it would fail
+        or hang, so every child is spawned pinned to the host CPU."""
+        from cilium_tpu.proxy import launcher as launcher_mod
+
+        seen = {}
+
+        def fake_popen(argv, **kw):
+            seen.update(kw["env"])
+            raise OSError("not spawned in this test")
+
+        monkeypatch.setattr(launcher_mod.subprocess, "Popen", fake_popen)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        with pytest.raises(OSError):
+            ProxyLauncher("/nonexistent.xds")._spawn()
+        assert seen["JAX_PLATFORMS"] == "cpu"
+
 
 class TestKafkaWire:
     def test_kafka_reject_and_upstream_relay(self, control_plane):

@@ -252,11 +252,12 @@ class TestClassifiedSites:
         with pytest.raises(_faults.PoisonedFault):
             store.pump()
 
-    def test_attach_site_unit(self):
-        _faults.hub.fail(_faults.SITE_ATTACH, _faults.KIND_TRANSIENT, 1)
+    @pytest.mark.parametrize("site", _faults.SITES)
+    def test_one_shot_fault_is_consumed(self, site):
+        _faults.hub.fail(site, _faults.KIND_TRANSIENT, 1)
         with pytest.raises(_faults.TransientFault):
-            _faults.hub.check(_faults.SITE_ATTACH)
-        _faults.hub.check(_faults.SITE_ATTACH)  # consumed → clean
+            _faults.hub.check(site)
+        _faults.hub.check(site)  # consumed → clean
 
     def test_programmer_error_still_raises_raw(self):
         """KIND_ERROR exceptions must pass through self-healing

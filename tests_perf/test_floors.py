@@ -1,6 +1,7 @@
-"""Order-of-magnitude perf floors over the bench's own building blocks
-(VERDICT r04 #7). Each floor sits ~5-10x under the BENCH_r04 in-world
-number, so real regressions fail here while environment jitter passes.
+"""Order-of-magnitude perf floors over the bench's own building blocks.
+Each floor sits ~5-10x under a chip number measured before PR 1 (that
+record is deleted and the number is not measured on this tree), so
+real regressions fail here while environment jitter passes.
 
 Device floors skip off-accelerator (the CPU backend is not the
 measured regime); host floors (Kafka ACL, native C++ front-end) run
