@@ -40,6 +40,11 @@ TRACE_PHASES: Tuple[str, ...] = (
     "ct_create",
     "counters",
     "emit_events",
+    # proxy-http traces (proxy/proxy.py check_http, l7/http_policy.py)
+    "encode",
+    "overlong",
+    "rule_match",
+    "access_log",
 )
 
 # -- drop reasons (monitor/events.py REASON_*) ------------------------

@@ -35,6 +35,7 @@ from .maps.proxymap import ProxyMap
 from .maps.routes import RouteTable
 from .maps.tunnel import TunnelMap
 from .mtu import MTUConfig
+from .observe import gcwatch as _gcwatch
 from .observe.flows import FlowRing
 from .utils.iputil import prefix_lengths_of
 from .utils.logging import get_logger
@@ -139,7 +140,14 @@ class Daemon:
 
         self.controllers = ControllerManager()
         self.endpoint_manager = EndpointManager(controllers=self.controllers)
-        self.proxy = Proxy()
+        # one tracer for the node: PhaseTracing covers the verdict
+        # pipeline, the L7 pipeline, the proxy's HTTP check and the CT
+        # GC timer; the GC hook counts collector pauses always and
+        # mirrors them as profiler spans while it is on
+        self.proxy = Proxy(tracer=self.pipeline.tracer)
+        if self.conntrack is not None:
+            self.conntrack.tracer = self.pipeline.tracer
+        _gcwatch.install(self.pipeline.tracer)
         if self.conntrack is not None and ct_gc_interval > 0:
             # periodic CT reaping (endpointmanager.EnableConntrackGC,
             # ctmap.go GC:345)
@@ -2042,6 +2050,7 @@ class Daemon:
         # degrades) everything in flight, persists CT + compiled +
         # state.json under the deadline
         self.drain(deadline_s=deadline_s)
+        _gcwatch.release(self.pipeline.tracer)
         self._stop_journal()
         self._stop_fleet_sampler()
         self.controllers.remove_all()

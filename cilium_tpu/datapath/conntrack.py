@@ -24,12 +24,14 @@ mirroring the kernel's forward/reverse tuple pair.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Optional, Tuple
 
 import numpy as np
 
+from .. import metrics as _metrics
 from ..maps.ctmap import DEFAULT_LIFETIME_OTHER, DEFAULT_LIFETIME_TCP
 
 CT_NEW = 0
@@ -37,6 +39,10 @@ CT_ESTABLISHED = 1
 CT_REPLY = 2
 
 _EMPTY = np.uint64(0xFFFFFFFFFFFFFFFF)
+
+# label sets of the search counters, built once
+_OP_LOOKUP = {"op": "lookup"}
+_OP_CREATE = {"op": "create"}
 
 
 def _mix64(x: np.ndarray) -> np.ndarray:
@@ -126,6 +132,11 @@ class FlowConntrack:
         # original VIP after backend→client translation.
         self.revnat = np.zeros(c, np.uint16)
         self.version = 0
+        # valid slots (live, or expired and not yet reaped): the
+        # cilium_tpu_conntrack_entries gauge, kept by every mutation
+        self._occupied = 0
+        # the daemon's Tracer: while tracing, gc() is a profiler span
+        self.tracer = None
 
     # ------------------------------------------------------------------
     def _hash(self, ka, kb, kc) -> np.ndarray:
@@ -142,7 +153,7 @@ class FlowConntrack:
                 & self.mask
             ).astype(np.int64)
 
-    def _find(self, ka, kb, kc, now: float) -> np.ndarray:
+    def _find(self, ka, kb, kc, now: float, labels=_OP_LOOKUP) -> np.ndarray:
         """[B] slot of a live exact match, or -1.
 
         Progressive narrowing: probe round p touches only flows still
@@ -151,12 +162,15 @@ class FlowConntrack:
         almost everything resolves in round 0, so the memory traffic is
         ~1.1 gathers per flow instead of P=16 — materializing the full
         [B, P] probe matrix made the CT pre-pass cost more than the
-        device dispatch it was meant to save."""
+        device dispatch it was meant to save. Counts the keys and the
+        slots probed (``labels`` names the caller's op)."""
         n = len(ka)
         h = self._hash(ka, kb, kc)
         out = np.full(n, -1, np.int64)
         pending = np.arange(n)
+        probed = 0
         for p in range(self.probes):
+            probed += pending.size
             with np.errstate(over="ignore"):
                 s = ((h[pending] + np.uint64(p)) & self.mask).astype(np.int64)
             kas = self.ka[s]
@@ -174,7 +188,13 @@ class FlowConntrack:
             pending = pending[cont]
             if pending.size == 0:
                 break
+        if n:
+            _metrics.ct_lookups_total.inc(labels, n)
+            _metrics.ct_probe_rounds_total.inc(labels, probed)
         return out
+
+    def _publish_occupancy(self) -> None:
+        _metrics.ct_entries.set(float(self._occupied))
 
     # ------------------------------------------------------------------
     def lookup_batch(
@@ -260,7 +280,7 @@ class FlowConntrack:
             )
             ka, kb, kc, revnat = ka[uidx], kb[uidx], kc[uidx], revnat[uidx]
             # skip keys already present (established)
-            have = self._find(ka, kb, kc, now) >= 0
+            have = self._find(ka, kb, kc, now, _OP_CREATE) >= 0
             ka, kb, kc, revnat = ka[~have], kb[~have], kc[~have], revnat[~have]
             if len(ka) == 0:
                 return 0
@@ -280,6 +300,9 @@ class FlowConntrack:
                 _, first = np.unique(cand[idx], return_index=True)
                 win = idx[first]
                 s = cand[win]
+                # an expired slot is reused in place: only empty or
+                # reaped slots add to the occupancy
+                self._occupied += int(np.count_nonzero(~self.valid[s]))
                 self.ka[s] = ka[win]
                 self.kb[s] = kb[win]
                 self.kc[s] = kc[win]
@@ -292,7 +315,13 @@ class FlowConntrack:
                 if placed.all():
                     break
             self.version += 1
-            return inserted
+            self._publish_occupancy()
+        _metrics.ct_inserts_total.inc({"result": "inserted"}, inserted)
+        if inserted < len(ka):
+            _metrics.ct_inserts_total.inc(
+                {"result": "dropped"}, len(ka) - inserted
+            )
+        return inserted
 
     # -- snapshot / restore (policyd-survive) --------------------------
     def snapshot_arrays(self) -> dict:
@@ -354,7 +383,7 @@ class FlowConntrack:
         ttl = np.minimum(ttl, max(self.tcp_lifetime, self.other_lifetime))
         kept = 0
         with self._lock:
-            have = self._find(ka, kb, kc, now) >= 0
+            have = self._find(ka, kb, kc, now, _OP_CREATE) >= 0
             kept += int(have.sum())
             ka, kb, kc, ttl = ka[~have], kb[~have], kc[~have], ttl[~have]
             packets, revnat = packets[~have], revnat[~have]
@@ -371,6 +400,7 @@ class FlowConntrack:
                 _, first = np.unique(cand[idx], return_index=True)
                 win = idx[first]
                 s = cand[win]
+                self._occupied += int(np.count_nonzero(~self.valid[s]))
                 self.ka[s] = ka[win]
                 self.kb[s] = kb[win]
                 self.kc[s] = kc[win]
@@ -384,6 +414,7 @@ class FlowConntrack:
             kept += int(placed.sum())
             expired += int((~placed).sum())
             self.version += 1
+            self._publish_occupancy()
         return kept, expired
 
     # -- maintenance ----------------------------------------------------
@@ -394,23 +425,32 @@ class FlowConntrack:
         chains at an EMPTY ka, so emptying a reclaimed slot would make
         live entries later in the same chain unreachable. Tombstoned
         slots stay reusable — create_batch's free test is
-        ``~valid | expired``, not ``ka == EMPTY``."""
-        now = time.monotonic()
-        with self._lock:
-            stale = self.valid & (self.expires <= now)
-            n = int(stale.sum())
-            if n:
-                self.valid[stale] = False
-                self.version += 1
-            # Tombstones accumulate forever (ka stays) and each one
-            # keeps probe chains alive past it — sustained churn would
-            # erode the early-termination win back to full-width
-            # probing. Past 25% occupancy by tombstones, rehash the
-            # live entries into fresh arrays.
-            tombstones = int(((self.ka != _EMPTY) & ~self.valid).sum())
-            if tombstones > self.capacity // 4:
-                self._compact(now)
-            return n
+        ``~valid | expired``, not ``ka == EMPTY``. While tracing, the
+        whole reap (compaction included) is the ``policyd.ct.gc``
+        profiler span."""
+        tr = self.tracer
+        with (
+            tr.annotate("policyd.ct.gc") if tr is not None
+            else contextlib.nullcontext()
+        ):
+            now = time.monotonic()
+            with self._lock:
+                stale = self.valid & (self.expires <= now)
+                n = int(stale.sum())
+                if n:
+                    self.valid[stale] = False
+                    self.version += 1
+                    self._occupied -= n
+                # Tombstones accumulate forever (ka stays) and each one
+                # keeps probe chains alive past it — sustained churn
+                # would erode the early-termination win back to
+                # full-width probing. Past 25% occupancy by tombstones,
+                # rehash the live entries into fresh arrays.
+                tombstones = int(((self.ka != _EMPTY) & ~self.valid).sum())
+                if tombstones > self.capacity // 4:
+                    self._compact(now)
+                self._publish_occupancy()
+                return n
 
     def _compact(self, now: float) -> None:
         """Rebuild the table from its live entries (caller holds the
@@ -447,6 +487,7 @@ class FlowConntrack:
             placed[win] = True
             if placed.all():
                 break
+        self._occupied = int(placed.sum())
         self.version += 1
 
     def flush(self) -> int:
@@ -455,6 +496,8 @@ class FlowConntrack:
             self.valid[:] = False
             self.ka[:] = _EMPTY
             self.version += 1
+            self._occupied = 0
+            self._publish_occupancy()
             return n
 
     def __len__(self) -> int:

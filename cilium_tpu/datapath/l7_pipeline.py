@@ -195,109 +195,112 @@ class L7Pipeline:
         tr = self.tracer
         bt = tr.begin("l7", len(fields[0][0])) if (tr is not None and tr.active) else NOOP_BATCH
 
-        with bt.phase("prepare"):
-            n_req = len(fields[0][0])
-            caps = [cap for _, cap in fields]
-            flat: List[bytes] = []
-            for values, _cap in fields:
-                if len(values) != n_req:
-                    raise ValueError("field batches must be the same length")
-                flat.extend(values)
-            # one rung covers every field; per-field caps re-mark
-            # overlong rows below
-            needed = 1
-            for s in flat:
-                if len(s) > needed:
-                    needed = len(s)
-            cap_max = max(caps)
-            rung = len_rung(min(needed, cap_max), cap_max)
-            sb, lens = strings_to_batch_u8(flat, rung)
-            for f, cap in enumerate(caps):
-                if cap < rung:
-                    seg = lens[f * n_req : (f + 1) * n_req]
-                    seg[seg > cap] = -1
-            starts = np.repeat(table.starts_host, n_req)
-            live = int(lens.size)
-            live_bytes = int(np.maximum(lens, 0).sum())
+        # the enqueue half ends at queue admission: finishing older
+        # batches past the depth bound belongs to THEIR complete halves
+        with bt.half("enqueue"):
+            with bt.phase("prepare"):
+                n_req = len(fields[0][0])
+                caps = [cap for _, cap in fields]
+                flat: List[bytes] = []
+                for values, _cap in fields:
+                    if len(values) != n_req:
+                        raise ValueError("field batches must be the same length")
+                    flat.extend(values)
+                # one rung covers every field; per-field caps re-mark
+                # overlong rows below
+                needed = 1
+                for s in flat:
+                    if len(s) > needed:
+                        needed = len(s)
+                cap_max = max(caps)
+                rung = len_rung(min(needed, cap_max), cap_max)
+                sb, lens = strings_to_batch_u8(flat, rung)
+                for f, cap in enumerate(caps):
+                    if cap < rung:
+                        seg = lens[f * n_req : (f + 1) * n_req]
+                        seg[seg > cap] = -1
+                starts = np.repeat(table.starts_host, n_req)
+                live = int(lens.size)
+                live_bytes = int(np.maximum(lens, 0).sum())
 
-        # policyd-prof: one attribute read while off; the sampled
-        # batch pays the explicit-upload / ready sandwiches below
-        prof = self.profiler
-        ps = prof.begin_dispatch("l7", n_req) if prof is not None else None
+            # policyd-prof: one attribute read while off; the sampled
+            # batch pays the explicit-upload / ready sandwiches below
+            prof = self.profiler
+            ps = prof.begin_dispatch("l7", n_req) if prof is not None else None
 
-        with bt.phase("dispatch"):
-            chunks = []
-            top = L7_LANE_RUNGS[-1]
-            pad_rows = 0
-            off = 0
-            n_chunks = 0
-            _pl_t0 = time.perf_counter() if ps is not None else 0.0
-            while off < live:
-                take = min(top, live - off)
-                lanes = lane_rung(take)
-                if take < lanes:
-                    csb = np.zeros((lanes, rung), np.uint8)
-                    csb[:take] = sb[off : off + take]
-                    clens = np.full(lanes, -1, np.int32)
-                    clens[:take] = lens[off : off + take]
-                    cstarts = np.zeros(lanes, np.int32)
-                    cstarts[:take] = starts[off : off + take]
-                    pad_rows += lanes - take
-                else:
-                    csb = sb[off : off + take]
-                    clens = lens[off : off + take]
-                    cstarts = starts[off : off + take]
+            with bt.phase("dispatch"):
+                chunks = []
+                top = L7_LANE_RUNGS[-1]
+                pad_rows = 0
+                off = 0
+                n_chunks = 0
+                _pl_t0 = time.perf_counter() if ps is not None else 0.0
+                while off < live:
+                    take = min(top, live - off)
+                    lanes = lane_rung(take)
+                    if take < lanes:
+                        csb = np.zeros((lanes, rung), np.uint8)
+                        csb[:take] = sb[off : off + take]
+                        clens = np.full(lanes, -1, np.int32)
+                        clens[:take] = lens[off : off + take]
+                        cstarts = np.zeros(lanes, np.int32)
+                        cstarts[:take] = starts[off : off + take]
+                        pad_rows += lanes - take
+                    else:
+                        csb = sb[off : off + take]
+                        clens = lens[off : off + take]
+                        cstarts = starts[off : off + take]
+                    if ps is not None:
+                        # sampled h2d edge: upload explicitly and wait so
+                        # the walk below starts from device-resident inputs
+                        # (jnp.asarray in _walk passes jax arrays through —
+                        # same avals, same compiled program). The per-chunk
+                        # sync IS the measurement, 1-in-N batches only:
+                        _t0 = time.perf_counter()
+                        csb, clens, cstarts = jax.block_until_ready(  # policyd-lint: disable=TPU002
+                            jax.device_put((csb, clens, cstarts))
+                        )
+                        ps.add_h2d(time.perf_counter() - _t0)
+                    kind = "pair" if table.has_pair else "fused"
+                    self._note_shape(kind, table.n_states, lanes, rung)
+                    lo, hi = self._walk(table, csb, clens, cstarts, rung)
+                    chunks.append((lo, hi, take))
+                    off += take
+                    n_chunks += 1
                 if ps is not None:
-                    # sampled h2d edge: upload explicitly and wait so
-                    # the walk below starts from device-resident inputs
-                    # (jnp.asarray in _walk passes jax arrays through —
-                    # same avals, same compiled program). The per-chunk
-                    # sync IS the measurement, 1-in-N batches only:
-                    _t0 = time.perf_counter()
-                    csb, clens, cstarts = jax.block_until_ready(  # policyd-lint: disable=TPU002
-                        jax.device_put((csb, clens, cstarts))
+                    # sampled compute edge: h2d already completed above, so
+                    # the rest of the chunk loop (lane padding, per-rung jit
+                    # dispatch) plus the residual wait here is the fused DFA
+                    # walk side of the split
+                    jax.block_until_ready([(c[0], c[1]) for c in chunks])
+                    ps.add_compute(
+                        time.perf_counter() - _pl_t0 - ps.h2d_s
                     )
-                    ps.add_h2d(time.perf_counter() - _t0)
-                kind = "pair" if table.has_pair else "fused"
-                self._note_shape(kind, table.n_states, lanes, rung)
-                lo, hi = self._walk(table, csb, clens, cstarts, rung)
-                chunks.append((lo, hi, take))
-                off += take
-                n_chunks += 1
-            if ps is not None:
-                # sampled compute edge: h2d already completed above, so
-                # the rest of the chunk loop (lane padding, per-rung jit
-                # dispatch) plus the residual wait here is the fused DFA
-                # walk side of the split
-                jax.block_until_ready([(c[0], c[1]) for c in chunks])
-                ps.add_compute(
-                    time.perf_counter() - _pl_t0 - ps.h2d_s
+                    ps.mark(
+                        rungs=[lane_rung(min(top, c[2])) for c in chunks],
+                        len_rung=int(rung),
+                        lanes=int(live),
+                        pad_lanes=int(pad_rows),
+                        chunks=n_chunks,
+                        parser=parser,
+                    )
+                metrics.l7_pad_lanes_total.inc({"kind": "lane"}, pad_rows)
+                metrics.l7_pad_lanes_total.inc({"kind": "lane_live"}, live)
+                metrics.l7_pad_lanes_total.inc(
+                    {"kind": "len_bytes"}, live * rung - live_bytes
                 )
-                ps.mark(
-                    rungs=[lane_rung(min(top, c[2])) for c in chunks],
-                    len_rung=int(rung),
-                    lanes=int(live),
-                    pad_lanes=int(pad_rows),
-                    chunks=n_chunks,
-                    parser=parser,
-                )
-            metrics.l7_pad_lanes_total.inc({"kind": "lane"}, pad_rows)
-            metrics.l7_pad_lanes_total.inc({"kind": "lane_live"}, live)
-            metrics.l7_pad_lanes_total.inc(
-                {"kind": "len_bytes"}, live * rung - live_bytes
-            )
-            metrics.l7_pad_lanes_total.inc({"kind": "len_bytes_live"}, live_bytes)
-            metrics.l7_batches_total.inc({"parser": parser})
+                metrics.l7_pad_lanes_total.inc({"kind": "len_bytes_live"}, live_bytes)
+                metrics.l7_batches_total.inc({"parser": parser})
 
-        pending = PendingL7Batch(self)
-        entry = _InFlight(pending, chunks, n_req, table.n_fields, bt, t0, ps)
-        if bt is not NOOP_BATCH:
-            tr.detach(bt)
-        overflow: List[_InFlight] = []
-        with self._lock:
-            self._inflight.append(entry)
-            while len(self._inflight) > self.depth:
-                overflow.append(self._inflight.popleft())
+            pending = PendingL7Batch(self)
+            entry = _InFlight(pending, chunks, n_req, table.n_fields, bt, t0, ps)
+            if bt is not NOOP_BATCH:
+                tr.detach(bt)
+            overflow: List[_InFlight] = []
+            with self._lock:
+                self._inflight.append(entry)
+                while len(self._inflight) > self.depth:
+                    overflow.append(self._inflight.popleft())
         for e in overflow:
             self._finish(e)
         return pending
@@ -313,10 +316,15 @@ class L7Pipeline:
 
     def _finish(self, entry: _InFlight) -> None:
         bt = entry.bt
+        with bt.half("complete"):
+            self._pull(entry)
+        bt.end()
+
+    def _pull(self, entry: _InFlight) -> None:
         ps = entry.ps
         _pt0 = time.perf_counter() if ps is not None else 0.0
         try:
-            with bt.phase("host_sync"):
+            with entry.bt.phase("host_sync"):
                 parts = []
                 for ch in entry.chunks:
                     lo64 = np.asarray(ch[0]).astype(np.uint64)
@@ -345,7 +353,6 @@ class L7Pipeline:
             entry.ps = None
         entry.pending._done = True
         metrics.l7_batch_seconds.observe(time.perf_counter() - entry.t0)
-        bt.end()
 
     def drain(self) -> None:
         while True:

@@ -193,27 +193,33 @@ def _v4_lpm_stage(t, peer_u32, prefilter: bool):
     deny+identity flat trie present and the prefilter stage active, ONE
     walk answers both questions (bpf_xdp.c check_filters + the ipcache
     secctx derivation in a single pass); otherwise the two classic
-    walks run (and the deny walk only when the stage is active)."""
+    walks run (and the deny walk only when the stage is active).
+
+    Device stages carry the named scopes ``lpm_v4`` and, for a separate
+    deny walk, ``prefilter`` (the fused walk answers both under
+    ``lpm_v4``): names in the profiler trace, not in the program."""
     fused = t.merged_sub_info.shape[-1] == 65536
-    if prefilter and fused:
-        packed = lpm_lookup_wide(
-            t.merged_root_info, t.merged_root_child, t.merged_sub_child,
-            t.merged_sub_info, peer_u32,
-        )
-        denied_pf = (packed & jnp.int32(DENY_BIT)) != 0
-        hit = packed & jnp.int32(MERGED_VALUE_MASK)
-        return denied_pf, hit
-    if prefilter:
-        denied_pf = lpm_lookup_wide(
-            t.pf_root_info, t.pf_root_child, t.pf_sub_child, t.pf_sub_info,
+    with jax.named_scope("lpm_v4"):
+        if prefilter and fused:
+            packed = lpm_lookup_wide(
+                t.merged_root_info, t.merged_root_child, t.merged_sub_child,
+                t.merged_sub_info, peer_u32,
+            )
+            denied_pf = (packed & jnp.int32(DENY_BIT)) != 0
+            hit = packed & jnp.int32(MERGED_VALUE_MASK)
+            return denied_pf, hit
+        if prefilter:
+            with jax.named_scope("prefilter"):
+                denied_pf = lpm_lookup_wide(
+                    t.pf_root_info, t.pf_root_child, t.pf_sub_child,
+                    t.pf_sub_info, peer_u32,
+                ) > 0
+        else:
+            denied_pf = jnp.zeros(peer_u32.shape[0], jnp.bool_)
+        hit = lpm_lookup_wide(
+            t.ip_root_info, t.ip_root_child, t.ip_sub_child, t.ip_sub_info,
             peer_u32,
-        ) > 0
-    else:
-        denied_pf = jnp.zeros(peer_u32.shape[0], jnp.bool_)
-    hit = lpm_lookup_wide(
-        t.ip_root_info, t.ip_root_child, t.ip_sub_child, t.ip_sub_info,
-        peer_u32,
-    )
+        )
     return denied_pf, hit
 
 
@@ -241,38 +247,47 @@ def _verdict_tail(
     for prefilter drops, which never reached the policymap), whether
     any L4 column covered the flow (the no-L4 vs no-L3 drop
     discriminator), and the on-device [R] rule-hit segment-sum —
-    pulled d2h only in the completion half, like the counters."""
-    if not attrib:
-        dec, red = lookup_batch(
-            policymap, ep_idx, peer_row, dport, proto, block=block,
-            ident_gather=ident_gather,
-        )
-    else:
-        dec, red, rule, l4x = lookup_batch(
-            policymap, ep_idx, peer_row, dport, proto, block=block,
-            attrib=True, rule_tab=rule_tab, ident_gather=ident_gather,
-        )
-    verdict = jnp.where(denied_pf, jnp.int8(DROP_PREFILTER), dec)
-    redirect = red & ~denied_pf
+    pulled d2h only in the completion half, like the counters.
 
-    # counters via one-hot matmul [B, EP]ᵀ @ [B, 3]
-    ep_oh = (ep_idx[:, None] == jnp.arange(ep_count)[None, :]).astype(jnp.int8)
-    cls = jnp.stack(
-        [verdict == FORWARD, verdict == DROP_POLICY, verdict == DROP_PREFILTER],
-        axis=1,
-    ).astype(jnp.int8)
-    counters = jax.lax.dot_general(
-        ep_oh, cls, (((0,), (0,)), ((), ())), preferred_element_type=jnp.int32
-    )
-    if not attrib:
-        return verdict, redirect, counters
-    rule = jnp.where(denied_pf, jnp.int32(-1), rule)
-    idx = jnp.clip(rule, 0, max(n_rules - 1, 0))
-    hits = (
-        jnp.zeros((max(n_rules, 1),), jnp.int32)
-        .at[idx]
-        .add((rule >= 0).astype(jnp.int32))
-    )
+    Named scopes ``policymap`` (lookup and prefilter override) and
+    ``counters`` (the matmul and the rule-hit sums)."""
+    with jax.named_scope("policymap"):
+        if not attrib:
+            dec, red = lookup_batch(
+                policymap, ep_idx, peer_row, dport, proto, block=block,
+                ident_gather=ident_gather,
+            )
+        else:
+            dec, red, rule, l4x = lookup_batch(
+                policymap, ep_idx, peer_row, dport, proto, block=block,
+                attrib=True, rule_tab=rule_tab, ident_gather=ident_gather,
+            )
+        verdict = jnp.where(denied_pf, jnp.int8(DROP_PREFILTER), dec)
+        redirect = red & ~denied_pf
+
+    with jax.named_scope("counters"):
+        # counters via one-hot matmul [B, EP]ᵀ @ [B, 3]
+        ep_oh = (
+            ep_idx[:, None] == jnp.arange(ep_count)[None, :]
+        ).astype(jnp.int8)
+        cls = jnp.stack(
+            [verdict == FORWARD, verdict == DROP_POLICY,
+             verdict == DROP_PREFILTER],
+            axis=1,
+        ).astype(jnp.int8)
+        counters = jax.lax.dot_general(
+            ep_oh, cls, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
+        if not attrib:
+            return verdict, redirect, counters
+        rule = jnp.where(denied_pf, jnp.int32(-1), rule)
+        idx = jnp.clip(rule, 0, max(n_rules - 1, 0))
+        hits = (
+            jnp.zeros((max(n_rules, 1),), jnp.int32)
+            .at[idx]
+            .add((rule >= 0).astype(jnp.int32))
+        )
     return verdict, redirect, counters, rule, l4x, hits
 
 
@@ -281,23 +296,28 @@ def _v6_lpm_stage(t, peer_bytes, levels: int, prefilter: bool, fused: bool):
     fused trie present and the deny stage active, ONE elided stride-8
     walk answers both questions; ``fused`` is a static flag because the
     stride-8 shapes can't disambiguate presence the way the flat
-    layout's 65536 width can."""
-    if prefilter and fused:
-        raw = _elided_lpm(
-            t.merged_child, t.merged_info, t.merged_common, peer_bytes,
-            levels,
+    layout's 65536 width can. Named scopes as in _v4_lpm_stage:
+    ``lpm_v6`` and ``prefilter``."""
+    with jax.named_scope("lpm_v6"):
+        if prefilter and fused:
+            raw = _elided_lpm(
+                t.merged_child, t.merged_info, t.merged_common, peer_bytes,
+                levels,
+            )
+            packed = jnp.where(raw > 0, raw - 1, 0)
+            denied_pf = (packed & jnp.int32(DENY_BIT)) != 0
+            hit = packed & jnp.int32(MERGED_VALUE_MASK)
+            return denied_pf, hit
+        if prefilter:
+            with jax.named_scope("prefilter"):
+                denied_pf = _elided_lpm(
+                    t.pf_child, t.pf_info, t.pf_common, peer_bytes, levels
+                ) > 0
+        else:
+            denied_pf = jnp.zeros(peer_bytes.shape[0], jnp.bool_)
+        hit = _elided_lpm(
+            t.ip_child, t.ip_info, t.ip_common, peer_bytes, levels
         )
-        packed = jnp.where(raw > 0, raw - 1, 0)
-        denied_pf = (packed & jnp.int32(DENY_BIT)) != 0
-        hit = packed & jnp.int32(MERGED_VALUE_MASK)
-        return denied_pf, hit
-    if prefilter:
-        denied_pf = _elided_lpm(
-            t.pf_child, t.pf_info, t.pf_common, peer_bytes, levels
-        ) > 0
-    else:
-        denied_pf = jnp.zeros(peer_bytes.shape[0], jnp.bool_)
-    hit = _elided_lpm(t.ip_child, t.ip_info, t.ip_common, peer_bytes, levels)
     return denied_pf, hit
 
 
@@ -3955,24 +3975,26 @@ class DatapathPipeline:
         adm = self._admission
         wd = self._watchdog
         try:
-            # the watchdog's stall clock starts when a thread ACTIVELY
-            # pulls this batch — un-pulled in-flight batches are the
-            # pipeline's normal lazy shape, not stalls
-            if wd is not None:
-                self._completing = (inf, time.monotonic())
-            # classified completion (policyd-failsafe): transient
-            # faults retry bounded, poisoned batches quarantine into a
-            # degraded RESULT, and only programmer errors come back as
-            # an exception for result() to surface raw
-            value, exc = self._finish_guarded(inf)
-            # publish under the queue lock, where the watchdog decides
-            # abandonment: a batch it already resolved degraded must
-            # not have its (late, possibly-poisoned) result overwrite
-            # the published one
-            with self._queue_lock:
-                if not inf.abandoned:
-                    inf.pending._value = value
-                    inf.pending._exc = exc
+            with inf.bt.half("complete"):
+                # the watchdog's stall clock starts when a thread
+                # ACTIVELY pulls this batch — un-pulled in-flight
+                # batches are the pipeline's normal lazy shape, not
+                # stalls
+                if wd is not None:
+                    self._completing = (inf, time.monotonic())
+                # classified completion (policyd-failsafe): transient
+                # faults retry bounded, poisoned batches quarantine into
+                # a degraded RESULT, and only programmer errors come
+                # back as an exception for result() to surface raw
+                value, exc = self._finish_guarded(inf)
+                # publish under the queue lock, where the watchdog
+                # decides abandonment: a batch it already resolved
+                # degraded must not have its (late, possibly-poisoned)
+                # result overwrite the published one
+                with self._queue_lock:
+                    if not inf.abandoned:
+                        inf.pending._value = value
+                        inf.pending._exc = exc
         finally:
             if wd is not None:
                 self._completing = None
@@ -4128,68 +4150,71 @@ class DatapathPipeline:
             )
         else:
             bt = _NOOP_BATCH
-        # classified enqueue (policyd-failsafe): a fault in the enqueue
-        # half (rebuild / h2d / async dispatch) retries bounded on
-        # transient, then resolves DEGRADED — the caller always gets a
-        # PendingBatch whose result() carries a verdict per flow.
-        # Programmer errors still raise raw (pre-failsafe contract).
-        attempt = 0
-        bo: Optional[Backoff] = None
-        while True:
-            try:
-                inf = self._submit_inner(
-                    peer_bytes, ep_idx, dports, protos, sports,
-                    ingress=ingress, family=family, peer_words=peer_words,
-                    want_rev_nat=want_rev_nat,
-                    tunnel_identities=tunnel_identities, bt=bt,
-                )
-                break
-            except BaseException as e:
-                kind = _faults.classify(e)
-                if kind == _faults.KIND_ERROR:
+        # the enqueue half ends at queue admission: completing older
+        # batches past the depth bound belongs to THEIR complete halves
+        with bt.half("enqueue"):
+            # classified enqueue (policyd-failsafe): a fault in the enqueue
+            # half (rebuild / h2d / async dispatch) retries bounded on
+            # transient, then resolves DEGRADED — the caller always gets a
+            # PendingBatch whose result() carries a verdict per flow.
+            # Programmer errors still raise raw (pre-failsafe contract).
+            attempt = 0
+            bo: Optional[Backoff] = None
+            while True:
+                try:
+                    inf = self._submit_inner(
+                        peer_bytes, ep_idx, dports, protos, sports,
+                        ingress=ingress, family=family, peer_words=peer_words,
+                        want_rev_nat=want_rev_nat,
+                        tunnel_identities=tunnel_identities, bt=bt,
+                    )
+                    break
+                except BaseException as e:
+                    kind = _faults.classify(e)
+                    if kind == _faults.KIND_ERROR:
+                        if bt is not _NOOP_BATCH:
+                            bt.end(self.monitor)
+                        raise
+                    self._note_fault(e, kind)
+                    if (
+                        kind == _faults.KIND_TRANSIENT
+                        and attempt < self.retry_limit
+                    ):
+                        attempt += 1
+                        if bo is None:
+                            bo = Backoff(
+                                min_s=self.retry_min_s, max_s=self.retry_max_s,
+                                jitter=False,
+                            )
+                        time.sleep(bo.duration())
+                        continue
                     if bt is not _NOOP_BATCH:
                         bt.end(self.monitor)
-                    raise
-                self._note_fault(e, kind)
-                if (
-                    kind == _faults.KIND_TRANSIENT
-                    and attempt < self.retry_limit
-                ):
-                    attempt += 1
-                    if bo is None:
-                        bo = Backoff(
-                            min_s=self.retry_min_s, max_s=self.retry_max_s,
-                            jitter=False,
-                        )
-                    time.sleep(bo.duration())
-                    continue
+                    pending = PendingBatch(self)
+                    shell = _InFlight(
+                        pending, None, bt,
+                        b=peer_bytes.shape[0], rev=want_rev_nat,
+                    )
+                    pending._value = self._quarantine(shell)
+                    pending._event.set()
+                    return pending
+            if bt is not _NOOP_BATCH:
+                tr.detach(bt)
+            if self._admission is not None or self._watchdog is not None:
+                inf.t0 = time.monotonic()
+            if inf.finish is None:
+                # ran synchronously (device-CT donated-state path)
                 if bt is not _NOOP_BATCH:
                     bt.end(self.monitor)
-                pending = PendingBatch(self)
-                shell = _InFlight(
-                    pending, None, bt,
-                    b=peer_bytes.shape[0], rev=want_rev_nat,
-                )
-                pending._value = self._quarantine(shell)
-                pending._event.set()
-                return pending
-        if bt is not _NOOP_BATCH:
-            tr.detach(bt)
-        if self._admission is not None or self._watchdog is not None:
-            inf.t0 = time.monotonic()
-        if inf.finish is None:
-            # ran synchronously (device-CT donated-state path)
-            if bt is not _NOOP_BATCH:
-                bt.end(self.monitor)
-            return inf.pending
-        with self._queue_lock:
-            self._inflight.append(inf)
-            if tuner is not None:
-                inf.enq_ns = time.perf_counter_ns() - t0
-                inf.occ = len(self._inflight)
-                inf.b = peer_bytes.shape[0]
-            _metrics.pipeline_inflight_depth.set(float(len(self._inflight)))
-            over = len(self._inflight) > self.pipeline_depth
+                return inf.pending
+            with self._queue_lock:
+                self._inflight.append(inf)
+                if tuner is not None:
+                    inf.enq_ns = time.perf_counter_ns() - t0
+                    inf.occ = len(self._inflight)
+                    inf.b = peer_bytes.shape[0]
+                _metrics.pipeline_inflight_depth.set(float(len(self._inflight)))
+                over = len(self._inflight) > self.pipeline_depth
         while over:
             self._complete_oldest()
             with self._queue_lock:

@@ -29,6 +29,7 @@ import numpy as np
 
 from .. import metrics
 from ..datapath import l7_pipeline as l7rt
+from ..observe import tracer as _tracer
 from ..ops.dfa import fuse_dfas, intern_fused_table, match_patterns
 from ..policy.api import HTTPRule
 from .regex_compile import (
@@ -283,35 +284,38 @@ class HTTPPolicy:
             if self._fused_table is None:
                 return None
         n = len(requests)
-        by_field = {
-            id(self._methods): [r.method for r in requests],
-            id(self._paths): [r.path for r in requests],
-            id(self._hosts): [r.host for r in requests],
-        }
-        encs = [
-            [v.encode() for v in by_field[id(ps)]]
-            for ps, _ in self._fused_fields
-        ]
+        bt = _tracer.current("proxy-http")
+        with bt.phase("encode"):
+            by_field = {
+                id(self._methods): [r.method for r in requests],
+                id(self._paths): [r.path for r in requests],
+                id(self._hosts): [r.host for r in requests],
+            }
+            encs = [
+                [v.encode() for v in by_field[id(ps)]]
+                for ps, _ in self._fused_fields
+            ]
         pending = pipe.submit(
             self._fused_table,
             [(e, cap) for e, (_, cap) in zip(encs, self._fused_fields)],
             parser="http",
         )
         raws = pending.result()
-        out = {}
-        for raw, enc, (ps, cap) in zip(raws, encs, self._fused_fields):
-            ps.correct_overlong(raw, enc, cap)
-            out[id(ps)] = ps.finish_masks(raw, by_field[id(ps)], n)
-        # fields without a device DFA (empty, or fully demoted) keep
-        # their host-only evaluation
-        masks = []
-        for ps, cap in (
-            (self._methods, 16),
-            (self._paths, self.max_len),
-            (self._hosts, self.max_len),
-        ):
-            got = out.get(id(ps))
-            masks.append(got if got is not None else ps.masks(by_field[id(ps)], cap))
+        with bt.phase("overlong"):
+            out = {}
+            for raw, enc, (ps, cap) in zip(raws, encs, self._fused_fields):
+                ps.correct_overlong(raw, enc, cap)
+                out[id(ps)] = ps.finish_masks(raw, by_field[id(ps)], n)
+            # fields without a device DFA (empty, or fully demoted) keep
+            # their host-only evaluation
+            masks = []
+            for ps, cap in (
+                (self._methods, 16),
+                (self._paths, self.max_len),
+                (self._hosts, self.max_len),
+            ):
+                got = out.get(id(ps))
+                masks.append(got if got is not None else ps.masks(by_field[id(ps)], cap))
         return tuple(masks)
 
     def __len__(self) -> int:
@@ -333,26 +337,27 @@ class HTTPPolicy:
             p_mask = self._paths.masks([r.path for r in requests], self.max_len)
             h_mask = self._hosts.masks([r.host for r in requests], self.max_len)
         out = np.zeros(n, bool)
-        for i, req in enumerate(requests):
-            for cr in self._rules:
-                if cr.allowed_identities is not None and req.src_identity not in cr.allowed_identities:
-                    continue
-                if cr.method_pid >= 0 and not (int(m_mask[i]) >> cr.method_pid) & 1:
-                    continue
-                if cr.path_pid >= 0 and not (int(p_mask[i]) >> cr.path_pid) & 1:
-                    continue
-                if cr.host_pid >= 0 and not (int(h_mask[i]) >> cr.host_pid) & 1:
-                    continue
-                if cr.rule.headers:
-                    hd = req.header_dict()
-                    if not all(
-                        (lambda name, want: (got := hd.get(name.strip().lower())) is not None
-                         and (not want or got.strip() == want.strip()))(*h.partition(":")[::2])
-                        for h in cr.rule.headers
-                    ):
+        with _tracer.current("proxy-http").phase("rule_match"):
+            for i, req in enumerate(requests):
+                for cr in self._rules:
+                    if cr.allowed_identities is not None and req.src_identity not in cr.allowed_identities:
                         continue
-                out[i] = True
-                break
+                    if cr.method_pid >= 0 and not (int(m_mask[i]) >> cr.method_pid) & 1:
+                        continue
+                    if cr.path_pid >= 0 and not (int(p_mask[i]) >> cr.path_pid) & 1:
+                        continue
+                    if cr.host_pid >= 0 and not (int(h_mask[i]) >> cr.host_pid) & 1:
+                        continue
+                    if cr.rule.headers:
+                        hd = req.header_dict()
+                        if not all(
+                            (lambda name, want: (got := hd.get(name.strip().lower())) is not None
+                             and (not want or got.strip() == want.strip()))(*h.partition(":")[::2])
+                            for h in cr.rule.headers
+                        ):
+                            continue
+                    out[i] = True
+                    break
         return out
 
     def check(self, request: HTTPRequest) -> bool:

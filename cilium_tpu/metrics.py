@@ -56,11 +56,9 @@ class Counter:
             f"# HELP {self.name} {self.help}",
             f"# TYPE {self.name} {self._TYPE}",
         ]
-        # snapshot under the lock: a concurrent inc() on a fresh label
-        # set would otherwise mutate the dict mid-iteration
-        with self._lock:
-            items = sorted(self._values.items())
-        for k, v in items:
+        # series() snapshots under the lock: a concurrent inc() on a
+        # fresh label set would otherwise mutate the dict mid-iteration
+        for k, v in sorted(self.series().items()):
             out.append(f"{self.name}{_fmt_labels(k)} {v}")
         return out
 
@@ -71,6 +69,30 @@ class Gauge(Counter):
     def set(self, value: float, labels: Optional[Dict[str, str]] = None) -> None:
         with self._lock:
             self._values[_labels_key(labels)] = value
+
+
+class SlotCounter(Counter):
+    """A counter over the fixed values of one label, each a cell of the
+    plain list ``slots``, which its one writer adds into with no lock.
+
+    For a writer that may take none: a ``gc.callbacks`` hook runs at
+    whatever allocation starts a collection, and that can be inside
+    another counter's locked ``series()`` copy on the same thread,
+    where taking that lock again would deadlock the thread."""
+
+    def __init__(self, name: str, help_: str, label: str, values: Sequence[str]) -> None:
+        super().__init__(name, help_)
+        self._keys: Tuple[_LabelKey, ...] = tuple(((label, v),) for v in values)
+        self.slots: List[float] = [0.0] * len(self._keys)
+
+    def inc(self, labels: Optional[Dict[str, str]] = None, value: float = 1.0) -> None:
+        self.slots[self._keys.index(_labels_key(labels))] += value
+
+    def get(self, labels: Optional[Dict[str, str]] = None) -> float:
+        return self.series().get(_labels_key(labels), 0.0)
+
+    def series(self) -> Dict[_LabelKey, float]:
+        return dict(zip(self._keys, self.slots))
 
 
 class _HistSeries:
@@ -177,7 +199,10 @@ class Registry:
         self._lock = threading.Lock()
         self._metrics: Dict[str, object] = {}
 
-    def counter(self, name: str, help_: str = "") -> Counter:
+    def counter(self, name: str, help_: str = "", slots=None) -> Counter:
+        """``slots=(label, values)`` gives a lock-free SlotCounter."""
+        if slots is not None:
+            return self._get(name, lambda: SlotCounter(name, help_, *slots))
         return self._get(name, lambda: Counter(name, help_))
 
     def gauge(self, name: str, help_: str = "") -> Gauge:
@@ -560,4 +585,46 @@ journal_frames_total = registry.counter(
     "Journal tail frame outcomes on the federation exchange (label "
     "result: published | publish_error | rejected | stale — same "
     "vocabulary as telemetry_frames_total)",
+)
+
+# -- conntrack (datapath/conntrack.py) families ---------------------------
+# Always on: one inc per table call, from numbers the call already holds.
+ct_lookups_total = registry.counter(
+    "cilium_tpu_conntrack_lookups_total",
+    "Keys searched in the flow conntrack table (label op: lookup = the "
+    "CT pre-pass, forward and reply tuples each counting; create = the "
+    "already-present check before an insert)",
+)
+ct_probe_rounds_total = registry.counter(
+    "cilium_tpu_conntrack_probe_rounds_total",
+    "Slots probed by those searches (label op, as lookups_total): "
+    "divide by lookups_total for the mean probe chain; 16 is the cap, "
+    "reached when the table is full",
+)
+ct_inserts_total = registry.counter(
+    "cilium_tpu_conntrack_inserts_total",
+    "New conntrack entries by outcome (label result: inserted | dropped "
+    "= a new, unique key found no free slot among its probes; the flow "
+    "re-verdicts on its next batch)",
+)
+ct_entries = registry.gauge(
+    "cilium_tpu_conntrack_entries",
+    "Occupied conntrack slots: live entries plus expired ones the GC "
+    "has not reaped yet (the kernel map's view); kept current by "
+    "inserts, GC and flushes, never by a pass over the table",
+)
+
+# -- host runtime (observe/gcwatch.py) families ----------------------------
+# Slot counters: the collector's callback adds into them with no lock.
+GC_GENERATIONS = ("generation", ("0", "1", "2"))
+gc_pause_seconds_total = registry.counter(
+    "cilium_tpu_gc_pause_seconds_total",
+    "Wall time the Python garbage collector stopped the process "
+    "(label generation: 0|1|2); always on",
+    slots=GC_GENERATIONS,
+)
+gc_collections_total = registry.counter(
+    "cilium_tpu_gc_collections_total",
+    "Python garbage collections (label generation: 0|1|2); always on",
+    slots=GC_GENERATIONS,
 )

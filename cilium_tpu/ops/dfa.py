@@ -244,16 +244,20 @@ def dfa_match_batch_fused(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Single-byte walk with PER-ROW start states: one dispatch
     classifies every field of the whole batch against its own
-    sub-automaton of the stacked table."""
-    flat = trans.reshape(-1)
+    sub-automaton of the stacked table. Named scopes label the device
+    ops: ``table_flatten``, ``dfa_walk`` (the loop) and ``dfa_step``."""
+    with jax.named_scope("table_flatten"):
+        flat = trans.reshape(-1)
     state = starts
 
     def step(lvl, state):
-        byte = str_bytes[:, lvl].astype(jnp.int32)
-        nxt = jnp.take(flat, state * 256 + byte)
-        return jnp.where(lvl < lengths, nxt, state)
+        with jax.named_scope("dfa_step"):
+            byte = str_bytes[:, lvl].astype(jnp.int32)
+            nxt = jnp.take(flat, state * 256 + byte)
+            return jnp.where(lvl < lengths, nxt, state)
 
-    state = jax.lax.fori_loop(0, max_len, step, state)
+    with jax.named_scope("dfa_walk"):
+        state = jax.lax.fori_loop(0, max_len, step, state)
     ok = lengths >= 0
     lo = jnp.where(ok, jnp.take(accept_lo, state), jnp.uint32(0))
     hi = jnp.where(ok, jnp.take(accept_hi, state), jnp.uint32(0))
@@ -273,18 +277,22 @@ def dfa_match_batch_pair(
     """Stride-2 walk: ceil(max_len/2) chained gathers instead of
     max_len. Tail bytes past the string length are substituted with the
     identity symbol IN-KERNEL, so the packed buffers stay 0-padded and
-    no post-step select is needed."""
-    flat = pair.reshape(-1)
+    no post-step select is needed. Named scopes label the device ops:
+    ``table_flatten``, ``dfa_walk`` (the loop) and ``dfa_step``."""
+    with jax.named_scope("table_flatten"):
+        flat = pair.reshape(-1)
     state = starts
     pad = jnp.int32(PAIR_PAD)
 
     def step(i, state):
-        lvl = 2 * i
-        b0 = jnp.where(lvl < lengths, str_bytes[:, lvl].astype(jnp.int32), pad)
-        b1 = jnp.where(lvl + 1 < lengths, str_bytes[:, lvl + 1].astype(jnp.int32), pad)
-        return jnp.take(flat, (state * PAIR_ALPHA + b0) * PAIR_ALPHA + b1)
+        with jax.named_scope("dfa_step"):
+            lvl = 2 * i
+            b0 = jnp.where(lvl < lengths, str_bytes[:, lvl].astype(jnp.int32), pad)
+            b1 = jnp.where(lvl + 1 < lengths, str_bytes[:, lvl + 1].astype(jnp.int32), pad)
+            return jnp.take(flat, (state * PAIR_ALPHA + b0) * PAIR_ALPHA + b1)
 
-    state = jax.lax.fori_loop(0, (max_len + 1) // 2, step, state)
+    with jax.named_scope("dfa_walk"):
+        state = jax.lax.fori_loop(0, (max_len + 1) // 2, step, state)
     ok = lengths >= 0
     lo = jnp.where(ok, jnp.take(accept_lo, state), jnp.uint32(0))
     hi = jnp.where(ok, jnp.take(accept_hi, state), jnp.uint32(0))

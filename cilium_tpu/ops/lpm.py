@@ -360,14 +360,19 @@ def lpm_lookup_wide(
         lo = (q & 0xFFFF).astype(jnp.int32)
         best = jnp.take(root_info, hi)
         node = jnp.take(root_child, hi)
-        v1 = jnp.take(sub_info.reshape(-1), node * 65536 + lo)
+        # named scope: a whole-table relayout shows under this name in
+        # the profiler trace
+        with jax.named_scope("table_flatten"):
+            flat_i = sub_info.reshape(-1)
+        v1 = jnp.take(flat_i, node * 65536 + lo)
         return jnp.where((node > 0) & (v1 > 0), v1, best)
     b2 = ((q >> 8) & 0xFF).astype(jnp.int32)
     b3 = (q & 0xFF).astype(jnp.int32)
     best = jnp.take(root_info, hi)
     node = jnp.take(root_child, hi)
-    flat_c = sub_child.reshape(-1)
-    flat_i = sub_info.reshape(-1)
+    with jax.named_scope("table_flatten"):
+        flat_c = sub_child.reshape(-1)
+        flat_i = sub_info.reshape(-1)
     idx1 = node * 256 + b2
     v1 = jnp.take(flat_i, idx1)
     n1 = jnp.take(flat_c, idx1)

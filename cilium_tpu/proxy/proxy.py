@@ -17,6 +17,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from ..l7.http_policy import HTTPPolicy, HTTPRequest
 from ..l7.kafka_policy import KafkaACL, KafkaRequest
+from ..observe.tracer import NOOP_BATCH, Tracer
 from ..option import get_config
 from .accesslog import (
     AccessLogServer,
@@ -57,7 +58,14 @@ class Redirect:
 
 
 class Proxy:
-    def __init__(self, accesslog: Optional[AccessLogServer] = None) -> None:
+    def __init__(
+        self,
+        accesslog: Optional[AccessLogServer] = None,
+        tracer: Optional[Tracer] = None,
+    ) -> None:
+        # the daemon passes its pipeline's tracer: while PhaseTracing is
+        # on each check_http batch is a ``proxy-http`` trace
+        self.tracer = tracer
         cfg = get_config()
         self._port_min = cfg.proxy_port_min
         self._port_max = cfg.proxy_port_max
@@ -150,26 +158,40 @@ class Proxy:
     # -- enforcement hooks ----------------------------------------------
     def check_http(self, redirect: Redirect, requests: Sequence[HTTPRequest]):
         """Batch HTTP enforcement + access logging → [B] bool allow
-        (the cilium.l7policy decodeHeaders role)."""
-        pol = redirect.http_policy
-        allows = (
-            pol.check_batch(requests)
-            if pol is not None
-            else [True] * len(requests)
+        (the cilium.l7policy decodeHeaders role). While tracing, the
+        batch is a ``proxy-http`` trace: ``encode``, ``overlong`` and
+        ``rule_match`` come from the policy's check_batch, then
+        ``access_log``."""
+        tr = self.tracer
+        bt = (
+            tr.begin("proxy-http", len(requests))
+            if tr is not None and tr.active
+            else NOOP_BATCH
         )
-        for req, ok in zip(requests, allows):
-            self.accesslog.log(
-                LogRecord(
-                    type=TYPE_REQUEST,
-                    verdict=VERDICT_FORWARDED if ok else VERDICT_DENIED,
-                    timestamp=time.time(),
-                    src_identity=req.src_identity,
-                    dst_port=redirect.dst_port,
-                    proto="http",
-                    http={"method": req.method, "path": req.path, "host": req.host,
-                          "code": 200 if ok else 403},
-                )
+        try:
+            pol = redirect.http_policy
+            allows = (
+                pol.check_batch(requests)
+                if pol is not None
+                else [True] * len(requests)
             )
+            with bt.phase("access_log"):
+                for req, ok in zip(requests, allows):
+                    self.accesslog.log(
+                        LogRecord(
+                            type=TYPE_REQUEST,
+                            verdict=VERDICT_FORWARDED if ok else VERDICT_DENIED,
+                            timestamp=time.time(),
+                            src_identity=req.src_identity,
+                            dst_port=redirect.dst_port,
+                            proto="http",
+                            http={"method": req.method, "path": req.path,
+                                  "host": req.host,
+                                  "code": 200 if ok else 403},
+                        )
+                    )
+        finally:
+            bt.end()
         return allows
 
     def handle_kafka_bytes(

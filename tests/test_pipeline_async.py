@@ -19,6 +19,7 @@ from __graft_entry__ import _build_datapath_world, _make_ip_flows
 
 from cilium_tpu.datapath.conntrack import FlowConntrack
 from cilium_tpu.datapath.pipeline import DatapathPipeline
+from cilium_tpu.observe import tracer as tracer_mod
 
 
 def _batches(idents, k: int, b: int, seed0: int):
@@ -224,7 +225,7 @@ class TestTracesUnderOverlap:
         pipe.tracer.disable()
         # TLS span stack must end clean (current() falls back to the
         # no-op singleton only when nothing is left open)
-        assert not getattr(pipe.tracer._tls, "stack", None)
+        assert not getattr(tracer_mod._TLS, "stack", None)
 
         t1, t2 = pipe.tracer.traces(2)  # oldest→newest = completion order
         assert t1["batch"] == 200 and t2["batch"] == 100
