@@ -14,6 +14,7 @@ State persistence: rules/endpoints/ipcache snapshot to a state dir
 
 from __future__ import annotations
 
+import ipaddress
 import json
 import os
 import tempfile
@@ -21,6 +22,8 @@ import threading
 import time
 from dataclasses import asdict as dataclasses_asdict
 from typing import Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from . import metrics
 from .datapath.conntrack import FlowConntrack
@@ -173,7 +176,7 @@ class Daemon:
         # datapath redirect verdicts → proxymap entries (the
         # cilium_proxy4/6 write of bpf_lxc.c; the L7 front-end reads
         # them back to recover original destination + source identity)
-        self.pipeline.on_redirect = self._record_proxy_flow
+        self.pipeline.on_redirect_batch = self._record_proxy_flows
         # per-endpoint option resolution for event gating (`cilium
         # endpoint config` overrides, layered over the daemon map)
         self.pipeline.endpoint_options = self._endpoint_option
@@ -745,40 +748,47 @@ class Daemon:
             return default
         return ep.options.get(name)  # inherits the daemon map
 
-    def _record_proxy_flow(
-        self, peer_addr: bytes, ep_idx: int, sport: int, dport: int,
-        proto: int, ingress: bool, family: int,
+    def _record_proxy_flows(
+        self, peer_bytes: np.ndarray, ep_idx: np.ndarray,
+        sports: np.ndarray, dports: np.ndarray, protos: np.ndarray,
+        ingress: bool, family: int,
     ) -> None:
-        """bpf_lxc.c proxymap insert on redirect verdicts: key the
-        redirected 5-tuple to its ORIGINAL destination + source
-        identity so the L7 front-end (envoy/cilium_bpf_metadata.cc
-        read side) knows where the connection was headed and who sent
-        it."""
-        import ipaddress as _ipa
-
-        from .maps.proxymap import ProxyValue
-
-        ep_id = self.pipeline.endpoint_id_at(ep_idx)
-        ep = self.endpoint_manager.lookup(ep_id) if ep_id is not None else None
-        ep_ip = (ep.ipv4 if family == 4 else ep.ipv6) if ep else None
-        peer_ip = str(_ipa.ip_address(peer_addr))
-        entry = self.ipcache.lookup_by_ip(peer_ip)
+        """bpf_lxc.c proxymap insert on redirect verdicts, for one
+        batch's redirected flows: key each 5-tuple to its ORIGINAL
+        destination + source identity so the L7 front-end
+        (envoy/cilium_bpf_metadata.cc read side) knows where the
+        connection was headed and who sent it. Each distinct endpoint
+        and peer of the batch is resolved once."""
+        eps, ep_at = np.unique(ep_idx, return_inverse=True)
+        ep_ips, ep_idents = [], []
+        for i in eps.tolist():
+            ep_id = self.pipeline.endpoint_id_at(i)
+            ep = self.endpoint_manager.lookup(ep_id) if ep_id is not None else None
+            ep_ips.append(((ep.ipv4 if family == 4 else ep.ipv6) if ep else None) or "")
+            ep_idents.append(ep.identity.id if ep and ep.identity else 0)
+        rows = (np.asarray(peer_bytes) & 0xFF).astype(np.uint8)
+        peers, peer_at = np.unique(rows, axis=0, return_inverse=True)
+        addrs = [ipaddress.ip_address(bytes(p)) for p in peers]
+        peer_ips = [str(a) for a in addrs]
+        peer_idents = [
+            e.identity if e else 0 for e in self.ipcache.lookup_many(addrs)
+        ]
+        ep_at, peer_at = ep_at.tolist(), peer_at.tolist()
+        ep_ip = [ep_ips[j] for j in ep_at]
+        peer_ip = [peer_ips[j] for j in peer_at]
+        dport = dports.tolist()
         if ingress:
-            src_ip, src_port = peer_ip, sport
-            dst_ip, dst_port = ep_ip or "", dport
-            src_identity = entry.identity if entry else 0
+            src, dst = peer_ip, ep_ip
+            ident = [peer_idents[j] for j in peer_at]
         else:
-            src_ip, src_port = ep_ip or "", sport
-            dst_ip, dst_port = peer_ip, dport
-            src_identity = ep.identity.id if ep and ep.identity else 0
-        self.proxymap.record(
-            src_ip, src_port, dst_ip, dst_port, proto,
-            ProxyValue(
-                orig_dst_ip=dst_ip,
-                orig_dst_port=dst_port,
-                src_identity=src_identity,
-            ),
+            src, dst = ep_ip, peer_ip
+            ident = [ep_idents[j] for j in ep_at]
+        self.proxymap.record_batch(
+            zip(src, sports.tolist(), dst, dport, protos.tolist()),
+            zip(dst, dport, ident),
         )
+        metrics.proxymap_handoff_flows_total.inc(None, len(dport))
+        metrics.proxymap_handoff_resolves_total.inc(None, len(peer_ips))
 
     def notify_agent(self, kind: str, message: str) -> None:
         """AgentNotify on the monitor stream (pkg/monitor/agent.go)."""

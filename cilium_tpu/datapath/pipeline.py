@@ -864,11 +864,13 @@ class DatapathPipeline:
         # jit-cache key shapes already dispatched (tracing telemetry:
         # a new member ≈ one XLA recompile)
         self._seen_shapes: set = set()
-        # called for every redirect verdict with a known 5-tuple:
-        # fn(peer_addr_bytes, ep_idx, sport, dport, proto, ingress,
-        # family) — the cilium_proxy4/6 write hook (bpf_lxc.c inserts
-        # a proxymap entry when the verdict is a proxy port)
-        self.on_redirect = None
+        # called once per batch that redirects a flow with a known
+        # 5-tuple, with the redirected rows as arrays:
+        # fn(peer_bytes[R, 4|16], ep_idx[R], sports[R], dports[R],
+        # protos[R], ingress, family) — the cilium_proxy4/6 write hook
+        # (bpf_lxc.c inserts a proxymap entry when the verdict is a
+        # proxy port)
+        self.on_redirect_batch = None
         # TraceNotify for forwarded flows is opt-in (the reference
         # gates trace events behind the TraceNotify endpoint option);
         # DropNotify defaults on while a listener is attached, gated
@@ -4511,13 +4513,10 @@ class DatapathPipeline:
             # proxymap handoff: redirected flows carry their full
             # 5-tuple here (sports present) — record for the L7
             # front-end
-            if self.on_redirect is not None and redirect.any():
-                for i in np.nonzero(redirect)[0]:
-                    self.on_redirect(
-                        bytes(int(x) & 0xFF for x in peer_bytes[i]),
-                        int(ep_idx[i]), int(sports[i]), int(dports[i]),
-                        int(protos[i]), ingress, family,
-                    )
+            self._hand_off_redirects(
+                redirect, peer_bytes, ep_idx, sports, dports, protos,
+                ingress=ingress, family=family,
+            )
 
             # host counter accumulation (CT hits included)
             with bt.phase("counters"):
@@ -4551,6 +4550,22 @@ class DatapathPipeline:
             return verdict, redirect
 
         return _InFlight(pending, finish, bt, b=b, rev=want_rev_nat)
+
+    def _hand_off_redirects(
+        self, redirect, peer_bytes, ep_idx, sports, dports, protos, *,
+        ingress: bool, family: int,
+    ) -> None:
+        """Hand the batch's redirected rows to ``on_redirect_batch`` in
+        one call; a batch that redirects nothing calls nothing. Rows
+        past ``len(redirect)`` (bucket padding) are never redirected."""
+        hook = self.on_redirect_batch
+        if hook is None or not redirect.any():
+            return
+        idx = np.nonzero(redirect)[0]
+        hook(
+            peer_bytes[idx], ep_idx[idx], sports[idx], dports[idx],
+            protos[idx], ingress, family,
+        )
 
     def _process_device_ct(
         self,
@@ -4638,13 +4653,10 @@ class DatapathPipeline:
             redirect = np.asarray(red)[:b]
         with bt.phase("counters"):
             self._account_batch(verdict)
-        if self.on_redirect is not None and redirect.any():
-            for i in np.nonzero(redirect)[0]:
-                self.on_redirect(
-                    bytes(int(x) & 0xFF for x in peer_bytes[i]),
-                    int(ep_idx[i]), int(sports[i]), int(dports[i]),
-                    int(protos[i]), ingress, family,
-                )
+        self._hand_off_redirects(
+            redirect, peer_bytes, ep_idx, sports, dports, protos,
+            ingress=ingress, family=family,
+        )
         self._emit_flow_events(
             peer_bytes[:b], ep_idx[:b], dports[:b], protos[:b], verdict,
             ingress=ingress, family=family, redirect=redirect,

@@ -17,7 +17,7 @@ from __future__ import annotations
 import dataclasses
 import ipaddress
 import threading
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 
 # Source priorities (ipcache.go allowOverwrite: agent-local knowledge
@@ -154,15 +154,29 @@ class IPCache:
 
     def lookup_by_ip(self, ip: str) -> Optional[Entry]:
         """Host-side LPM walk (the datapath does this on device)."""
-        addr = ipaddress.ip_address(ip)
-        max_len = 32 if addr.version == 4 else 128
+        return self.lookup_many([ipaddress.ip_address(ip)])[0]
+
+    def lookup_many(
+        self, addrs: Sequence[Union[ipaddress.IPv4Address, ipaddress.IPv6Address]]
+    ) -> List[Optional[Entry]]:
+        """Host-side LPM walk of each address, longest prefix first,
+        under one lock hold. Each prefix's key is the address's integer
+        masked to that length, so no prefix length parses a string."""
+        out = []
         with self._lock:
-            for plen in range(max_len, -1, -1):
-                net = ipaddress.ip_network(f"{ip}/{plen}", strict=False)
-                e = self._by_prefix.get(str(net))
-                if e is not None:
-                    return e
-        return None
+            get = self._by_prefix.get
+            for addr in addrs:
+                bits = addr.max_prefixlen
+                e = get(f"{addr}/{bits}")
+                if e is None:
+                    n, cls = int(addr), type(addr)
+                    for plen in range(bits - 1, -1, -1):
+                        host = bits - plen
+                        e = get(f"{cls(n >> host << host)}/{plen}")
+                        if e is not None:
+                            break
+                out.append(e)
+        return out
 
     def prefixes_for_identity(self, identity: int) -> List[str]:
         with self._lock:

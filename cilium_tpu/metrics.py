@@ -614,6 +614,21 @@ ct_entries = registry.gauge(
     "inserts, GC and flushes, never by a pass over the table",
 )
 
+# -- proxymap hand-off (Daemon._record_proxy_flows) families --------------
+# Always on: one inc per batch that redirects, never per flow.
+proxymap_handoff_flows_total = registry.counter(
+    "cilium_tpu_proxymap_handoff_flows_total",
+    "Redirected flows handed from the verdict pipeline to the proxymap "
+    "(one increment per batch, by the batch's redirected count)",
+)
+proxymap_handoff_resolves_total = registry.counter(
+    "cilium_tpu_proxymap_handoff_resolves_total",
+    "Distinct peer addresses those hand-offs resolved (address string "
+    "and ipcache longest-prefix lookup), once per peer per batch; "
+    "divide by handoff_flows_total for the share of flows that paid "
+    "for a lookup",
+)
+
 # -- host runtime (observe/gcwatch.py) families ----------------------------
 # Slot counters: the collector's callback adds into them with no lock.
 GC_GENERATIONS = ("generation", ("0", "1", "2"))

@@ -80,6 +80,37 @@ class TestIPCache:
         assert c.lookup_by_ip("10.200.0.1").identity == 7
         assert c.lookup_by_ip("11.0.0.1") is None
 
+    @pytest.mark.parametrize("family", [4, 6])
+    def test_lpm_lookups_match_a_plain_walk(self, family):
+        def plain(c, ip):
+            # every prefix length, longest first, each parsed as a network
+            for plen in range(bits, -1, -1):
+                e = c.lookup_exact(f"{ip}/{plen}")
+                if e is not None:
+                    return e
+            return None
+
+        rng = random.Random(family)
+        bits = 32 if family == 4 else 128
+        base = int(ipaddress.ip_address("10.0.0.0" if family == 4 else "fd00::"))
+        cls = ipaddress.IPv4Address if family == 4 else ipaddress.IPv6Address
+        c = IPCache()
+        # nested prefixes of random lengths inside one /8, host entries
+        # among them; then a default route
+        addrs = [cls(base | rng.getrandbits(bits - 8)) for _ in range(300)]
+        for i, a in enumerate(addrs[:150]):
+            plen = rng.randint(12, bits)
+            c.upsert(str(ipaddress.ip_network(f"{a}/{plen}", strict=False)), i + 1, SOURCE_K8S)
+        for default in (False, True):
+            if default:
+                c.upsert("0.0.0.0/0" if family == 4 else "::/0", 999, SOURCE_K8S)
+            want = [plain(c, a) for a in addrs]
+            assert c.lookup_many(addrs) == want
+            assert [c.lookup_by_ip(str(a)) for a in addrs] == want
+            assert (None in want) != default
+            assert any(e is not None and e.identity != 999 for e in want)
+        assert any(e.identity == 999 for e in want)
+
     def test_listeners_and_identity_index(self):
         c = IPCache()
         events = []

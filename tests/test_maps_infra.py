@@ -6,12 +6,18 @@ pkg/ip, pkg/ipam, pkg/logging, plugins/cilium-cni, pkg/workloads.
 
 from __future__ import annotations
 
+import gc
 import io
+import ipaddress
 import json
+import time
 
+import numpy as np
 import pytest
 
+from cilium_tpu import metrics
 from cilium_tpu.ipam import IPAM, IPAMError
+from cilium_tpu.ipcache.ipcache import IPCache
 from cilium_tpu.maps.lxcmap import EndpointInfo, LXCMap
 from cilium_tpu.maps.proxymap import ProxyMap, ProxyValue
 from cilium_tpu.maps.tunnel import TunnelMap
@@ -81,13 +87,52 @@ class TestProxyMap:
         pm2 = ProxyMap()
         v = ProxyValue(orig_dst_ip="10.0.0.9", orig_dst_port=80,
                        src_identity=1002)
-        pm2.record("10.0.0.1", 4444, "10.0.0.2", 15001, 6, v)
+        pm2.record_batch([("10.0.0.1", 4444, "10.0.0.2", 15001, 6)],
+                         [("10.0.0.9", 80, 1002)])
         got = pm2.lookup("10.0.0.1", 4444, "10.0.0.2", 15001, 6)
         assert got == v
         assert pm2.lookup("10.0.0.1", 4445, "10.0.0.2", 15001, 6) is None
-        pm.record("1.1.1.1", 1, "2.2.2.2", 2, 6, v)
+        pm.record_batch([("1.1.1.1", 1, "2.2.2.2", 2, 6)], [("10.0.0.9", 80, 1002)])
         assert pm.lookup("1.1.1.1", 1, "2.2.2.2", 2, 6) is None
         assert pm.gc() == 1
+
+    def test_record_batch_reads_like_record(self):
+        pm = ProxyMap()
+        now = time.monotonic()
+        keys = [("10.0.0.1", 4444, "10.0.0.2", 80, 6),
+                ("fd00::1", 4445, "fd00::2", 80, 6),
+                ("10.0.0.1", 4444, "10.0.0.2", 80, 6)]   # repeated: last wins
+        values = [("10.0.0.2", 80, 1002), ("fd00::2", 80, 0), ("10.0.0.2", 80, 1003)]
+        pm.record_batch(keys, values, now)
+        assert len(pm) == 2
+        assert pm.lookup(*keys[0]) == ProxyValue("10.0.0.2", 80, 1003)
+        assert pm.lookup(*keys[1]) == ProxyValue("fd00::2", 80, 0)
+        assert sorted(pm.items(), key=lambda e: e["src"]) == [
+            {"src": "10.0.0.1:4444", "dst": "10.0.0.2:80", "proto": 6,
+             "orig_dst": "10.0.0.2:80", "src_identity": 1003},
+            {"src": "fd00::1:4445", "dst": "fd00::2:80", "proto": 6,
+             "orig_dst": "fd00::2:80", "src_identity": 0},
+        ]
+        # entries recorded a lifetime ago are expired: unread, uncounted, reaped
+        pm.record_batch([("1.1.1.1", 1, "2.2.2.2", 2, 6)], [("2.2.2.2", 2, 7)],
+                        now - pm.lifetime)
+        assert pm.lookup("1.1.1.1", 1, "2.2.2.2", 2, 6) is None
+        assert len(pm) == 2 and len(pm.items()) == 2
+        assert pm.gc() == 1 and len(pm) == 2
+
+    def test_record_batch_adds_no_tracked_object_per_entry(self):
+        n = 20_000
+        keys = [(f"10.1.{i >> 8}.{i & 255}", 30000 + i % 9000, "10.0.0.7", 80, 6)
+                for i in range(n)]
+        values = [("10.0.0.7", 80, 1000 + i % 5) for i in range(n)]
+        pm = ProxyMap()
+        gc.collect()
+        before = len(gc.get_objects())
+        pm.record_batch(keys, values)
+        del keys, values
+        gc.collect()
+        assert len(pm) == n
+        assert len(gc.get_objects()) - before < 50
 
 
 class TestPrefixCounter:
@@ -189,6 +234,130 @@ class TestProxymapWiring:
         client_identity = d.endpoint_manager.lookup(9).identity.id
         assert got.src_identity == client_identity
         d.shutdown()
+
+    @pytest.mark.parametrize("family", [4, 6])
+    @pytest.mark.parametrize("ingress", [True, False], ids=["ingress", "egress"])
+    def test_batch_handoff_matches_per_flow(self, l7_daemon, family, ingress, request):
+        d = l7_daemon
+        device_ct = request.node.callspec.params["l7_daemon"] == "device_ct"
+        rng = np.random.default_rng(family + 2 * ingress)
+        n = 96
+        peer = rng.integers(0, len(L7_PEERS[family]), n)
+        ep = rng.integers(0, 2, n)
+        dport = np.where(rng.random(n) < 0.7, 80, 81)     # 81: dropped
+        sport = rng.integers(20000, 20064, n)
+        rows = np.r_[np.arange(n), np.arange(8)]          # 8 repeated 5-tuples
+        peer, ep, dport, sport = peer[rows], ep[rows], dport[rows], sport[rows]
+        ep_ids = np.array(L7_WEB)[ep]
+        before = len(d.proxymap)
+        _, red = _submit_l7(d, family, [L7_PEERS[family][p] for p in peer],
+                            ep_ids, dport, sport, ingress)
+        assert (d.pipeline._device_ct is not None) == device_ct
+        assert not red[dport == 81].any()
+        assert set(peer[red].tolist()) == {0, 1, 2}       # every kind of peer
+        want = {}
+        for i in np.nonzero(red)[0]:
+            k, v = _per_flow_entry(d, L7_PEERS[family][peer[i]], int(ep_ids[i]),
+                                   int(sport[i]), int(dport[i]), ingress, family)
+            want[k] = v
+        assert {k: d.proxymap.lookup(*k) for k in want} == want
+        assert len(d.proxymap) == before + len(want)
+        listed = {(e["src"], e["dst"]): e for e in d.proxymap.items()}
+        for (sip, sp, dip, dp, proto), v in want.items():
+            assert listed[(f"{sip}:{sp}", f"{dip}:{dp}")] == {
+                "src": f"{sip}:{sp}", "dst": f"{dip}:{dp}", "proto": proto,
+                "orig_dst": f"{v.orig_dst_ip}:{v.orig_dst_port}",
+                "src_identity": v.src_identity,
+            }
+        if ingress:   # /32 or /128, a wider CIDR only, no entry
+            assert {v.src_identity for v in want.values()} == {
+                d.endpoint_manager.lookup(L7_CLIENT).identity.id, 0}
+
+    def test_one_call_and_one_increment_per_batch(self, l7_daemon, monkeypatch):
+        d = l7_daemon
+        calls, incs = [], []
+        hook = d.pipeline.on_redirect_batch
+        monkeypatch.setattr(d.pipeline, "on_redirect_batch",
+                            lambda *a: (calls.append(len(a[1])), hook(*a)))
+        for fam in (metrics.proxymap_handoff_flows_total,
+                    metrics.proxymap_handoff_resolves_total):
+            monkeypatch.setattr(fam, "inc", lambda labels=None, value=1.0, _f=fam.name:
+                                incs.append((_f, value)))
+        peers = ["10.200.0.9", "10.201.3.4", "10.200.0.9", "192.0.2.55", "10.200.0.9"]
+        n = len(peers)
+        ep_ids = np.full(n, L7_WEB[0])
+        before = len(d.proxymap)
+        # a batch with no redirect calls nothing and counts nothing
+        _, red = _submit_l7(d, 4, peers, ep_ids, np.full(n, 81), np.arange(n), True)
+        assert not red.any() and calls == [] and incs == []
+        assert len(d.proxymap) == before
+        _, red = _submit_l7(d, 4, peers, ep_ids, np.full(n, 80), np.arange(n), True)
+        assert red.all() and calls == [n]
+        assert incs == [("cilium_tpu_proxymap_handoff_flows_total", n),
+                        ("cilium_tpu_proxymap_handoff_resolves_total", 3)]
+
+
+L7_WEB, L7_CLIENT = (7, 8), 9
+# per family: a peer with a host entry, one under a wider CIDR only,
+# and one the ipcache does not know (identity 0)
+L7_PEERS = {4: ["10.200.0.9", "10.201.3.4", "192.0.2.55"],
+            6: ["fd00::9", "fd01::3:4", "2001:db8::55"]}
+
+
+@pytest.fixture(scope="module", params=["host_ct", "device_ct"])
+def l7_daemon(request):
+    """A node whose web endpoints redirect port 80 to the proxy, in
+    both directions, for the client endpoint, its wider CIDR and the
+    world; on the host-CT or the fused device-CT dispatch."""
+    from cilium_tpu.daemon import Daemon
+
+    d = Daemon()
+    if request.param == "device_ct":
+        d.pipeline._device_ct_bits = 10
+    l7 = [{"ports": [{"port": "80", "protocol": "TCP"}],
+           "rules": {"http": [{"method": "GET", "path": "/api/.*"}]}}]
+    client = {"matchLabels": {"k8s:app": "client"}}
+    d.policy_add(json.dumps([{
+        "endpointSelector": {"matchLabels": {"k8s:app": "web"}},
+        "ingress": [{"fromEndpoints": [client], "toPorts": l7},
+                    {"fromEntities": ["world"], "toPorts": l7}],
+        "egress": [{"toEndpoints": [client], "toPorts": l7},
+                   {"toEntities": ["world"], "toPorts": l7}],
+        "labels": ["k8s:policy=l7-both-ways"],
+    }]))
+    for ep_id in L7_WEB:
+        d.endpoint_add(ep_id, ["k8s:app=web"], ipv4=f"10.200.0.{ep_id}",
+                       ipv6=f"fd00::{ep_id}")
+    d.endpoint_add(L7_CLIENT, ["k8s:app=client"], ipv4="10.200.0.9", ipv6="fd00::9")
+    ident = d.endpoint_manager.lookup(L7_CLIENT).identity.id
+    d.ipcache.upsert("10.201.0.0/16", ident, source="k8s")
+    d.ipcache.upsert("fd01::/64", ident, source="k8s")
+    yield d
+    d.shutdown()
+
+
+def _submit_l7(d, family, peers, ep_ids, dports, sports, ingress):
+    from cilium_tpu.ops.lpm import ip_strings_to_u32, ipv6_to_bytes
+
+    n = len(peers)
+    ep_idx = np.array([d.pipeline.endpoint_index(int(e)) for e in ep_ids], np.int32)
+    args = (ep_idx, np.asarray(dports, np.int32), np.full(n, 6, np.int32))
+    kw = dict(ingress=ingress, sports=np.asarray(sports))
+    if family == 4:
+        return d.pipeline.submit(ip_strings_to_u32(peers), *args, **kw).result()
+    return d.pipeline.submit_v6(ipv6_to_bytes(peers), *args, **kw).result()
+
+
+def _per_flow_entry(d, peer_ip, ep_id, sport, dport, ingress, family):
+    """One redirected flow's proxymap entry, built the plain way."""
+    ep = d.endpoint_manager.lookup(ep_id)
+    ep_ip = ep.ipv4 if family == 4 else ep.ipv6
+    if ingress:
+        e = d.ipcache.lookup_by_ip(peer_ip)
+        return ((peer_ip, sport, ep_ip, dport, 6),
+                ProxyValue(ep_ip, dport, e.identity if e else 0))
+    return ((ep_ip, sport, peer_ip, dport, 6),
+            ProxyValue(peer_ip, dport, ep.identity.id))
 
 
 class TestIPAMRestore:
