@@ -24,7 +24,15 @@ it as ``harness_*`` on its ``phase=setup`` line.
 
 Without a TPU (or with fewer chips than the cell asks for) the run
 exits 2 and prints no result; ``--cpu-rehearsal`` is the one explicit
-exception, and such a result names the CPU as its device.
+exception, and such a result names the CPU as its device. A cell on
+more than one chip prints the daemon's placement plan once set-up is
+done, and exits 3 with no result unless the plan spans exactly the
+cell's chips on the mesh axes its configuration asks for.
+
+Each step of set-up prints a ``setup-step`` line on stderr as it ends
+(seconds, seconds since start, compile seconds so far, the process's
+peak RSS, each chip's bytes in use), so a run stopped in set-up names
+the step it was in.
 """
 
 from __future__ import annotations
@@ -39,6 +47,7 @@ import dataclasses  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -59,8 +68,8 @@ from benchmark.tracefile import WINDOW, find_xplane, reduce_xplane  # noqa: E402
 WARM_SECONDS = 2.0       # traffic run before the window, at the cell's rate
 
 
-class NoDevice(SystemExit):
-    pass
+class NoResult(SystemExit):
+    """The run ends with this code and prints no result line."""
 
 
 def say(**kv) -> None:
@@ -91,12 +100,47 @@ def device_or_exit(chips: int, rehearsal: bool) -> dict:
     devs = jax.devices()
     if not rehearsal and devs[0].platform != "tpu":
         print(f"benchmark: no TPU (JAX found {devs[0].platform}); no result", file=sys.stderr)
-        raise NoDevice(2)
+        raise NoResult(2)
     if not rehearsal and len(devs) < chips:
         print(f"benchmark: the cell needs {chips} chips, JAX sees {len(devs)}; no result",
               file=sys.stderr)
-        raise NoDevice(2)
+        raise NoResult(2)
     return {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": chips}
+
+
+def memory_peaks(ids: List[int]) -> Dict[int, int]:
+    """``peak_bytes_in_use`` of each device in ``ids`` (0 where the
+    backend keeps no statistics)."""
+    import jax
+
+    by_id = {d.id: d for d in jax.devices()}
+    return {i: int((by_id[i].memory_stats() or {}).get("peak_bytes_in_use", 0)) for i in ids}
+
+
+def plan_devices(d, chips: int) -> List[int]:
+    """The devices the cell runs on: device 0 for one chip; for more,
+    the daemon's placement plan, printed, which has to span exactly
+    ``chips`` devices on the axes the daemon's configuration asks for
+    (``flows`` x ``ident`` with ``mesh_sharding_2d``, else ``flows``),
+    or the run ends with no result."""
+    import jax
+
+    from cilium_tpu.option import get_config
+
+    if chips == 1:
+        return [jax.devices()[0].id]
+    st = d.pipeline.placement_state()
+    say(phase="placement", axes=json.dumps(st["axes"], separators=(",", ":")),
+        devices=json.dumps(st["devices"], separators=(",", ":")),
+        ident_sharded=st["ident_sharded"], generation=st["generation"])
+    dcfg = get_config()
+    ident = dcfg.mesh_ident_axis if dcfg.mesh_sharding_2d else 1
+    want = {"flows": chips // ident, "ident": ident} if ident > 1 else {"flows": chips}
+    if len(st["devices"]) != chips or st["axes"] != want:
+        print(f"benchmark: the cell asks for {chips} chips on axes {want}; the daemon's plan "
+              f"spans devices {st['devices']} on axes {st['axes']}; no result", file=sys.stderr)
+        raise NoResult(3)
+    return list(st["devices"])
 
 
 class CompileLog:
@@ -324,22 +368,33 @@ class Prepared:
     warm_sched: T.Schedule
     scheds: List[T.Schedule]
     steps: Dict[str, float]
+    stores: Dict[str, object]     # remote clusters' kvstores (clusters worlds)
 
 
 def prepare(cfg: dict, traffic: dict, seed: int, seconds: float,
-            rates: Optional[List[float]]) -> Prepared:
+            rates: Optional[List[float]], on_step=None) -> Prepared:
     steps: Dict[str, float] = {}
     t = time.perf_counter()
+
+    def lap(name):
+        nonlocal t
+        steps[name] = time.perf_counter() - t
+        t = time.perf_counter()
+        if on_step is not None:
+            on_step(f"harness_{name}", steps[name])
+
     w = W.build_world(cfg, seed)
     ref = R.Reference(w)
     kind = T.load_kind(traffic["kind"]).Kind(w, traffic, seed)
-    steps["world_data"] = time.perf_counter() - t
-    t = time.perf_counter()
+    lap("world_data")
     warm_batches = kind.warm_batches()
     warm_sched = T.schedule(kind, WARM_SECONDS)
     scheds = [T.schedule(kind, seconds, rate=r) for r in (rates or [None])]
-    steps["schedule"] = time.perf_counter() - t
-    return Prepared(w, ref, kind, warm_batches, warm_sched, scheds, steps)
+    lap("schedule")
+    stores = W.remote_stores(w)
+    if stores:
+        lap("remote_stores")
+    return Prepared(w, ref, kind, warm_batches, warm_sched, scheds, steps, stores)
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
@@ -353,7 +408,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         cfg[k] = v
     for k, v in (overrides or {}).get("traffic", {}).items():
         traffic[k] = v
-    device = device_or_exit(int(cell["chips"]), rehearsal)
+    W.daemon_config(cfg)     # an unknown daemon key ends the run here
+    chips = int(cell["chips"])
+    device = device_or_exit(chips, rehearsal)
 
     import jax
 
@@ -366,10 +423,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     compiles = CompileLog()
     peaks = peaks_for(device["kind"], rehearsal)
 
-    prep = prepare(cfg, traffic, seed, seconds, sweep)
+    def step_line(name, secs):
+        hbm = [round((dev.memory_stats() or {}).get("bytes_in_use", 0) / 2**30, 3)
+               for dev in jax.devices()[:chips]]
+        print(f"setup-step {name} s={secs:.3f} at_s={time.perf_counter() - t_start:.1f} "
+              f"compile_s={compiles.secs:.1f} "
+              f"rss_peak_gb={resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20:.2f} "
+              f"hbm_gb={json.dumps(hbm)}", file=sys.stderr, flush=True)
+
+    prep = prepare(cfg, traffic, seed, seconds, sweep, on_step=step_line)
     harness_s = sum(prep.steps.values())
     w, ref, kind = prep.w, prep.ref, prep.kind
-    d, steps = W.boot_daemon(w, phase_tracing=trace, **kind.daemon)
+    d, steps = W.boot_daemon(w, phase_tracing=trace, stores=prep.stores, on_step=step_line,
+                             **kind.daemon)
     world_build_s = sum(steps.values())
     try:
         kind.attach(d)
@@ -379,6 +445,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             kind.send(b).result()
         driver.run(prep.warm_sched, WARM_SECONDS)
         steps["warm"] = time.perf_counter() - t
+        step_line("warm", steps["warm"])
+        devices = plan_devices(d, chips)
         if sweep:
             return run_sweep(d, driver, kind, ref, prep.scheds, seconds)
         sched = prep.scheds[0]
@@ -400,8 +468,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             jax.profiler.stop_trace()
         c1 = counter_snapshot()
         compiles_in_window = compiles.count - compiles_setup
-        mem = jax.devices()[0].memory_stats() or {}
-        device["memory_peak_bytes"] = int(mem.get("peak_bytes_in_use", 0))
+        mem = memory_peaks(devices)
+        device["memory_peak_bytes"] = max(mem.values())
         ct_live = len(d.conntrack) if d.conntrack is not None else 0
         ct_cap = d.conntrack.capacity if d.conntrack is not None else 0
         shapes = table_shapes(d) if trace else {}
@@ -424,6 +492,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         batch_p50_ms=round(float(np.median(lat_ms)), 3) if len(lat_ms) else "nan",
         batch_p95_ms=round(_p95(lat_ms), 3), compiles_in_window=compiles_in_window,
         ct_live_entries=ct_live, ct_capacity=ct_cap, check_s=round(check_s, 3),
+        memory_peak_bytes=json.dumps(mem, separators=(",", ":")),
         **{k.lstrip("_"): v for k, v in checks.items() if k.startswith("_")})
 
     e2e = end_to_end(win, kind, setup_s)
@@ -503,7 +572,7 @@ def main(argv=None) -> int:
         res = run_cell(args.workload, args.seed, args.seconds, bool(args.trace),
                        rehearsal=args.cpu_rehearsal,
                        sweep=[float(x) for x in args.sweep.split(",") if x])
-    except NoDevice as e:
+    except NoResult as e:
         return int(e.code)
     if "sweep" in res:
         return 0

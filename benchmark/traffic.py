@@ -144,9 +144,11 @@ class FlowSource:
 
     def __init__(self, w: W.World, t: dict, rng) -> None:
         self.w, self.t, self.rng = w, t, rng
-        n_svc = int(w.cfg["services"])
-        self._svc_cdf = zipf_cdf(n_svc, float(t.get("peer_zipf_s", 1.1)))
-        self._svc_perm = self.rng.permutation(n_svc)
+        # peers are drawn over every identity pods run under: the
+        # services, or in a ``clusters`` world each (cluster, service)
+        n_ident = w.n_idents
+        self._svc_cdf = zipf_cdf(n_ident, float(t.get("peer_zipf_s", 1.1)))
+        self._svc_perm = self.rng.permutation(n_ident)
         # per-endpoint allowed (peer app, port, proto) options, flattened
         self._opts = {}
         for ingress, table in ((True, w.allow_in), (False, w.allow_eg)):
