@@ -73,10 +73,11 @@ class ClusterNode:
         daemon.attach_node_registry(self.nodes, probe_interval=probe_interval)
         # ip→identity announcements (InitIPIdentityWatcher)
         self.ipsync = IPIdentitySync(backend, daemon.ipcache, cluster=cluster)
-        daemon.ipcache.add_listener(self._on_ipcache_change, replay=True)
+        daemon.ipcache.add_batch_listener(self._on_ipcache_change, replay=True)
         # remote-cluster merge (identities + ipcache + services)
         self.mesh = ClusterMesh(
-            daemon.registry, daemon.ipcache, services=daemon.services
+            daemon.registry, daemon.ipcache, services=daemon.services,
+            tracer=daemon.pipeline.tracer,
         )
         log.info("joined cluster", fields={
             "cluster": cluster, "nodeName": node.name,
@@ -124,17 +125,19 @@ class ClusterNode:
                      fields={"count": renumbered})
 
     # -- local endpoint announcements -----------------------------------
-    def _on_ipcache_change(self, cidr, old, new) -> None:
+    def _on_ipcache_change(self, changes) -> None:
         """Announce ONLY agent-sourced entries (this node's endpoints).
         kvstore-sourced entries are other nodes' announcements echoed
         back — re-announcing them would loop; the ipcache's source
         priority (agent > kvstore) already keeps our local truth from
-        being clobbered by our own echo."""
+        being clobbered by our own echo. One call per ipcache write,
+        so a remote cluster's batch costs one pass over its changes."""
         host = self.nodes.local.ipv4 or self.nodes.local.ipv6
-        if new is not None and new.source == SOURCE_AGENT:
-            self.ipsync.announce(cidr, new.identity, host_ip=host)
-        elif new is None and old is not None and old.source == SOURCE_AGENT:
-            self.ipsync.withdraw(cidr)
+        for cidr, old, new in changes:
+            if new is not None and new.source == SOURCE_AGENT:
+                self.ipsync.announce(cidr, new.identity, host_ip=host)
+            elif new is None and old is not None and old.source == SOURCE_AGENT:
+                self.ipsync.withdraw(cidr)
 
     # -- services -------------------------------------------------------
     def export_services(self) -> int:
@@ -197,9 +200,11 @@ class ClusterNode:
         daemon.routes.clear()
         from .ipcache.ipcache import SOURCE_KVSTORE
 
-        for cidr, e in daemon.ipcache.items():
-            if e.source == SOURCE_KVSTORE:
-                daemon.ipcache.delete(cidr, SOURCE_KVSTORE)
+        daemon.ipcache.update_many(
+            [(cidr, None, None) for cidr, e in daemon.ipcache.items()
+             if e.source == SOURCE_KVSTORE],
+            SOURCE_KVSTORE,
+        )
         self.mesh.close()
         self.ipsync.close()
         try:

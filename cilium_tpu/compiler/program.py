@@ -684,8 +684,14 @@ def compile_policy_state(
 
     # Selector axis padded to a multiple of 128 (MXU tile) — the padded
     # tail never matches (no conjuncts) and relation matrices are zero
-    # there.
-    s_pad = max(128, ((len(table) + 127) // 128) * 128)
+    # there — and, past 4,096 selectors, of 1/32 of the power of two at
+    # or above the count: a large rule set keeps its shape when a
+    # re-import moves its selector count a little, so the sweep and
+    # selector-match programs come from the compile cache (at most
+    # 1/16 more selectors, 1/8 more S² matrix bytes).
+    n_sel = len(table)
+    step = max(128, (1 << max(0, (n_sel - 1).bit_length())) // 32)
+    s_pad = max(128, -(-n_sel // step) * step)
     ing_packer = DirectionPacker(raw_ingress, s_pad)
     eg_packer = DirectionPacker(raw_egress, s_pad)
     for r, raw_i, raw_e in zip(rules, raws_ingress, raws_egress):

@@ -245,5 +245,6 @@ OPTION_BOOT_FIELDS: Dict[str, Optional[str]] = {
     "ClusterFederation": None,
     "Prefilter": "prefilter_shed",
     "SparseDeltas": "sparse_deltas",
+    "PolicySubjectIndex": "policy_subject_index",
     "LifecycleJournal": "lifecycle_journal",
 }

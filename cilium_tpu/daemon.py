@@ -40,6 +40,7 @@ from .maps.tunnel import TunnelMap
 from .mtu import MTUConfig
 from .observe import gcwatch as _gcwatch
 from .observe.flows import FlowRing
+from .utils import gcpause
 from .utils.iputil import prefix_lengths_of
 from .utils.logging import get_logger
 from .utils.prefix_counter import PrefixLengthCounter
@@ -93,15 +94,17 @@ class Daemon:
         ct_gc_interval: float = 60.0,
     ) -> None:
         self.state_dir = state_dir
+        cfg = get_config()
         self.repo = Repository()
-        self.registry = IdentityRegistry()
+        # a ClusterMesh member (cluster id > 0) numbers its user
+        # identities under its cluster id: (cluster_id << 16) | n
+        self.registry = IdentityRegistry(cluster_id=cfg.cluster_id)
         self.ipcache = IPCache()
         self.prefilter = PreFilter()
         self.engine = PolicyEngine(self.repo, self.registry)
         self.conntrack = FlowConntrack() if conntrack else None
         self.services = ServiceManager()
         self.monitor = MonitorHub()
-        cfg = get_config()
         # placement intent (policyd-mesh): device subset / 2D axes /
         # per-host process index resolve into the pipeline's MeshPlan
         from .datapath.placement import PlacementConfig
@@ -148,6 +151,7 @@ class Daemon:
         # GC timer; the GC hook counts collector pauses always and
         # mirrors them as profiler spans while it is on
         self.proxy = Proxy(tracer=self.pipeline.tracer)
+        self.repo.tracer = self.pipeline.tracer
         if self.conntrack is not None:
             self.conntrack.tracer = self.pipeline.tracer
         _gcwatch.install(self.pipeline.tracer)
@@ -230,6 +234,7 @@ class Daemon:
             ("AdmissionControl", cfg.admission_control),
             ("Prefilter", cfg.prefilter_shed),
             ("SparseDeltas", cfg.sparse_deltas),
+            ("PolicySubjectIndex", cfg.policy_subject_index),
             ("DeviceProfiling", cfg.device_profiling),
             ("FaultInjection", cfg.fault_injection),
             ("FleetTelemetry", cfg.fleet_telemetry),
@@ -342,10 +347,14 @@ class Daemon:
 
     # -- policy ---------------------------------------------------------
     def policy_add(self, rules_json: str) -> Dict:
-        """PUT /policy (daemon/policy.go PolicyAdd:167)."""
-        rules = rules_from_json(rules_json)
-        rev = self.repo.add_list(rules)
-        self._regenerate("policy import")
+        """PUT /policy (daemon/policy.go PolicyAdd:167). The import and
+        the regeneration it triggers run with the cyclic collector
+        paused (utils.gcpause): a large rule set would otherwise pay
+        full collections over the whole node's heap as it allocates."""
+        with gcpause.paused():
+            rules = rules_from_json(rules_json)
+            rev = self.repo.add_list(rules)
+            self._regenerate("policy import")
         self.save_state()
         log.info("policy imported",
                  fields={"policyRevision": rev, "rules": len(rules)})
@@ -873,7 +882,7 @@ class Daemon:
             "FlowAttribution", "DispatchAutoTune", "FailOpen",
             "FaultInjection", "EpochSwap", "L7DeviceBatch",
             "AdmissionControl", "Prefilter", "SparseDeltas",
-            "DeviceProfiling",
+            "DeviceProfiling", "PolicySubjectIndex",
             "ClusterFederation", "PolicyVerdictNotification",
             "FleetTelemetry", "LifecycleJournal",
         }
@@ -905,6 +914,10 @@ class Daemon:
                 self.pipeline.tracer.enable()
             else:
                 self.pipeline.tracer.disable()
+        elif name == "PolicySubjectIndex":
+            # indexed L4 resolution; takes effect at the next
+            # regeneration (off drops the index: the per-rule walk)
+            self.repo.set_subject_index(value)
         elif name == "VerdictSharding":
             # flow-sharded dispatch; placement changes on next rebuild
             # (a single-device node accepts the option as a no-op)

@@ -1378,6 +1378,9 @@ class DatapathPipeline:
         self._mesh = plan.mesh
         self._flow_sharding = plan.flow_sharding
         self._table_sharding = plan.table_sharding
+        # the engine's device tables live where the plan puts tables:
+        # on a 2D plan rule tables replicated, sel_match ident-sharded
+        self.engine.set_placement(plan.table_sharding, plan.ident_sharding)
 
     # -- policyd-failsafe: ladder + classified error handling ----------
     def set_fail_open(self, on: bool) -> None:

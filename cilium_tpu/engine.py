@@ -120,6 +120,49 @@ def _pack_match_words(m: np.ndarray) -> np.ndarray:
     return packed.view(np.uint32).reshape(m.shape[0], m.shape[1] // 32)
 
 
+def _policy_from_host(compiled, sel_match, placement) -> DevicePolicy:
+    """The compiled tables uploaded as ``placement`` says (see
+    ``PolicyEngine.set_placement``); ``sel_match`` may already be on a
+    device."""
+    if placement is None:
+        return DevicePolicy(
+            id_bits=jnp.asarray(compiled.id_bits),
+            sel_match=jnp.asarray(sel_match),
+            ingress=DeviceTables.from_host(compiled.ingress),
+            egress=DeviceTables.from_host(compiled.egress),
+        )
+    rep, ident = placement
+    return DevicePolicy(
+        id_bits=jax.device_put(compiled.id_bits, rep),
+        sel_match=jax.device_put(sel_match, ident),
+        ingress=DeviceTables.from_host(compiled.ingress, rep),
+        egress=DeviceTables.from_host(compiled.egress, rep),
+    )
+
+
+def _place_policy(device: DevicePolicy, placement) -> DevicePolicy:
+    """``device`` moved to ``placement`` (None: uncommitted arrays on
+    the default device, by way of the host)."""
+    if placement is None:
+        def put_rep(a):
+            return jnp.asarray(np.asarray(a))
+        put_ident = put_rep
+    else:
+        rep, ident = placement
+
+        def put_rep(a):
+            return jax.device_put(a, rep)
+
+        def put_ident(a):
+            return jax.device_put(a, ident)
+    return DevicePolicy(
+        id_bits=put_rep(device.id_bits),
+        sel_match=put_ident(device.sel_match),
+        ingress=jax.tree_util.tree_map(put_rep, device.ingress),
+        egress=jax.tree_util.tree_map(put_rep, device.egress),
+    )
+
+
 class PolicyEngine:
     # Delta-log ring consumed by DatapathPipeline for incremental
     # policymap materialization.
@@ -152,8 +195,29 @@ class PolicyEngine:
         # (key, {ingress: AttribTables}, n_rules) — rule-origin tables
         # for verdict attribution, rebuilt when the compile moves
         self._attrib_cache: Optional[tuple] = None
+        # (replicated, ident) shardings of a 2D placement plan, or None:
+        # where the device tables live (set_placement)
+        self._placement: Optional[tuple] = None
 
     # ------------------------------------------------------------------
+    def set_placement(self, replicated=None, ident=None) -> None:
+        """Place the device tables as the datapath's placement plan
+        says. On a 2D plan (``ident`` given) ``sel_match`` is row-sharded
+        with ``ident`` and every other table is ``replicated`` on each
+        device of the plan, built there from the host copy: no full
+        table is staged on the default device first, and none stays
+        there beside the placed copies. Without ``ident`` the tables
+        are uncommitted arrays on the default device, as the 1D and
+        single-device plans use them. Re-places what is already
+        uploaded when the placement moves."""
+        new = (replicated, ident) if ident is not None else None
+        with self._lock:
+            if new == self._placement:
+                return
+            self._placement = new
+            if self._device is not None:
+                self._device = _place_policy(self._device, new)
+
     def _log_delta(self, kind: str, payload: tuple) -> None:
         self.delta_seq += 1
         self._delta_log.append((self.delta_seq, kind, payload))
@@ -244,10 +308,11 @@ class PolicyEngine:
             return c
 
     @staticmethod
-    def _compute_full(repo, registry):
+    def _compute_full(repo, registry, placement=None):
         """The expensive half of a full refresh (host compile + device
-        upload), lock-free so the background-continuity path can run it
-        while restored tables keep serving."""
+        upload, placed as ``placement`` says: see set_placement),
+        lock-free so the background-continuity path can run it while
+        restored tables keep serving."""
         compiled, state = compile_policy_state(repo, registry)
         sel_match = compute_selector_matches(
             jnp.asarray(compiled.id_bits),
@@ -256,17 +321,22 @@ class PolicyEngine:
             jnp.asarray(compiled.conj_valid),
             jnp.asarray(compiled.req_count),
         )
-        device = DevicePolicy(
-            id_bits=jnp.asarray(compiled.id_bits),
-            sel_match=sel_match,
-            ingress=DeviceTables.from_host(compiled.ingress),
-            egress=DeviceTables.from_host(compiled.egress),
+        # the upload runs where the refresh runs: under the engine lock
+        # on the synchronous path, as the default-device upload always
+        # did (the refresh is the control plane's table swap; readers
+        # keep the previous tables until the install)
+        device = _policy_from_host(  # policyd-lint: disable=LOCK002
+            compiled, sel_match, placement
         )
-        return compiled, state, sel_match, device
+        return compiled, state, sel_match, device, placement
 
-    def _install_compiled(self, compiled, state, sel_match, device) -> None:
+    def _install_compiled(self, compiled, state, sel_match, device,
+                          placement=None) -> None:
         """Swap a computed full-refresh result in (lock held)."""
         self._install_gen += 1
+        if placement != self._placement:
+            # the plan moved while a background refresh computed
+            device = _place_policy(device, self._placement)
         self._device = device
         # np.array (copy): asarray on a device buffer is read-only and
         # the incremental paths mutate this in place.
@@ -288,15 +358,14 @@ class PolicyEngine:
 
     def _full_refresh(self) -> CompiledPolicy:
         t0 = time.perf_counter()
-        compiled, state, sel_match, device = self._compute_full(
-            self.repo, self.registry
+        self._install_compiled(
+            *self._compute_full(self.repo, self.registry, self._placement)
         )
-        self._install_compiled(compiled, state, sel_match, device)
         _metrics.engine_refresh_seconds.observe(
             time.perf_counter() - t0, {"kind": "full"}
         )
         _metrics.engine_refreshes_total.inc({"kind": "full"})
-        return compiled
+        return self._compiled
 
     # -- incremental paths ---------------------------------------------
     # policyd: refresh-path
@@ -592,7 +661,9 @@ class PolicyEngine:
 
         def run():
             try:
-                result = self._compute_full(self.repo, self.registry)
+                result = self._compute_full(
+                    self.repo, self.registry, self._placement
+                )
                 with self._lock:
                     if self._install_gen != gen:
                         # someone installed a NEWER compile while this
@@ -722,11 +793,9 @@ class PolicyEngine:
             compiled.revision = -1
             compiled.identity_version = -1
         with self._lock:
-            self._device = DevicePolicy(
-                id_bits=jnp.asarray(compiled.id_bits),
-                sel_match=jnp.asarray(sel_match_host),
-                ingress=DeviceTables.from_host(compiled.ingress),
-                egress=DeviceTables.from_host(compiled.egress),
+            # restore installs under the lock, as it always uploaded
+            self._device = _policy_from_host(  # policyd-lint: disable=LOCK002
+                compiled, sel_match_host, self._placement
             )
             self._sel_match_host = sel_match_host
             low = np.full(MAX_USER_IDENTITY + 1, -1, np.int32)

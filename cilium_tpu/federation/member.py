@@ -27,11 +27,7 @@ import time
 from typing import Dict, Optional
 
 from ..identity.distributed import key_to_labels, labels_to_key
-from ..identity.model import (
-    Identity,
-    MAX_USER_IDENTITY,
-    MIN_USER_IDENTITY,
-)
+from ..identity.model import Identity
 from ..kvstore.backend import BackendOperations
 from ..kvstore.paths import IDENTITIES_PATH
 from ..labels import LabelArray
@@ -80,8 +76,10 @@ class FederationMember:
             backend,
             identities_path,
             node_name=node_name,
-            min_id=MIN_USER_IDENTITY,
-            max_id=MAX_USER_IDENTITY,
+            # the daemon's cluster-scoped user range (cluster id in
+            # bits 16-23 when the node has one)
+            min_id=daemon.registry.user_range[0],
+            max_id=daemon.registry.user_range[1],
             on_event=self._on_identity_event,
             backoff_factory=backoff_factory,
         )

@@ -27,7 +27,7 @@ from typing import Dict, Optional
 from ..kvstore.allocator import Allocator
 from ..kvstore.backend import BackendOperations
 from ..labels import LabelArray, parse_label_array
-from .model import Identity, MAX_USER_IDENTITY, MIN_USER_IDENTITY
+from .model import Identity
 from .registry import IdentityRegistry
 
 from ..kvstore.paths import IDENTITIES_PATH, key_to_label_strings
@@ -73,8 +73,11 @@ class DistributedIdentityAllocator:
             backend,
             base_path,
             suffix=node_name,
-            min_id=MIN_USER_IDENTITY,
-            max_id=MAX_USER_IDENTITY,
+            # the registry's cluster-scoped user range: the same numbers
+            # the node's standalone allocator hands out, so joining a
+            # cluster renumbers no endpoint
+            min_id=registry.user_range[0],
+            max_id=registry.user_range[1],
             on_event=self._on_allocator_event,
         )
         self.pump()

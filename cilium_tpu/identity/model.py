@@ -8,7 +8,7 @@ pkg/identity/numericidentity.go (reserved numeric identities and the
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 from ..labels import Label, LabelArray
 
@@ -21,6 +21,18 @@ ID_INIT = 5
 
 MIN_USER_IDENTITY = 256
 MAX_USER_IDENTITY = 65535
+# ClusterMesh scopes a cluster's user identities by its cluster id
+# (1-255) in bits 16-23: (cluster_id << 16) | n, n in the user range
+CLUSTER_ID_SHIFT = 16
+
+
+def user_identity_range(cluster_id: int = 0) -> Tuple[int, int]:
+    """(first, last) user identity a node of cluster ``cluster_id``
+    allocates: MIN..MAX_USER_IDENTITY under cluster id 0, else that
+    range under the cluster id, so no two clusters of a mesh can hand
+    out the same number."""
+    base = cluster_id << CLUSTER_ID_SHIFT
+    return base | MIN_USER_IDENTITY, base | MAX_USER_IDENTITY
 
 # Node-local identities (CIDR-derived). The reference scopes these
 # locally too; we place them above the global space so the two can never

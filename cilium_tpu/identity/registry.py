@@ -18,7 +18,7 @@ caches instead of a fresh trace per identity.
 from __future__ import annotations
 
 import threading
-from typing import Callable, Dict, Iterator, List, Optional, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -26,16 +26,24 @@ from ..labels import LabelArray, LabelVocab
 from .model import (
     Identity,
     LOCAL_IDENTITY_BASE,
-    MAX_USER_IDENTITY,
-    MIN_USER_IDENTITY,
     RESERVED_IDENTITIES,
     reserved_identity_labels,
+    user_identity_range,
 )
 
 
 class IdentityRegistry:
-    def __init__(self, vocab: Optional[LabelVocab] = None, row_bucket: int = 256):
+    def __init__(
+        self,
+        vocab: Optional[LabelVocab] = None,
+        row_bucket: int = 256,
+        *,
+        cluster_id: int = 0,
+    ):
         self.vocab = vocab or LabelVocab()
+        # the node's cluster-scoped user range (identity.model
+        # user_identity_range): every allocator of the node draws from it
+        self.user_range = user_identity_range(cluster_id)
         self.row_bucket = row_bucket
         self._lock = threading.RLock()
         self._by_id: Dict[int, Identity] = {}
@@ -43,7 +51,7 @@ class IdentityRegistry:
         self._refcount: Dict[int, int] = {}
         self._row_of: Dict[int, int] = {}
         self._id_of_row: List[int] = []
-        self._next_user = MIN_USER_IDENTITY
+        self._next_user = self.user_range[0]
         self._next_local = LOCAL_IDENTITY_BASE
         self.version = 0
         self._observers: List[Callable[[Identity, bool], None]] = []
@@ -89,7 +97,7 @@ class IdentityRegistry:
                 self._next_local += 1
             else:
                 num = self._next_user
-                if num > MAX_USER_IDENTITY:
+                if num > self.user_range[1]:
                     raise RuntimeError("user identity space exhausted")
                 self._next_user += 1
             ident = Identity(num, labels)
@@ -121,10 +129,32 @@ class IdentityRegistry:
                     f"labels already bound to identity {stale.id}, got {num}"
                 )
             ident = Identity(num, labels)
-            if MIN_USER_IDENTITY <= num <= MAX_USER_IDENTITY:
+            if self.user_range[0] <= num <= self.user_range[1]:
                 self._next_user = max(self._next_user, num + 1)
             self._insert(ident)
             return ident
+
+    def insert_global_many(
+        self, items: Sequence[Tuple[int, LabelArray]], *, skip_known: bool = False
+    ) -> List[bool]:
+        """``insert_global`` for each ``(num, labels)`` in order, under
+        one lock hold: a watch pump's drained batch. With
+        ``skip_known`` a number the registry already holds is left as
+        it is. → per item, whether it was inserted (False: skipped, or
+        refused as ``insert_global`` refuses a conflicting binding)."""
+        out: List[bool] = []
+        with self._lock:
+            for num, labels in items:
+                if skip_known and num in self._by_id:
+                    out.append(False)
+                    continue
+                try:
+                    self.insert_global(num, labels)
+                except ValueError:
+                    out.append(False)
+                    continue
+                out.append(True)
+        return out
 
     def release_by_id(self, num: int) -> bool:
         """Release one reference of identity ``num`` (remote-deletion
@@ -197,9 +227,18 @@ class IdentityRegistry:
             bitmaps = np.zeros((rows, words), dtype=np.uint32)
             ids = np.zeros(rows, dtype=np.int32)
             live = np.zeros(rows, dtype=bool)
-            for r, num in enumerate(self._id_of_row):
-                ids[r] = num
-                if r in row_bits:
-                    bitmaps[r] = self.vocab.pack(row_bits[r], words)
-                    live[r] = True
+            ids[: len(self._id_of_row)] = self._id_of_row
+            live[list(row_bits)] = True
+            # every (row, bit) pair set in one scatter (vocab.pack's
+            # layout: bit b is bit b % 32 of word b // 32)
+            r_idx = np.fromiter(
+                (r for r, bits in row_bits.items() for _ in bits), np.int64
+            )
+            b_idx = np.fromiter(
+                (b for bits in row_bits.values() for b in bits), np.int64
+            )
+            np.bitwise_or.at(
+                bitmaps, (r_idx, b_idx // 32),
+                np.left_shift(np.uint32(1), (b_idx % 32).astype(np.uint32)),
+            )
             return bitmaps, ids, live

@@ -38,6 +38,10 @@ class Entry:
 
 # fn(cidr, old_entry_or_None, new_entry_or_None)
 Listener = Callable[[str, Optional[Entry], Optional[Entry]], None]
+# (cidr, old_entry_or_None, new_entry_or_None)
+Change = Tuple[str, Optional[Entry], Optional[Entry]]
+# fn([change, ...]): every entry one write changed
+BatchListener = Callable[[List[Change]], None]
 
 
 class IPCache:
@@ -50,6 +54,7 @@ class IPCache:
         self._by_prefix: Dict[str, Entry] = {}
         self._by_identity: Dict[int, set] = {}
         self._listeners: List[Listener] = []
+        self._batch_listeners: List[BatchListener] = []
         self.version = 0
         # (version, cidr, old_identity|None, new_identity|None) —
         # appended under the lock by upsert/delete, oldest dropped past
@@ -85,22 +90,72 @@ class IPCache:
         return str(ipaddress.ip_network(cidr, strict=False))
 
     def add_listener(self, fn: Listener, replay: bool = True) -> None:
-        """SetListeners (listener fan-out); replay synthesizes the
-        current state like the reference's initial dump."""
+        """SetListeners (listener fan-out), called once per changed
+        entry; replay synthesizes the current state like the
+        reference's initial dump."""
         with self._lock:
             self._listeners.append(fn)
             if replay:
                 for cidr, e in self._by_prefix.items():
                     fn(cidr, None, e)
 
-    def remove_listener(self, fn: Listener) -> bool:
-        """Detach a listener (cluster leave must stop announcements)."""
+    def add_batch_listener(self, fn: BatchListener, replay: bool = True) -> None:
+        """A listener called once per write with the list of entries it
+        changed: one call for an ``update_many`` batch, where a
+        per-entry listener is called once per entry. Replay hands it
+        the current state as one batch."""
         with self._lock:
-            try:
-                self._listeners.remove(fn)
-                return True
-            except ValueError:
-                return False
+            self._batch_listeners.append(fn)
+            if replay and self._by_prefix:
+                fn([(cidr, None, e) for cidr, e in self._by_prefix.items()])
+
+    def remove_listener(self, fn) -> bool:
+        """Detach a listener of either kind (cluster leave must stop
+        announcements)."""
+        with self._lock:
+            for group in (self._listeners, self._batch_listeners):
+                if fn in group:
+                    group.remove(fn)
+                    return True
+            return False
+
+    def _change_locked(
+        self, key: str, identity: Optional[int], source: str,
+        host_ip: Optional[str] = None,
+    ) -> Optional[Change]:
+        """Upsert (``identity`` given) or delete one normalised entry
+        under the lock, calling the per-entry listeners in map-update
+        order (the reference holds the ipcache mutex across
+        IPIdentityMappingListener callbacks). → the change, or None
+        when a higher-priority source owns the entry (ipcache.go:183
+        allowOverwrite) or there is nothing to delete."""
+        old = self._by_prefix.get(key)
+        if identity is None:
+            if old is None or _PRIORITY[old.source] > _PRIORITY[source]:
+                return None
+            new = None
+            del self._by_prefix[key]
+        else:
+            if old is not None and _PRIORITY[old.source] > _PRIORITY[source]:
+                return None
+            new = Entry(identity, source, host_ip)
+            self._by_prefix[key] = new
+        if old is not None:
+            s = self._by_identity.get(old.identity)
+            if s:
+                s.discard(key)
+        if new is not None:
+            self._by_identity.setdefault(identity, set()).add(key)
+        self.version += 1
+        self._log_delta(key, old.identity if old else None, identity)
+        for fn in self._listeners:
+            fn(key, old, new)
+        return key, old, new
+
+    def _notify_batch(self, changes: List[Change]) -> None:
+        if changes:
+            for fn in self._batch_listeners:
+                fn(changes)
 
     def upsert(
         self,
@@ -112,41 +167,43 @@ class IPCache:
         """Returns False when a higher-priority source owns the entry
         (ipcache.go:183 allowOverwrite)."""
         key = self._norm(cidr)
-        new = Entry(identity, source, host_ip)
-        # Listener fan-out happens under the lock so derived state sees
-        # events in map-update order (the reference holds the ipcache
-        # mutex across IPIdentityMappingListener callbacks).
         with self._lock:
-            old = self._by_prefix.get(key)
-            if old is not None and _PRIORITY[old.source] > _PRIORITY[source]:
-                return False
-            self._by_prefix[key] = new
-            if old is not None:
-                s = self._by_identity.get(old.identity)
-                if s:
-                    s.discard(key)
-            self._by_identity.setdefault(identity, set()).add(key)
-            self.version += 1
-            self._log_delta(key, old.identity if old else None, identity)
-            for fn in self._listeners:
-                fn(key, old, new)
-        return True
+            ch = self._change_locked(key, identity, source, host_ip)
+            self._notify_batch([ch] if ch else [])
+        return ch is not None
 
     def delete(self, cidr: str, source: str) -> bool:
         key = self._norm(cidr)
         with self._lock:
-            old = self._by_prefix.get(key)
-            if old is None or _PRIORITY[old.source] > _PRIORITY[source]:
-                return False
-            del self._by_prefix[key]
-            s = self._by_identity.get(old.identity)
-            if s:
-                s.discard(key)
-            self.version += 1
-            self._log_delta(key, old.identity, None)
-            for fn in self._listeners:
-                fn(key, old, None)
-        return True
+            ch = self._change_locked(key, None, source)
+            self._notify_batch([ch] if ch else [])
+        return ch is not None
+
+    def update_many(
+        self,
+        updates: Sequence[Tuple[str, Optional[int], Optional[str]]],
+        source: str,
+    ) -> int:
+        """Apply ``(cidr, identity, host_ip)`` upserts, and deletes
+        where ``identity`` is None, in order, as one write: each entry
+        follows the rules of ``upsert``/``delete``, and the batch
+        listeners are called once with every change. An entry whose
+        CIDR does not parse is skipped, as a watcher skips a malformed
+        event. → entries changed."""
+        keyed = []
+        for cidr, identity, host_ip in updates:
+            try:
+                keyed.append((self._norm(cidr), identity, host_ip))
+            except ValueError:
+                continue
+        with self._lock:
+            changes = []
+            for key, identity, host_ip in keyed:
+                ch = self._change_locked(key, identity, source, host_ip)
+                if ch is not None:
+                    changes.append(ch)
+            self._notify_batch(changes)
+        return len(changes)
 
     # -- lookups --------------------------------------------------------
     def lookup_exact(self, cidr: str) -> Optional[Entry]:

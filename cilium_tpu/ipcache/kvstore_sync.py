@@ -91,27 +91,25 @@ class IPIdentitySync:
 
     def pump(self) -> int:
         """Merge pending watch events into the local IPCache
-        (InitIPIdentityWatcher loop). Returns events applied."""
-        n = 0
-        for ev in self._watcher.drain():
-            n += 1
+        (InitIPIdentityWatcher loop), as one ipcache batch. Returns
+        events applied."""
+        events = self._watcher.drain()
+        updates = []
+        for ev in events:
             if ev.typ == EventTypeListDone:
                 continue
             cidr = ev.key[len(self.prefix):]
             if ev.typ == EventTypeDelete:
-                self.ipcache.delete(cidr, SOURCE_KVSTORE)
+                updates.append((cidr, None, None))
             else:
                 try:
                     payload = json.loads((ev.value or b"{}").decode())
                 except ValueError:
                     continue
-                self.ipcache.upsert(
-                    cidr,
-                    int(payload.get("identity", 0)),
-                    source=SOURCE_KVSTORE,
-                    host_ip=payload.get("host_ip"),
-                )
-        return n
+                updates.append((cidr, int(payload.get("identity", 0)),
+                                payload.get("host_ip")))
+        self.ipcache.update_many(updates, SOURCE_KVSTORE)
+        return len(events)
 
     def close(self) -> None:
         self.backend.stop_watcher(self._watcher)

@@ -16,12 +16,14 @@ loop belongs to the daemon layer.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 from typing import Callable, Dict, List, Optional
 
 from typing import TYPE_CHECKING
 
+from .. import metrics as _metrics
 from ..identity.registry import IdentityRegistry
 from ..ipcache.ipcache import IPCache, SOURCE_KVSTORE
 
@@ -29,6 +31,7 @@ if TYPE_CHECKING:  # runtime import is lazy — nodes.registry depends on
     from ..nodes.registry import Node  # kvstore, so a top-level import
     # here would make `import cilium_tpu.nodes` order-dependent
 from ..labels import parse_label_array
+from ..utils import gcpause
 from .backend import (
     BackendOperations,
     EventTypeDelete,
@@ -60,8 +63,10 @@ class RemoteCluster:
         ipcache: IPCache,
         on_node: Optional[Callable[[str, Node, bool], None]] = None,
         services=None,  # Optional[lb.service.ServiceManager]
+        tracer=None,  # Optional[observe.tracer.Tracer]
     ) -> None:
         self.name = name
+        self.tracer = tracer
         self.backend = backend
         self.registry = registry
         self.ipcache = ipcache
@@ -96,12 +101,29 @@ class RemoteCluster:
     def pump(self) -> int:
         """Apply pending remote events (the RemoteCache merge of
         allocator.go + ipcache kvstore watcher, scoped to this
-        cluster)."""
+        cluster). Each drained list of identity events goes into the
+        registry under one lock hold, and each drained list of ip
+        events into the ipcache as one batch: one listener call and one
+        NPHDS version bump per pump, not one per entry. While the
+        tracer is active the whole pump is the
+        ``policyd.clustermesh.pump`` profiler span. A pump runs with
+        the cyclic collector paused (utils.gcpause): a remote cluster's
+        first list is hundreds of thousands of long-lived entries."""
+        tr = self.tracer
+        with (
+            tr.annotate("policyd.clustermesh.pump") if tr is not None
+            else contextlib.nullcontext()
+        ), gcpause.paused():
+            return self._pump()
+
+    def _pump(self) -> int:
         from ..nodes.registry import Node  # lazy: breaks import cycle
 
         n = 0
-        for ev in self._w_ids.drain():
-            n += 1
+        events = self._w_ids.drain()
+        n += len(events)
+        inserts = []
+        for ev in events:
             if ev.typ == EventTypeListDone:
                 continue
             try:
@@ -109,42 +131,39 @@ class RemoteCluster:
             except ValueError:
                 continue
             if ev.typ == EventTypeDelete:
+                if inserts:
+                    self._insert_ids(inserts)
+                    inserts = []
                 if self._held_ids.pop(id_, None):
                     self.registry.release_by_id(id_)
-            else:
-                if id_ in self._held_ids or self.registry.get(id_) is not None:
-                    continue
-                try:
-                    self.registry.insert_global(
-                        id_, _key_to_labels((ev.value or b"").decode())
-                    )
-                    self._held_ids[id_] = True
-                except ValueError:
-                    # conflicting binding: local cluster wins; the
-                    # reference logs and skips (cache.go invalidKey)
-                    continue
-        for ev in self._w_ips.drain():
-            n += 1
+            elif id_ not in self._held_ids:
+                inserts.append((id_, ev.value))
+        self._insert_ids(inserts)
+        _metrics.clustermesh_events_total.inc({"kind": "identity"}, len(events))
+        events = self._w_ips.drain()
+        n += len(events)
+        updates = []
+        for ev in events:
             if ev.typ == EventTypeListDone:
                 continue
             cidr = ev.key[len(self._ip_prefix):]
             if ev.typ == EventTypeDelete:
-                self.ipcache.delete(cidr, SOURCE_KVSTORE)
+                updates.append((cidr, None, None))
                 self._ip_entries.discard(cidr)
             else:
                 try:
                     payload = json.loads((ev.value or b"{}").decode())
                 except ValueError:
                     continue
-                self.ipcache.upsert(
-                    cidr,
-                    int(payload.get("identity", 0)),
-                    source=SOURCE_KVSTORE,
-                    host_ip=payload.get("host_ip"),
-                )
+                updates.append((cidr, int(payload.get("identity", 0)),
+                                payload.get("host_ip")))
                 self._ip_entries.add(cidr)
-        for ev in self._w_nodes.drain():
-            n += 1
+        self.ipcache.update_many(updates, SOURCE_KVSTORE)
+        _metrics.clustermesh_events_total.inc({"kind": "ip"}, len(events))
+        events = self._w_nodes.drain()
+        n += len(events)
+        _metrics.clustermesh_events_total.inc({"kind": "node"}, len(events))
+        for ev in events:
             if ev.typ == EventTypeListDone:
                 continue
             name = ev.key[len(self._node_prefix):]
@@ -163,8 +182,10 @@ class RemoteCluster:
         if self._w_svcs is not None:
             from ..lb.service import Backend, L3n4Addr
 
-            for ev in self._w_svcs.drain():
-                n += 1
+            events = self._w_svcs.drain()
+            n += len(events)
+            _metrics.clustermesh_events_total.inc({"kind": "service"}, len(events))
+            for ev in events:
                 if ev.typ == EventTypeListDone:
                     continue
                 fe_str = ev.key[len(self._svc_prefix):]
@@ -193,6 +214,24 @@ class RemoteCluster:
                 self._svc_frontends.add(fe)
         return n
 
+    def _insert_ids(self, items) -> None:
+        """Mirror remote identities into the registry in one lock hold.
+        A number the registry already holds, or labels bound under
+        another number (local cluster wins; the reference logs and
+        skips, cache.go invalidKey), is skipped."""
+        parsed = []
+        for id_, value in items:
+            try:
+                parsed.append((id_, _key_to_labels((value or b"").decode())))
+            except ValueError:
+                continue  # undecodable key: skipped like a conflicting one
+        if not parsed:
+            return
+        done = self.registry.insert_global_many(parsed, skip_known=True)
+        for (id_, _), ok in zip(parsed, done):
+            if ok:
+                self._held_ids[id_] = True
+
     @staticmethod
     def _parse_frontend(text: str):
         from ..lb.service import L3n4Addr
@@ -209,8 +248,9 @@ class RemoteCluster:
         for id_ in list(self._held_ids):
             self.registry.release_by_id(id_)
         self._held_ids.clear()
-        for cidr in list(self._ip_entries):
-            self.ipcache.delete(cidr, SOURCE_KVSTORE)
+        self.ipcache.update_many(
+            [(cidr, None, None) for cidr in self._ip_entries], SOURCE_KVSTORE
+        )
         self._ip_entries.clear()
         if self.services is not None:
             for fe in list(self._svc_frontends):
@@ -234,8 +274,10 @@ class ClusterMesh:
         *,
         on_node: Optional[Callable[[str, Node, bool], None]] = None,
         services=None,  # Optional[lb.service.ServiceManager]
+        tracer=None,  # Optional[observe.tracer.Tracer]
     ) -> None:
         self.registry = registry
+        self.tracer = tracer
         self.ipcache = ipcache
         self._on_node = on_node
         self._services = services
@@ -248,7 +290,7 @@ class ClusterMesh:
                 return self.clusters[name]
             rc = RemoteCluster(
                 name, backend, self.registry, self.ipcache, self._on_node,
-                services=self._services,
+                services=self._services, tracer=self.tracer,
             )
             self.clusters[name] = rc
             return rc

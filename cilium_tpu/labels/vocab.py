@@ -54,8 +54,11 @@ class LabelVocab:
 
     @property
     def num_words(self) -> int:
-        """uint32 words needed for a full bitmap (≥1, padded)."""
-        return max(1, (len(self._bits) + 31) // 32)
+        """uint32 words for a full bitmap, padded to a multiple of 8: a
+        vocabulary growing one label at a time reshapes the identity
+        and selector bitmaps (a full engine refresh and new programs)
+        once per 256 labels, not once per 32."""
+        return max(1, -(-len(self._bits) // 256)) * 8
 
     def _intern(self, key: _BitKey) -> int:
         bit = self._bits.get(key)

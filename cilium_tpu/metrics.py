@@ -621,6 +621,19 @@ proxymap_handoff_flows_total = registry.counter(
     "Redirected flows handed from the verdict pipeline to the proxymap "
     "(one increment per batch, by the batch's redirected count)",
 )
+clustermesh_events_total = registry.counter(
+    "cilium_tpu_clustermesh_events_total",
+    "Remote-cluster kvstore events a clustermesh pump drained (label "
+    "kind: identity | ip | node | service; one increment per kind per "
+    "pump, by the count drained)",
+)
+policy_rules_visited_total = registry.counter(
+    "cilium_tpu_policy_rules_visited_total",
+    "Rules whose subject selector an endpoint's L4 policy resolution "
+    "tested (label direction: ingress | egress; one increment per "
+    "resolution): every rule on the per-rule walk, the candidates "
+    "only under PolicySubjectIndex",
+)
 proxymap_handoff_resolves_total = registry.counter(
     "cilium_tpu_proxymap_handoff_resolves_total",
     "Distinct peer addresses those hand-offs resolved (address string "

@@ -23,11 +23,58 @@ from __future__ import annotations
 
 import functools
 import ipaddress
+import re
+import socket
 from typing import Dict, Iterable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+# RFC 5952 text as Python's ipaddress writes it (lower-case hex, no
+# embedded IPv4 dotted quad): where inet_ntop's rendering of such text
+# matches, both parsers read the same address
+_V6_TEXT = re.compile(r"[0-9a-f:]+")
+
+
+def parse_cidr(cidr: str) -> Tuple[int, bytes, int]:
+    """(version, packed network address, prefix length) of a CIDR, as
+    ``ipaddress.ip_network(cidr, strict=False)`` gives them. The
+    canonical text the ipcache stores parses through ``inet_pton``
+    (a few times faster than building the network object, which
+    matters at hundreds of thousands of entries a rebuild); any other
+    text takes ``ipaddress`` itself, so both accept the same inputs."""
+    addr, sep, plen_s = cidr.partition("/")
+    v6 = ":" in addr
+    size = 16 if v6 else 4
+    try:
+        packed = socket.inet_pton(socket.AF_INET6 if v6 else socket.AF_INET, addr)
+        plen = int(plen_s) if sep else size * 8
+        canonical = (
+            socket.inet_ntop(socket.AF_INET6 if v6 else socket.AF_INET, packed) == addr
+            and (not sep or plen_s == str(plen))
+            and 0 <= plen <= size * 8
+            and (not v6 or _V6_TEXT.fullmatch(addr) is not None)
+        )
+    except (OSError, ValueError):
+        canonical = False
+    if not canonical:
+        net = ipaddress.ip_network(cidr, strict=False)
+        return net.version, net.network_address.packed, net.prefixlen
+    host = size * 8 - plen
+    n = int.from_bytes(packed, "big") >> host << host
+    return (6 if v6 else 4), n.to_bytes(size, "big"), plen
+
+
+def _rows_bucket(m: int) -> int:
+    """Rows of a stride-8 node table: ``m`` rounded up to 1/32 of the
+    power of two at or above it. A trie over a random prefix set (a
+    prefilter blocklist) then keeps its shape from one set to the next
+    of about the same size, and the verdict programs that read it come
+    from the compile cache; the rows added are zero, so no node points
+    at them. At most 1/32 more memory."""
+    step = max(1, (1 << max(0, (m - 1).bit_length())) // 32)
+    return -(-m // step) * step
 
 
 class TrieBuilder:
@@ -75,8 +122,8 @@ class TrieBuilder:
 
     def arrays(self) -> Tuple[np.ndarray, np.ndarray]:
         m = len(self._children)
-        child = np.zeros((m, 256), np.int32)
-        info = np.zeros((m, 256), np.int32)
+        child = np.zeros((_rows_bucket(m), 256), np.int32)
+        info = np.zeros((_rows_bucket(m), 256), np.int32)
         for n in range(m):
             for b, c in self._children[n].items():
                 child[n, b] = c
@@ -92,10 +139,10 @@ def build_trie(
     levels = 16 if ipv6 else 4
     t = TrieBuilder(levels)
     for cidr, value in prefixes:
-        net = ipaddress.ip_network(cidr, strict=False)
-        if (net.version == 6) != ipv6:
+        version, packed, plen = parse_cidr(cidr)
+        if (version == 6) != ipv6:
             continue
-        t.insert(net.network_address.packed, net.prefixlen, value)
+        t.insert(packed, plen, value)
     return t.arrays()
 
 
@@ -117,10 +164,10 @@ def build_trie_elided(
     size = 16 if ipv6 else 4
     entries = []
     for cidr, value in prefixes:
-        net = ipaddress.ip_network(cidr, strict=False)
-        if (net.version == 6) != ipv6:
+        version, packed, plen = parse_cidr(cidr)
+        if (version == 6) != ipv6:
             continue
-        entries.append((net.network_address.packed, net.prefixlen, value))
+        entries.append((packed, plen, value))
     k = 0
     if entries:
         first = entries[0][0]
@@ -245,8 +292,8 @@ class WideTrieBuilder(_DenseRoot):
 
     def arrays(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
         m = len(self._children)
-        sub_child = np.zeros((m, 256), np.int32)
-        sub_info = np.zeros((m, 256), np.int32)
+        sub_child = np.zeros((_rows_bucket(m), 256), np.int32)
+        sub_info = np.zeros((_rows_bucket(m), 256), np.int32)
         for n in range(m):
             for b, c in self._children[n].items():
                 sub_child[n, b] = c
@@ -325,10 +372,10 @@ def build_wide_trie(
     parsed = []
     deep_hi16 = set()
     for cidr, value in prefixes:
-        net = ipaddress.ip_network(cidr, strict=False)
-        if net.version != 4:
+        version, packed, plen = parse_cidr(cidr)
+        if version != 4:
             continue
-        addr, plen = int(net.network_address), net.prefixlen
+        addr = int.from_bytes(packed, "big")
         parsed.append((addr, plen, value))
         if plen > 16:
             deep_hi16.add(addr >> 16)
@@ -430,10 +477,10 @@ def merge_trie_entries(ip_prefixes, deny_prefixes, *, ipv6=True):
     def parse(prefixes):
         out = []
         for cidr, value in prefixes:
-            net = ipaddress.ip_network(cidr, strict=False)
-            if (net.version == 6) != ipv6:
+            version, packed, plen = parse_cidr(cidr)
+            if (version == 6) != ipv6:
                 continue
-            out.append((net.network_address.packed, net.prefixlen, value))
+            out.append((packed, plen, value))
         return out
 
     ip_entries = parse(ip_prefixes)
@@ -611,10 +658,10 @@ class PatchableElidedTrie:
         self._ipv6 = ipv6
         entries = []
         for cidr, value in prefixes:
-            net = ipaddress.ip_network(cidr, strict=False)
-            if (net.version == 6) != ipv6:
+            version, packed, plen = parse_cidr(cidr)
+            if (version == 6) != ipv6:
                 continue
-            entries.append((net.network_address.packed, net.prefixlen, value))
+            entries.append((packed, plen, value))
         k = 0
         if entries:
             first = entries[0][0]
@@ -698,10 +745,10 @@ class PatchableElidedTrie:
 
     # -- public ops ----------------------------------------------------
     def _parse(self, cidr: str):
-        net = ipaddress.ip_network(cidr, strict=False)
-        if (net.version == 6) != self._ipv6:
+        version, packed, plen = parse_cidr(cidr)
+        if (version == 6) != self._ipv6:
             return None
-        return net.network_address.packed, net.prefixlen
+        return packed, plen
 
     def insert(self, cidr: str, value: int) -> bool:
         """Upsert one prefix. False → not expressible in place (family
@@ -874,10 +921,10 @@ class PatchableFlatTrie:
     # -- public ops ----------------------------------------------------
     @staticmethod
     def _parse(cidr: str):
-        net = ipaddress.ip_network(cidr, strict=False)
-        if net.version != 4:
+        version, packed, plen = parse_cidr(cidr)
+        if version != 4:
             return None
-        return int(net.network_address), net.prefixlen
+        return int.from_bytes(packed, "big"), plen
 
     def insert(self, cidr: str, value: int) -> bool:
         p = self._parse(cidr)
@@ -998,10 +1045,10 @@ def make_patchable_wide(
     parsed = []
     deep_hi16 = set()
     for cidr, value in prefixes:
-        net = ipaddress.ip_network(cidr, strict=False)
-        if net.version != 4:
+        version, packed, plen = parse_cidr(cidr)
+        if version != 4:
             continue
-        addr, plen = int(net.network_address), net.prefixlen
+        addr = int.from_bytes(packed, "big")
         parsed.append((addr, plen, value))
         if plen > 16:
             deep_hi16.add(addr >> 16)

@@ -118,7 +118,9 @@ def materialize_endpoints(
 
 
 def _seg_bucket(n_seg: int) -> int:
-    b = 8
+    """Power-of-two segment count, at least 128: a node's first hundred
+    endpoints, added one at a time, share one compiled sweep."""
+    b = 128
     while b < n_seg:
         b <<= 1
     return b
@@ -296,6 +298,9 @@ def _unpack_rows(words: np.ndarray, n: int) -> np.ndarray:
 # Identity rows per matrix-sweep block: bounds the [nblock, S]
 # peer-term activations while keeping the MXU contraction dims full.
 _MATRIX_NBLOCK = 1024
+# Segments per matrix-sweep dispatch: its three stacked bool outputs
+# are [N, chunk] each (~150 MB apiece at 100k identities)
+_MATRIX_SEG_CHUNK = 512
 
 
 def _sweep_segments(
@@ -324,15 +329,25 @@ def _sweep_segments(
     sweeps always take the flow kernel: the first-match rule tail needs
     the per-flow term vectors the matrix form contracts away."""
     n_seg = len(sr)
-    # Chunk the segment axis so one dispatch's flattened row count
-    # stays bounded (~big-batch sized) regardless of endpoint count ×
-    # identity capacity, then pad each chunk to a bucket (dummy L3
-    # segs against row 0) so repeated materializations reuse the
-    # compiled sweep.
-    budget = max(8, (1 << 23) // max(1, n))
-    seg_chunk = 1 << (budget.bit_length() - 1)  # power of two ≤ budget
-    seg_chunk = min(seg_chunk, _seg_bucket(n_seg))
     use_matrix = sweep != "flow" and attrib_origin is None
+    # Chunk the segment axis, then pad each chunk to a bucket (dummy L3
+    # segs against row 0) so repeated materializations reuse the
+    # compiled sweep. The flow kernel flattens segments x identities
+    # into one batch, so its chunk keeps that row count bounded
+    # (~big-batch sized) regardless of endpoint count x identity
+    # capacity. The matrix kernel's cost is its peer terms, recomputed
+    # for every chunk whatever its size, so it takes the most segments
+    # its stacked [blocks, nb, chunk] outputs hold comfortably.
+    if use_matrix:
+        seg_chunk = _MATRIX_SEG_CHUNK
+    else:
+        budget = max(8, (1 << 23) // max(1, n))
+        seg_chunk = 1 << (budget.bit_length() - 1)  # power of two ≤ budget
+    seg_chunk = min(seg_chunk, _seg_bucket(n_seg))
+    # No sweep reads the identities' label bits, whose word count grows
+    # with the label vocabulary: left in, they would recompile every
+    # sweep each time a new label adds a word.
+    device = device.replace(id_bits=None)
     aw_parts: List[np.ndarray] = []
     l3_parts: List[np.ndarray] = []
     rw_parts: List[np.ndarray] = []
@@ -401,10 +416,15 @@ def materialize_endpoints_state(
     ep_rows = compiled.rows_for(endpoint_identity_ids)
     # Bounded [E, S/32] pull of just the endpoint subject rows — never
     # the full [N, S/32] matrix (at the 100k stretch that pull alone
-    # moved ~1.2GB per `policy explain`).
+    # moved ~1.2GB per `policy explain`). The row list is padded to a
+    # power-of-two bucket (row 0 repeated), so a node adding endpoints
+    # one at a time compiles the gather once per bucket, not once per
+    # endpoint count.
+    ep_take = np.zeros(_seg_bucket(len(ep_rows)), np.int32)
+    ep_take[: len(ep_rows)] = ep_rows
     ep_sel = np.asarray(  # policyd-lint: disable=TPU001,TPU005
-        jnp.take(device.sel_match, jnp.asarray(ep_rows, np.int32), axis=0)
-    )
+        jnp.take(device.sel_match, jnp.asarray(ep_take), axis=0)
+    )[: len(ep_rows)]
     live = compiled.row_live
     direction = TRAFFIC_INGRESS if ingress else TRAFFIC_EGRESS
 
@@ -507,10 +527,9 @@ def materialize_endpoints_state(
         col_proto=jnp.asarray(np.pad(np.asarray(col_proto, np.int32), (0, pad))),
         col_is_l3=jnp.asarray(np.pad(np.asarray(col_is_l3, bool), (0, pad))),
         # allow ‖ redirect in one table: the lookup kernel's row gather
-        # lowers to a single one-hot matmul serving both bitmaps
-        id_bits=pack_bool_bits(
-            jnp.asarray(np.concatenate([allow_nc, red_nc], axis=1))
-        ),
+        # lowers to a single one-hot matmul serving both bitmaps. Packed
+        # here, on the host, and uploaded once.
+        id_bits=jnp.asarray(_pack_rows(np.concatenate([allow_nc, red_nc], axis=1))),
     )
     return MaterializedState(
         tables=tables,
@@ -565,9 +584,7 @@ def state_from_snapshot(row_ids: np.ndarray, fields: dict) -> MaterializedState:
         col_port=jnp.asarray(col_port),
         col_proto=jnp.asarray(col_proto),
         col_is_l3=jnp.asarray(col_is_l3),
-        id_bits=pack_bool_bits(
-            jnp.asarray(np.concatenate([allow_nc, red_nc], axis=1))
-        ),
+        id_bits=jnp.asarray(_pack_rows(np.concatenate([allow_nc, red_nc], axis=1))),
     )
     return MaterializedState(
         tables=tables,

@@ -100,6 +100,10 @@ class DaemonConfig:
     # field (or a field the daemon never seeds from) fails the lint
     # gate, which is how the L7DeviceBatch dead-toggle bug class dies.
     policy_verdict_notification: bool = False
+    # Boot-time value of the PolicySubjectIndex runtime option: an
+    # endpoint's L4 policy resolves from the rules whose subject
+    # selector can select it (a label index), not a walk of every rule.
+    policy_subject_index: bool = False
     phase_tracing: bool = False
     flow_attribution: bool = False
     dispatch_autotune: bool = False
@@ -361,6 +365,16 @@ OPTION_SPECS: Dict[str, OptionSpec] = {
             "falls back to the classic full rebuild. Off compiles the "
             "exact pre-option programs — dense re-placement, classic "
             "unpadded trie builds",
+        ),
+        OptionSpec(
+            "PolicySubjectIndex",
+            "Indexed L4 policy resolution: an endpoint's regeneration "
+            "resolves its L4 policy from the rules filed, in a label "
+            "index of rule subject selectors, under one of its labels "
+            "(plus every rule whose selector requires no label), "
+            "instead of testing every rule's selector; the result is "
+            "the per-rule walk's. Off keeps the per-rule walk and "
+            "holds no index",
         ),
         OptionSpec(
             "Prefilter",

@@ -123,27 +123,47 @@ class EndpointSelector:
         return EndpointSelector(self.match_labels, self.match_expressions + (expr,))
 
     # -- host-side evaluation (the oracle path) -------------------------
-    def matches(self, labels: LabelArray) -> bool:
-        for key, value in self.match_labels:
-            if not labels.has(_parse_selector_label(key, value)):
-                return False
-        for expr in self.match_expressions:
-            probe = _parse_selector_label(expr.key)
-            has_key = any(
-                l.key == probe.key and (probe.source == "any" or probe.source == l.source)
-                for l in labels
+    def _parsed(self):
+        """The selector's labels parsed once, on first use: (required
+        labels, ((operator, key probe, value labels), ...)). Kept on the
+        instance outside the dataclass fields, so equality and hashing
+        are unchanged."""
+        parsed = self.__dict__.get("_parsed_cache")
+        if parsed is None:
+            parsed = (
+                tuple(_parse_selector_label(k, v) for k, v in self.match_labels),
+                tuple(
+                    (e.operator, _parse_selector_label(e.key),
+                     tuple(_parse_selector_label(e.key, v) for v in e.values))
+                    for e in self.match_expressions
+                ),
             )
-            if expr.operator == EXISTS:
-                if not has_key:
+            object.__setattr__(self, "_parsed_cache", parsed)
+        return parsed
+
+    def required_labels(self) -> Tuple[Tuple[str, str], ...]:
+        """(key, value) of every matchLabels entry: a label set the
+        selector matches carries each of them, under some source."""
+        return tuple((l.key, l.value) for l in self._parsed()[0])
+
+    def matches(self, labels: LabelArray) -> bool:
+        required, expressions = self._parsed()
+        for lbl in required:
+            if not labels.has(lbl):
+                return False
+        for operator, probe, values in expressions:
+            if operator == EXISTS or operator == DOES_NOT_EXIST:
+                has_key = any(
+                    l.key == probe.key and (probe.source == "any" or probe.source == l.source)
+                    for l in labels
+                )
+                if has_key != (operator == EXISTS):
                     return False
-            elif expr.operator == DOES_NOT_EXIST:
-                if has_key:
+            elif operator == IN:
+                if not any(labels.has(v) for v in values):
                     return False
-            elif expr.operator == IN:
-                if not any(labels.has(_parse_selector_label(expr.key, v)) for v in expr.values):
-                    return False
-            elif expr.operator == NOT_IN:
-                if any(labels.has(_parse_selector_label(expr.key, v)) for v in expr.values):
+            elif operator == NOT_IN:
+                if any(labels.has(v) for v in values):
                     return False
         return True
 

@@ -27,24 +27,32 @@ class ResourceCache:
 
     def upsert(self, type_url: str, name: str, resource: dict) -> int:
         """→ new version (cache.go tx: no-op writes don't bump)."""
-        with self._cond:
-            version, res = self._types.get(type_url, (0, {}))
-            if res.get(name) == resource:
-                return version
-            res = dict(res)
-            res[name] = resource
-            version += 1
-            self._types[type_url] = (version, res)
-            self._cond.notify_all()
-            return version
+        return self.apply(type_url, {name: resource})
 
     def delete(self, type_url: str, name: str) -> int:
+        return self.apply(type_url, {name: None})
+
+    def apply(self, type_url: str, updates: Dict[str, Optional[dict]]) -> int:
+        """Upsert each ``name: resource`` and delete each ``name: None``
+        as one transaction: one version bump when anything changed,
+        none otherwise. → the type's version after it.
+
+        The per-type dict is changed in place, so a write costs its own
+        entries, not the type's size; ``get`` hands out copies made
+        under the lock, which is what keeps a reader's snapshot still."""
         with self._cond:
             version, res = self._types.get(type_url, (0, {}))
-            if name not in res:
+            changed = False
+            for name, resource in updates.items():
+                if resource is None:
+                    if name in res:
+                        del res[name]
+                        changed = True
+                elif res.get(name) != resource:
+                    res[name] = resource
+                    changed = True
+            if not changed:
                 return version
-            res = dict(res)
-            del res[name]
             version += 1
             self._types[type_url] = (version, res)
             self._cond.notify_all()

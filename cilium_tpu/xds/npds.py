@@ -9,7 +9,7 @@ external proxy processes subscribe via xds/client.py.
 
 from __future__ import annotations
 
-from typing import Iterable, List
+from typing import Dict, Iterable, List, Optional
 
 from .cache import (
     NETWORK_POLICY_HOSTS_TYPE,
@@ -57,23 +57,32 @@ def publish_host_mapping(
 ) -> int:
     """NPHDS row for one identity: the reverse identity → addresses
     map (resources.go:88-172). Empty prefix set deletes the row."""
-    prefixes = ipcache.prefixes_for_identity(identity)
-    if not prefixes:
-        return cache.delete(NETWORK_POLICY_HOSTS_TYPE, str(identity))
-    return cache.upsert(
-        NETWORK_POLICY_HOSTS_TYPE, str(identity),
-        {"policy": identity, "host_addresses": sorted(prefixes)},
-    )
+    return publish_host_mappings(cache, ipcache, (identity,))
+
+
+def publish_host_mappings(
+    cache: ResourceCache, ipcache, identities: Iterable[int]
+) -> int:
+    """The NPHDS rows of ``identities`` as one cache transaction: one
+    version bump for the lot, or none when no row changed."""
+    updates: Dict[str, Optional[dict]] = {}
+    for identity in identities:
+        prefixes = ipcache.prefixes_for_identity(identity)
+        updates[str(identity)] = (
+            {"policy": identity, "host_addresses": prefixes} if prefixes else None
+        )
+    return cache.apply(NETWORK_POLICY_HOSTS_TYPE, updates)
 
 
 def wire_nphds(cache: ResourceCache, ipcache) -> None:
-    """Subscribe the NPHDS type to ipcache churn: every upsert/delete
-    refreshes the affected identities' rows (the ipcache listener
-    fan-out of pkg/datapath/ipcache/listener.go, pointed at xDS)."""
+    """Subscribe the NPHDS type to ipcache churn: every batch of
+    upserts/deletes refreshes the affected identities' rows in one
+    transaction (the ipcache listener fan-out of
+    pkg/datapath/ipcache/listener.go, pointed at xDS)."""
 
-    def on_change(key: str, old, new) -> None:
-        for e in (old, new):
-            if e is not None:
-                publish_host_mapping(cache, ipcache, e.identity)
+    def on_changes(changes) -> None:
+        idents = {e.identity for _, old, new in changes for e in (old, new)
+                  if e is not None}
+        publish_host_mappings(cache, ipcache, sorted(idents))
 
-    ipcache.add_listener(on_change, replay=True)
+    ipcache.add_batch_listener(on_changes, replay=True)
