@@ -12,7 +12,10 @@ a FIXED rung set (ops.dfa.L7_LEN_LADDER) and the lane (row) dimension
 to L7_LANE_RUNGS, so jit keys only on rung shapes — a live batch never
 compiles a new program once the rungs are warm. Pad rows are marked
 length -1 (the kernels mask them to an empty accept mask) and counted
-in ``l7_pad_lanes_total``.
+in ``l7_pad_lanes_total``. Each lane chunk travels as one packed buffer
+up (ops.dfa.pack_walk_rows: bytes, lengths and start states) and one
+uint32 [2, lanes] result down, counted in
+``l7_device_transfers_total``.
 
 The module also owns the ``L7DeviceBatch`` runtime gate: policies read
 ``device_batch_enabled()`` per batch and fall back to their exact
@@ -29,7 +32,6 @@ from collections import deque
 from typing import List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from .. import metrics
@@ -37,9 +39,10 @@ from ..observe.tracer import NOOP_BATCH, Tracer
 from ..ops.dfa import (
     DeviceDFATable,
     L7_LEN_LADDER,
-    dfa_match_batch_fused,
-    dfa_match_batch_pair,
+    dfa_match_packed_fused,
+    dfa_match_packed_pair,
     len_rung,
+    pack_walk_rows,
     strings_to_batch_u8,
 )
 
@@ -79,18 +82,20 @@ class PendingL7Batch:
 
 
 class _InFlight:
-    __slots__ = ("pending", "chunks", "n_req", "n_fields", "bt", "t0", "ps")
+    __slots__ = ("pending", "chunks", "n_req", "n_fields", "bt", "t0",
+                 "parser", "ps")
 
-    def __init__(self, pending, chunks, n_req, n_fields, bt, t0,
+    def __init__(self, pending, chunks, n_req, n_fields, bt, t0, parser,
                  ps=None) -> None:
         self.pending = pending
-        # [(lo_dev, hi_dev, rows_live)] — device handles; pulled at
-        # completion time, not submit time (that's the overlap)
+        # [(masks_dev uint32 [2, lanes], rows_live)] — device handles;
+        # pulled at completion time, not submit time (that's the overlap)
         self.chunks = chunks
         self.n_req = n_req
         self.n_fields = n_fields
         self.bt = bt
         self.t0 = t0
+        self.parser = parser
         # policyd-prof: live _DispatchSample on the profiler's Nth
         # batch (None otherwise); _finish times the mask pull into it
         self.ps = ps
@@ -151,27 +156,23 @@ class L7Pipeline:
                 with self._lock:
                     if key in self._seen_shapes:
                         continue
-                sb = np.zeros((lanes, rung), np.uint8)
-                lens = np.full(lanes, -1, np.int32)
-                starts = np.zeros(lanes, np.int32)
-                lo, hi = self._walk(table, sb, lens, starts, rung)
-                lo.block_until_ready()
+                none = np.zeros(0, np.int32)
+                packed = pack_walk_rows(np.zeros((0, rung), np.uint8), none, none, lanes)
+                self._walk(table, packed, rung).block_until_ready()
                 self._note_shape(key_kind, table.n_states, lanes, rung, warm=True)
                 warmed += 1
-                del hi
         return warmed
 
     # -- dispatch --------------------------------------------------------
-    def _walk(self, table: DeviceDFATable, sb: np.ndarray, lens: np.ndarray,
-              starts: np.ndarray, rung: int):
+    def _walk(self, table: DeviceDFATable, packed, rung: int):
+        """One packed chunk (host or device array) → its device masks,
+        uint32 [2, lanes]: one upload, one output buffer."""
         if table.has_pair:
-            return dfa_match_batch_pair(
-                table.pair, table.accept_lo, table.accept_hi,
-                jnp.asarray(starts), jnp.asarray(sb), jnp.asarray(lens), rung,
+            return dfa_match_packed_pair(
+                table.pair, table.accept_lo, table.accept_hi, packed, rung,
             )
-        return dfa_match_batch_fused(
-            table.trans, table.accept_lo, table.accept_hi,
-            jnp.asarray(starts), jnp.asarray(sb), jnp.asarray(lens), rung,
+        return dfa_match_packed_fused(
+            table.trans, table.accept_lo, table.accept_hi, packed, rung,
         )
 
     def submit(
@@ -222,6 +223,12 @@ class L7Pipeline:
                 starts = np.repeat(table.starts_host, n_req)
                 live = int(lens.size)
                 live_bytes = int(np.maximum(lens, 0).sum())
+                # full top-rung chunks, then one tail chunk padded to
+                # its lane rung: the pad rows all sit at the end
+                top = L7_LANE_RUNGS[-1]
+                tail = live % top
+                rows = live - tail + (lane_rung(tail) if tail else 0)
+                packed = pack_walk_rows(sb, lens, starts, rows)
 
             # policyd-prof: one attribute read while off; the sampled
             # batch pays the explicit-upload / ready sandwiches below
@@ -230,54 +237,43 @@ class L7Pipeline:
 
             with bt.phase("dispatch"):
                 chunks = []
-                top = L7_LANE_RUNGS[-1]
-                pad_rows = 0
+                pad_rows = rows - live
                 off = 0
-                n_chunks = 0
                 _pl_t0 = time.perf_counter() if ps is not None else 0.0
+                kind = "pair" if table.has_pair else "fused"
                 while off < live:
                     take = min(top, live - off)
                     lanes = lane_rung(take)
-                    if take < lanes:
-                        csb = np.zeros((lanes, rung), np.uint8)
-                        csb[:take] = sb[off : off + take]
-                        clens = np.full(lanes, -1, np.int32)
-                        clens[:take] = lens[off : off + take]
-                        cstarts = np.zeros(lanes, np.int32)
-                        cstarts[:take] = starts[off : off + take]
-                        pad_rows += lanes - take
-                    else:
-                        csb = sb[off : off + take]
-                        clens = lens[off : off + take]
-                        cstarts = starts[off : off + take]
+                    chunk = packed[off : off + lanes]
                     if ps is not None:
                         # sampled h2d edge: upload explicitly and wait so
-                        # the walk below starts from device-resident inputs
-                        # (jnp.asarray in _walk passes jax arrays through —
-                        # same avals, same compiled program). The per-chunk
-                        # sync IS the measurement, 1-in-N batches only:
+                        # the walk below starts from a device-resident
+                        # buffer (same aval, same compiled program). The
+                        # per-chunk sync IS the measurement, 1-in-N
+                        # batches only:
                         _t0 = time.perf_counter()
-                        csb, clens, cstarts = jax.block_until_ready(  # policyd-lint: disable=TPU002
-                            jax.device_put((csb, clens, cstarts))
+                        chunk = jax.block_until_ready(  # policyd-lint: disable=TPU002
+                            jax.device_put(chunk)
                         )
                         ps.add_h2d(time.perf_counter() - _t0)
-                    kind = "pair" if table.has_pair else "fused"
                     self._note_shape(kind, table.n_states, lanes, rung)
-                    lo, hi = self._walk(table, csb, clens, cstarts, rung)
-                    chunks.append((lo, hi, take))
+                    chunks.append((self._walk(table, chunk, rung), take))
                     off += take
-                    n_chunks += 1
+                n_chunks = len(chunks)
+                metrics.l7_device_transfers_total.inc(
+                    {"direction": "h2d", "parser": parser}, n_chunks
+                )
                 if ps is not None:
                     # sampled compute edge: h2d already completed above, so
-                    # the rest of the chunk loop (lane padding, per-rung jit
-                    # dispatch) plus the residual wait here is the fused DFA
-                    # walk side of the split
-                    jax.block_until_ready([(c[0], c[1]) for c in chunks])
+                    # the rest of the chunk loop (per-rung jit dispatch)
+                    # plus the residual wait here is the fused DFA walk
+                    # side of the split
+                    jax.block_until_ready([c[0] for c in chunks])
                     ps.add_compute(
                         time.perf_counter() - _pl_t0 - ps.h2d_s
                     )
                     ps.mark(
-                        rungs=[lane_rung(min(top, c[2])) for c in chunks],
+                        rungs=[lane_rung(c[1]) for c in chunks],
                         len_rung=int(rung),
                         lanes=int(live),
                         pad_lanes=int(pad_rows),
@@ -293,7 +289,8 @@ class L7Pipeline:
                 metrics.l7_batches_total.inc({"parser": parser})
 
             pending = PendingL7Batch(self)
-            entry = _InFlight(pending, chunks, n_req, table.n_fields, bt, t0, ps)
+            entry = _InFlight(pending, chunks, n_req, table.n_fields, bt, t0,
+                              parser, ps)
             if bt is not NOOP_BATCH:
                 tr.detach(bt)
             overflow: List[_InFlight] = []
@@ -326,10 +323,12 @@ class L7Pipeline:
         try:
             with entry.bt.phase("host_sync"):
                 parts = []
-                for ch in entry.chunks:
-                    lo64 = np.asarray(ch[0]).astype(np.uint64)
-                    hi64 = np.asarray(ch[1]).astype(np.uint64)
-                    parts.append((lo64 | (hi64 << np.uint64(32)))[: ch[2]])
+                for dev, take in entry.chunks:
+                    words = np.asarray(dev)[:, :take].astype(np.uint64)
+                    parts.append(words[0] | (words[1] << np.uint64(32)))
+                metrics.l7_device_transfers_total.inc(
+                    {"direction": "d2h", "parser": entry.parser}, len(parts)
+                )
                 if not parts:
                     masks = np.zeros(0, np.uint64)
                 elif len(parts) == 1:

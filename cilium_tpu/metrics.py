@@ -416,6 +416,12 @@ l7_batches_total = registry.counter(
     "L7 request batches classified through the fused device path "
     "(label parser: http|kafka)",
 )
+l7_device_transfers_total = registry.counter(
+    "cilium_tpu_l7_device_transfers_total",
+    "Host-device array transfers of the fused L7 walk's request batches "
+    "(direction=h2d: packed-buffer uploads, d2h: mask pulls; label "
+    "parser: http|kafka) — one each per lane chunk",
+)
 
 # -- policyd-flows (verdict attribution) families -------------------------
 rule_hits_total = registry.counter(

@@ -232,20 +232,11 @@ def fuse_dfas(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("max_len",))
-def dfa_match_batch_fused(
-    trans: jnp.ndarray,  # [Q, 256] int32 (stacked fields, absolute ids)
-    accept_lo: jnp.ndarray,  # [Q] uint32
-    accept_hi: jnp.ndarray,  # [Q] uint32
-    starts: jnp.ndarray,  # [B] int32 per-row start state
-    str_bytes: jnp.ndarray,  # [B, max_len] uint8 (or int32)
-    lengths: jnp.ndarray,  # [B] int32 (-1 = fail closed)
-    max_len: int,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Single-byte walk with PER-ROW start states: one dispatch
-    classifies every field of the whole batch against its own
-    sub-automaton of the stacked table. Named scopes label the device
-    ops: ``table_flatten``, ``dfa_walk`` (the loop) and ``dfa_step``."""
+def _walk_single(trans, accept_lo, accept_hi, starts, str_bytes, lengths,
+                 max_len: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The single-byte walk's body, shared by its three-array and
+    packed entry points. Reads only the first ``max_len`` columns of
+    ``str_bytes``."""
     with jax.named_scope("table_flatten"):
         flat = trans.reshape(-1)
     state = starts
@@ -264,21 +255,11 @@ def dfa_match_batch_fused(
     return lo, hi
 
 
-@functools.partial(jax.jit, static_argnames=("max_len",))
-def dfa_match_batch_pair(
-    pair: jnp.ndarray,  # [Q, 257*257] int32 stride-2 table
-    accept_lo: jnp.ndarray,  # [Q] uint32
-    accept_hi: jnp.ndarray,  # [Q] uint32
-    starts: jnp.ndarray,  # [B] int32 per-row start state
-    str_bytes: jnp.ndarray,  # [B, max_len] uint8 (or int32), 0-padded
-    lengths: jnp.ndarray,  # [B] int32 (-1 = fail closed)
-    max_len: int,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Stride-2 walk: ceil(max_len/2) chained gathers instead of
-    max_len. Tail bytes past the string length are substituted with the
-    identity symbol IN-KERNEL, so the packed buffers stay 0-padded and
-    no post-step select is needed. Named scopes label the device ops:
-    ``table_flatten``, ``dfa_walk`` (the loop) and ``dfa_step``."""
+def _walk_pair(pair, accept_lo, accept_hi, starts, str_bytes, lengths,
+               max_len: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The stride-2 walk's body, shared by its three-array and packed
+    entry points. A column at or past ``max_len`` is read only where
+    the row's length masks it to the pad symbol."""
     with jax.named_scope("table_flatten"):
         flat = pair.reshape(-1)
     state = starts
@@ -297,6 +278,107 @@ def dfa_match_batch_pair(
     lo = jnp.where(ok, jnp.take(accept_lo, state), jnp.uint32(0))
     hi = jnp.where(ok, jnp.take(accept_hi, state), jnp.uint32(0))
     return lo, hi
+
+
+@functools.partial(jax.jit, static_argnames=("max_len",))
+def dfa_match_batch_fused(
+    trans: jnp.ndarray,  # [Q, 256] int32 (stacked fields, absolute ids)
+    accept_lo: jnp.ndarray,  # [Q] uint32
+    accept_hi: jnp.ndarray,  # [Q] uint32
+    starts: jnp.ndarray,  # [B] int32 per-row start state
+    str_bytes: jnp.ndarray,  # [B, max_len] uint8 (or int32)
+    lengths: jnp.ndarray,  # [B] int32 (-1 = fail closed)
+    max_len: int,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Single-byte walk with PER-ROW start states: one dispatch
+    classifies every field of the whole batch against its own
+    sub-automaton of the stacked table. Named scopes label the device
+    ops: ``table_flatten``, ``dfa_walk`` (the loop) and ``dfa_step``."""
+    return _walk_single(trans, accept_lo, accept_hi, starts, str_bytes,
+                        lengths, max_len)
+
+
+@functools.partial(jax.jit, static_argnames=("max_len",))
+def dfa_match_batch_pair(
+    pair: jnp.ndarray,  # [Q, 257*257] int32 stride-2 table
+    accept_lo: jnp.ndarray,  # [Q] uint32
+    accept_hi: jnp.ndarray,  # [Q] uint32
+    starts: jnp.ndarray,  # [B] int32 per-row start state
+    str_bytes: jnp.ndarray,  # [B, max_len] uint8 (or int32), 0-padded
+    lengths: jnp.ndarray,  # [B] int32 (-1 = fail closed)
+    max_len: int,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Stride-2 walk: ceil(max_len/2) chained gathers instead of
+    max_len. Tail bytes past the string length are substituted with the
+    identity symbol IN-KERNEL, so the packed buffers stay 0-padded and
+    no post-step select is needed. Named scopes label the device ops:
+    ``table_flatten``, ``dfa_walk`` (the loop) and ``dfa_step``."""
+    return _walk_pair(pair, accept_lo, accept_hi, starts, str_bytes,
+                      lengths, max_len)
+
+
+# ---------------------------------------------------------------------------
+# Packed walks: one buffer up, one result down per dispatch
+# ---------------------------------------------------------------------------
+
+# A packed walk row is the string's max_len 0-padded bytes followed by
+# PACK_HEADER bytes: its length, then its start state, each a
+# little-endian int32. Pad rows carry length -1 (empty accept mask).
+# One array per dispatch instead of three: each upload and each output
+# buffer costs a fixed ~0.1-0.35 ms on a TPU v5e, whatever its size.
+PACK_HEADER = 8
+
+
+def pack_walk_rows(str_bytes: np.ndarray, lengths: np.ndarray,
+                   starts: np.ndarray, rows: int) -> np.ndarray:
+    """[n, max_len] uint8 bytes, [n] lengths and [n] start states →
+    uint8 [rows, max_len + PACK_HEADER], rows n.. being pad rows."""
+    n, max_len = str_bytes.shape
+    buf = np.zeros((rows, max_len + PACK_HEADER), np.uint8)
+    buf[:n, :max_len] = str_bytes
+    header = buf[:, max_len:].view("<i4")
+    header[:n, 0] = lengths
+    header[n:, 0] = -1
+    header[:n, 1] = starts
+    return buf
+
+
+def _unpack_header(packed: jnp.ndarray, max_len: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """→ (lengths [B] int32, starts [B] int32) of a packed buffer."""
+    b = packed.shape[0]
+    words = packed[:, max_len : max_len + PACK_HEADER].reshape(b, 2, 4)
+    header = jax.lax.bitcast_convert_type(words, jnp.int32)
+    return header[:, 0], header[:, 1]
+
+
+@functools.partial(jax.jit, static_argnames=("max_len",))
+def dfa_match_packed_fused(
+    trans: jnp.ndarray,  # [Q, 256] int32 (stacked fields, absolute ids)
+    accept_lo: jnp.ndarray,  # [Q] uint32
+    accept_hi: jnp.ndarray,  # [Q] uint32
+    packed: jnp.ndarray,  # [B, max_len + PACK_HEADER] uint8 (pack_walk_rows)
+    max_len: int,
+) -> jnp.ndarray:
+    """``dfa_match_batch_fused`` on one packed buffer → uint32 [2, B]:
+    the accept masks' low and high words."""
+    lengths, starts = _unpack_header(packed, max_len)
+    return jnp.stack(_walk_single(trans, accept_lo, accept_hi, starts,
+                                  packed, lengths, max_len))
+
+
+@functools.partial(jax.jit, static_argnames=("max_len",))
+def dfa_match_packed_pair(
+    pair: jnp.ndarray,  # [Q, 257*257] int32 stride-2 table
+    accept_lo: jnp.ndarray,  # [Q] uint32
+    accept_hi: jnp.ndarray,  # [Q] uint32
+    packed: jnp.ndarray,  # [B, max_len + PACK_HEADER] uint8 (pack_walk_rows)
+    max_len: int,
+) -> jnp.ndarray:
+    """``dfa_match_batch_pair`` on one packed buffer → uint32 [2, B]:
+    the accept masks' low and high words."""
+    lengths, starts = _unpack_header(packed, max_len)
+    return jnp.stack(_walk_pair(pair, accept_lo, accept_hi, starts,
+                                packed, lengths, max_len))
 
 
 class DeviceDFATable:

@@ -24,14 +24,20 @@ from cilium_tpu.l7.http_policy import _DEVICE_BATCH_MIN
 from cilium_tpu.l7.kafka_policy import _mask_ids
 from cilium_tpu.l7.regex_compile import compile_patterns_cached
 from cilium_tpu.ops import dfa as dfa_mod
+from cilium_tpu.observe.profiler import DeviceProfiler
 from cilium_tpu.ops.dfa import (
     DFA_INTERN_CAP,
     L7_LEN_LADDER,
     DeviceDFATable,
     dfa_intern_stats,
+    dfa_match_batch_fused,
+    dfa_match_batch_pair,
+    dfa_match_packed_fused,
+    dfa_match_packed_pair,
     fuse_dfas,
     intern_fused_table,
     len_rung,
+    pack_walk_rows,
     strings_to_batch,
     strings_to_batch_u8,
 )
@@ -228,8 +234,10 @@ class TestOnOffParity:
         kernels must be unreachable."""
         def _boom(*a, **k):
             raise AssertionError("fused kernel invoked with L7DeviceBatch off")
-        monkeypatch.setattr(l7rt, "dfa_match_batch_fused", _boom)
-        monkeypatch.setattr(l7rt, "dfa_match_batch_pair", _boom)
+        monkeypatch.setattr(dfa_mod, "dfa_match_batch_fused", _boom)
+        monkeypatch.setattr(dfa_mod, "dfa_match_batch_pair", _boom)
+        monkeypatch.setattr(l7rt, "dfa_match_packed_fused", _boom)
+        monkeypatch.setattr(l7rt, "dfa_match_packed_pair", _boom)
         pol = HTTPPolicy(_HTTP_RULES)
         assert pol._fused_table is None  # not even built
         pol.check_batch(_mixed_requests(200))
@@ -423,3 +431,188 @@ class TestRuntimeOption:
         assert not l7rt.device_batch_enabled()
         assert l7rt.shared_pipeline() is None
         assert pending.result()[0].tolist() == [1]  # drained, not dropped
+
+
+# ---------------------------------------------------------------------------
+# Packed walk: one buffer up, one [2, lanes] result down per lane chunk
+# ---------------------------------------------------------------------------
+
+# two fields: a method-like one capped at 8 bytes, a path-like one at
+# the walk's rung, so rows overlong for their field's cap but not for
+# the rung are walked too
+_PACK_FIELDS = (["GET", "POST", "[A-Z]+"], ["/a[a-c]*", "/b.*x", "[a-c/]*", "/c/.*"])
+
+
+def _pack_table(kind):
+    dfas = [compile_patterns(p) for p in _PACK_FIELDS]
+    fused = fuse_dfas(dfas) if kind == "pair" else fuse_dfas(dfas, pair_cap_elems=0)
+    table = DeviceDFATable(("pack", kind), fused)
+    assert table.has_pair == (kind == "pair")
+    return table
+
+
+def _pack_fields(n_req, rung, seed):
+    """Two fields of ``n_req`` strings whose longest is exactly ``rung``
+    bytes, with rows overlong for the rung and for the first field's
+    cap, empty rows, and rows that match."""
+    rng = random.Random(seed)
+    methods, paths = [], []
+    for i in range(n_req):
+        methods.append(rng.choice([b"GET", b"POST", b"PUT", b"", b"DELETEDELETE"]))
+        n = rng.choice([0, 1, rng.randint(1, rung), rung, rung + 3])
+        body = bytes(rng.choice(b"abcx/") for _ in range(max(0, n - 2)))
+        paths.append((rng.choice([b"/a", b"/b", b"/c", b"zz"]) + body)[:n])
+    paths[0] = b"/" + b"a" * (rung - 1)
+    return [(methods, 8), (paths, rung)]
+
+
+def _three_array_masks(table, fields, rung, lanes):
+    """The three-array path as it was before the packed walk: bytes,
+    lengths and start states uploaded apart, lo and hi pulled apart.
+    → ([lanes] uint32 lo, [lanes] uint32 hi, live rows)."""
+    n_req = len(fields[0][0])
+    flat = [s for values, _ in fields for s in values]
+    sb, lens = strings_to_batch_u8(flat, rung)
+    for f, (_, cap) in enumerate(fields):
+        seg = lens[f * n_req : (f + 1) * n_req]
+        seg[seg > cap] = -1
+    live = len(flat)
+    psb = np.zeros((lanes, rung), np.uint8)
+    psb[:live] = sb
+    plens = np.full(lanes, -1, np.int32)
+    plens[:live] = lens
+    pstarts = np.zeros(lanes, np.int32)
+    pstarts[:live] = np.repeat(table.starts_host, n_req)
+    if table.has_pair:
+        lo, hi = dfa_match_batch_pair(
+            table.pair, table.accept_lo, table.accept_hi, pstarts, psb, plens, rung)
+    else:
+        lo, hi = dfa_match_batch_fused(
+            table.trans, table.accept_lo, table.accept_hi, pstarts, psb, plens, rung)
+    return np.asarray(lo), np.asarray(hi), (sb, lens, live)
+
+
+def _transfers(direction, parser="http"):
+    return metrics.l7_device_transfers_total.get(
+        {"direction": direction, "parser": parser})
+
+
+class TestPackedWalk:
+    @pytest.mark.parametrize("lanes", L7_LANE_RUNGS)
+    @pytest.mark.parametrize("rung", L7_LEN_LADDER + (256,))
+    @pytest.mark.parametrize("kind", ["pair", "single"])
+    def test_bit_identical_to_three_array_kernels(self, kind, rung, lanes):
+        """Every length rung × lane rung × table kind: the packed kernel
+        equals the three-array kernel on every row, pad rows included,
+        and ``submit`` picks the same rungs and returns the same masks."""
+        table = _pack_table(kind)
+        n_req = (lanes // 2) * 3 // 8 if lanes > L7_LANE_RUNGS[0] else 150
+        fields = _pack_fields(n_req, rung, seed=rung * 7 + lanes)
+        lo, hi, (sb, lens, live) = _three_array_masks(table, fields, rung, lanes)
+        assert lane_rung(live) == lanes and live < lanes
+        assert (lens == -1).any() and (lens == 0).any() and lo.any()
+
+        starts = np.repeat(table.starts_host, n_req)
+        packed = pack_walk_rows(sb, lens, starts, lanes)
+        assert packed.shape == (lanes, rung + dfa_mod.PACK_HEADER)
+        walk = dfa_match_packed_pair if table.has_pair else dfa_match_packed_fused
+        got = np.asarray(walk(
+            table.pair if table.has_pair else table.trans,
+            table.accept_lo, table.accept_hi, packed, rung))
+        assert got.dtype == np.uint32 and got.shape == (2, lanes)
+        assert np.array_equal(got[0], lo) and np.array_equal(got[1], hi)
+        assert not got[:, live:].any()  # pad rows: empty masks
+
+        pipe = L7Pipeline(depth=1)
+        masks = pipe.submit(table, fields).result()
+        assert {(k[2], k[3]) for k in pipe._seen_shapes} == {(lanes, rung)}
+        want = lo[:live].astype(np.uint64) | (hi[:live].astype(np.uint64) << np.uint64(32))
+        assert np.array_equal(np.concatenate(masks), want)
+
+    @pytest.mark.parametrize("kind", ["pair", "single"])
+    def test_multi_chunk_submit(self, kind):
+        """Past the top lane rung a submit walks several chunks; the
+        masks equal one three-array walk over every row."""
+        table = _pack_table(kind)
+        top = L7_LANE_RUNGS[-1]
+        n_req = top // 2 + 300  # 2 fields: one full chunk and a tail
+        fields = _pack_fields(n_req, 16, seed=3)
+        lo, hi, (_, _, live) = _three_array_masks(table, fields, 16, 2 * n_req)
+        pipe = L7Pipeline(depth=1)
+        h2d0, d2h0 = _transfers("h2d"), _transfers("d2h")
+        m1, m2 = pipe.submit(table, fields).result()
+        want = lo.astype(np.uint64) | (hi.astype(np.uint64) << np.uint64(32))
+        assert np.array_equal(np.concatenate([m1, m2]), want)
+        assert {k[2] for k in pipe._seen_shapes} == {top, lane_rung(live - top)}
+        assert (_transfers("h2d") - h2d0, _transfers("d2h") - d2h0) == (2, 2)
+
+    @pytest.mark.parametrize("parser", ["http", "kafka"])
+    @pytest.mark.parametrize("n_req", [_DEVICE_BATCH_MIN, 171, 2000])
+    def test_check_batch_on_equals_off(self, parser, n_req):
+        """The policies' answers with L7DeviceBatch on (the packed walk)
+        against off, from the smallest device batch to a 16k-lane one."""
+        if parser == "http":
+            reqs = _mixed_requests(n_req)
+            make = lambda: HTTPPolicy(_HTTP_RULES)  # noqa: E731
+        else:
+            rng = random.Random(n_req)
+            reqs = [KafkaRequest(
+                api_key=rng.choice([0, 1, 2]), topic=rng.choice(["orders", "audit", "x" * 200]),
+                client_id=rng.choice(["svc-a", "", "c" * 300]), src_identity=rng.choice([17, 99]),
+            ) for _ in range(n_req)]
+            rules = [(KafkaRule(api_key="fetch", topic="orders"), None),
+                     (KafkaRule(role="produce", topic="audit", client_id="svc-a"), {17})]
+            make = lambda: KafkaACL(rules)  # noqa: E731
+        off = make().check_batch(reqs)
+        l7rt.set_device_batch(True)
+        batches0 = metrics.l7_batches_total.get({"parser": parser})
+        on = make()
+        assert on._fused_table is not None
+        assert np.array_equal(off, on.check_batch(reqs))
+        assert metrics.l7_batches_total.get({"parser": parser}) == batches0 + 1
+
+
+class TestDeviceTransfers:
+    def _table(self):
+        return DeviceDFATable(("xfer",), fuse_dfas([compile_patterns(["/a.*"])]))
+
+    @pytest.mark.parametrize("chunks", [1, 2, 3])
+    def test_one_upload_and_one_pull_per_chunk(self, chunks):
+        top = L7_LANE_RUNGS[-1]
+        n = (chunks - 1) * top + 5
+        pipe = L7Pipeline(depth=1)
+        h2d0, d2h0 = _transfers("h2d", "kafka"), _transfers("d2h", "kafka")
+        pending = pipe.submit(self._table(), [([b"/a"] * n, 16)], parser="kafka")
+        assert (_transfers("h2d", "kafka") - h2d0, _transfers("d2h", "kafka") - d2h0) == (chunks, 0)
+        (mask,) = pending.result()
+        assert mask.shape == (n,) and (mask == 1).all()
+        assert (_transfers("h2d", "kafka") - h2d0, _transfers("d2h", "kafka") - d2h0) == (chunks, chunks)
+
+    def test_profiler_sampled_batch_counts_the_same(self):
+        prof = DeviceProfiler(sample_every=1)
+        pipe = L7Pipeline(depth=1)
+        pipe.profiler = prof
+        h2d0, d2h0 = _transfers("h2d"), _transfers("d2h")
+        (mask,) = pipe.submit(self._table(), [([b"/a", b"/b"], 16)]).result()
+        assert mask.tolist() == [1, 0]
+        assert (_transfers("h2d") - h2d0, _transfers("d2h") - d2h0) == (1, 1)
+        (sample,) = prof._ring
+        assert sample.site == "l7" and sample.notes["chunks"] == 1
+        assert sample.notes["rungs"] == [L7_LANE_RUNGS[0]]
+
+    def test_prewarm_compiles_the_packed_programs(self, monkeypatch):
+        """Prewarm compiles the packed walk at every rung submit can
+        pick, so a request batch never runs an unwarmed program."""
+        seen = []
+        real = l7rt.dfa_match_packed_pair
+
+        def spy(pair, lo, hi, packed, rung):
+            seen.append((packed.shape, rung))
+            return real(pair, lo, hi, packed, rung)
+        monkeypatch.setattr(l7rt, "dfa_match_packed_pair", spy)
+        table = _pack_table("pair")
+        warmed = L7Pipeline(depth=1).prewarm(table, [8, 32])
+        assert warmed == 2 * len(L7_LANE_RUNGS) == len(seen)
+        assert sorted(seen) == sorted(
+            ((lanes, rung + dfa_mod.PACK_HEADER), rung)
+            for rung in (16, 32) for lanes in L7_LANE_RUNGS)

@@ -184,6 +184,20 @@ def _dfa_pair_walk(topo):
     )
 
 
+def _dfa_packed_walk(kernel, table_width):
+    def lower(topo):
+        from cilium_tpu.ops import dfa
+
+        a = _one_chip(topo)
+        return getattr(dfa, kernel).lower(
+            a((L7_STATES, table_width), jnp.int32),
+            a((L7_STATES,), jnp.uint32), a((L7_STATES,), jnp.uint32),
+            a((L7_LANES, L7_LEN + dfa.PACK_HEADER), jnp.uint8), max_len=L7_LEN,
+        )
+    lower.__name__ = f"_{kernel}"
+    return lower
+
+
 def _ct_step(topo):
     from cilium_tpu.datapath.device_ct import DeviceCTState, ct_step
 
@@ -199,6 +213,8 @@ def _ct_step(topo):
 @pytest.mark.parametrize("lower", [
     _lookup_batch, _process_flows_wide, _process_flows_wide_2x2,
     _sweep_device_matrix, _dfa_pair_walk, _ct_step,
+    _dfa_packed_walk("dfa_match_packed_pair", 257 * 257),
+    _dfa_packed_walk("dfa_match_packed_fused", 256),
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_compiles_for_v5e(chip, lower):
     lowered = lower(chip)
