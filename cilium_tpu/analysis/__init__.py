@@ -9,7 +9,7 @@ paper's target (≥100M verdicts/s, p99 <50µs) silently:
 - Family B (lock discipline): lock-order cycles, blocking ops and
   callbacks under locks, guard inconsistency — see ``locks``.
 - Family C (stable-API contracts): option discipline, stable-literal
-  drift, bench metric-key direction — see ``contracts``. Family C and
+  drift — see ``contracts``. Family C and
   the one-edge-deep inter-procedural variants of TPU001/LOCK002 run on
   a package-wide call graph (``callgraph``).
 

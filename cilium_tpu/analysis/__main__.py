@@ -7,8 +7,7 @@ Usage::
         [--rules TPU001,LOCK002] [--all] [--changed [REF]]
 
 Exit codes: 0 = clean against baseline; 1 = new findings; 2 = usage /
-internal error. With no paths, analyzes the cilium_tpu package plus
-the sibling ``bench.py`` (the BENCH001 surface).
+internal error. With no paths, analyzes the cilium_tpu package.
 
 ``--changed [REF]`` is the incremental mode: the full set is still
 parsed and call-graphed (cross-module rules need whole-package
@@ -39,14 +38,6 @@ from .baseline import (
 def _repo_root() -> str:
     """Directory containing the package (where relpaths anchor)."""
     return os.path.dirname(default_target())
-
-
-def _default_paths() -> List[str]:
-    paths = [default_target()]
-    bench = os.path.join(_repo_root(), "bench.py")
-    if os.path.isfile(bench):
-        paths.append(bench)
-    return paths
 
 
 def _changed_relpaths(ref: str) -> List[str]:
@@ -118,7 +109,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     except SystemExit as e:
         return 2 if e.code not in (0, None) else 0
 
-    paths = args.paths or _default_paths()
+    paths = args.paths or [default_target()]
     rules = args.rules.split(",") if args.rules else None
     changed: Optional[List[str]] = None
     if args.changed is not None:

@@ -4,8 +4,7 @@ These rules machine-check the ROADMAP's standing contracts against the
 canonical tables in ``cilium_tpu/contracts.py``. Unlike Families A/B
 (per-module pattern rules), every rule here is cross-file by nature:
 an option registered in ``option.py`` is judged by what ``daemon.py``
-and ``tests/`` do with it, and a bench metric key is judged by what
-``bench.py --diff``'s direction engine would do to it three PRs later.
+and ``tests/`` do with it.
 
 Rules
 -----
@@ -30,17 +29,9 @@ OPT002  option-gated mutation: state mutated ONLY inside an
 API001  stable-literal drift: int-valued ``REASON_*``/``ATTR_*``
         constants, ``.phase("...")`` literals, and ``BUCKET_LADDER``
         definitions anywhere in the package must match the canonical
-        tables — these names and numbers are diffed across bench
-        rounds and stored in flow logs, so drift is an incompatible
-        wire/schema change. Error.
-BENCH001  bench metric-key direction: a computed (``round(...)``)
-        top-level metric key in ``bench.py`` must carry a suffix the
-        ``--diff`` direction engine understands (higher-is-better
-        ``_vps/_rps/_lps/_qps/_ratio`` vs lower-is-better
-        ``_ms/_us/_ns/_s/_pct``) or be a declared bookkeeping key;
-        rate-shaped names ending ``_per_s``/``_ops_s`` are flagged as
-        errors — their ``_s`` suffix reads as a *duration*, so a
-        throughput gain would be reported as a regression.
+        tables — these names and numbers are read by the benchmark's
+        span readers and stored in flow logs, so drift is an
+        incompatible wire/schema change. Error.
 
 Canonical tables resolve from the analyzed set first (a module
 literally defining ``WIRE_REASONS``/``OPTION_BOOT_FIELDS``/... wins,
@@ -66,16 +57,12 @@ from .core import (
 
 _REASON_RE = re.compile(r"^REASON_[A-Z0-9_]+$")
 _ATTR_RE = re.compile(r"^ATTR_[A-Z0-9_]+$")
-_RATE_AS_DURATION_RE = re.compile(r"(_per_s|_ops_s)$")
 
 _CANON_NAMES = (
     "TRACE_PHASES",
     "WIRE_REASONS",
     "ATTR_CODES",
     "BUCKET_LADDER",
-    "DIFF_HIGHER_SUFFIXES",
-    "DIFF_LOWER_SUFFIXES",
-    "BENCH_BOOKKEEPING_KEYS",
     "OPTION_BOOT_FIELDS",
     "METRIC_BOUNDED_LABEL_KEYS",
     "JOURNAL_KINDS",
@@ -179,7 +166,8 @@ def _check_api001(
                                 f"drifts from the canonical value "
                                 f"{table[name]} — these codes are "
                                 "STABLE wire/API numbers (stored flow "
-                                "logs and bench --diff key on them)",
+                                "logs and monitor consumers key on "
+                                "them)",
                             ))
                 if name == "BUCKET_LADDER" and ladder:
                     try:
@@ -192,7 +180,8 @@ def _check_api001(
                             f"BUCKET_LADDER {got} drifts from the "
                             f"canonical ladder {ladder} — the rungs are "
                             "a compile-count contract (jit program "
-                            "budget, bench compile_s); import it from "
+                            "budget, benchmark compile_s); import it "
+                            "from "
                             "cilium_tpu.contracts instead of redefining",
                         ))
             if (
@@ -209,107 +198,11 @@ def _check_api001(
                     "API001", SEV_ERROR, node.lineno,
                     f"trace phase literal {node.args[0].value!r} is not "
                     "in the canonical TRACE_PHASES vocabulary — phase "
-                    "names are STABLE (bench --diff compares waterfalls "
-                    "by name; TRACES_PR*.md archives key on them); add "
-                    "it to cilium_tpu/contracts.py deliberately or use "
-                    "an existing phase",
+                    "names are STABLE (benchmark/scopes.py and the "
+                    "policyd.<kind>.<phase> annotations key on them); "
+                    "add it to cilium_tpu/contracts.py deliberately or "
+                    "use an existing phase",
                 ))
-
-
-# -------------------------------------------------------------- BENCH001
-
-
-def _is_round_call(expr: ast.AST) -> bool:
-    return (
-        isinstance(expr, ast.Call)
-        and isinstance(expr.func, ast.Name)
-        and expr.func.id == "round"
-    )
-
-
-def _check_bench_key(
-    mod: ModuleSource,
-    key: str,
-    line: int,
-    higher: Tuple[str, ...],
-    lower: Tuple[str, ...],
-    bookkeeping: Set[str],
-    findings: List[Finding],
-) -> None:
-    if key in bookkeeping or key.startswith("calib_"):
-        return
-    if _RATE_AS_DURATION_RE.search(key):
-        findings.append(mod.finding(
-            "BENCH001", SEV_ERROR, line,
-            f"metric key '{key}' is a rate but ends in '_s', which the "
-            "--diff direction engine reads as a duration (lower-is-"
-            "better) — a throughput gain would be reported as a "
-            "regression; rename with a rate suffix "
-            f"({'/'.join(higher)})",
-        ))
-        return
-    if key.endswith(tuple(higher) + tuple(lower)):
-        return
-    findings.append(mod.finding(
-        "BENCH001", SEV_WARNING, line,
-        f"computed metric key '{key}' carries no --diff direction "
-        f"suffix (higher: {'/'.join(higher)}; lower: "
-        f"{'/'.join(lower)}) — it silently falls out of regression "
-        "coverage; suffix it, or add it to BENCH_BOOKKEEPING_KEYS if "
-        "it describes the scenario rather than measuring it",
-    ))
-
-
-def _check_bench001(
-    modules: Sequence[ModuleSource],
-    canon: _Canon,
-    findings: List[Finding],
-) -> None:
-    higher = tuple(canon.get("DIFF_HIGHER_SUFFIXES") or ())
-    lower = tuple(canon.get("DIFF_LOWER_SUFFIXES") or ())
-    bookkeeping = set(canon.get("BENCH_BOOKKEEPING_KEYS") or ())
-    if not higher or not lower:
-        return
-    for mod in modules:
-        if os.path.basename(mod.path) != "bench.py":
-            continue
-        for node in ast.walk(mod.tree):
-            if isinstance(node, ast.Dict):
-                items = [
-                    (k.value, v)
-                    for k, v in zip(node.keys, node.values)
-                    if isinstance(k, ast.Constant)
-                    and isinstance(k.value, str)
-                ]
-                round_items = [
-                    (k, v) for k, v in items if _is_round_call(v)
-                ]
-                # record-like: an explicit artifact record, or a dict
-                # computing ≥3 rounded measurements (sub-bench results
-                # merged into records by the caller)
-                record_like = (
-                    any(k == "metric" for k, _ in items)
-                    or len(round_items) >= 3
-                )
-                if not record_like:
-                    continue
-                for key, value in round_items:
-                    _check_bench_key(
-                        mod, key, value.lineno, higher, lower,
-                        bookkeeping, findings,
-                    )
-            elif (
-                isinstance(node, ast.Assign)
-                and len(node.targets) == 1
-                and isinstance(node.targets[0], ast.Subscript)
-                and isinstance(node.targets[0].slice, ast.Constant)
-                and isinstance(node.targets[0].slice.value, str)
-                and _is_round_call(node.value)
-            ):
-                _check_bench_key(
-                    mod, node.targets[0].slice.value, node.lineno,
-                    higher, lower, bookkeeping, findings,
-                )
 
 
 # ---------------------------------------------------------------- OPT001
@@ -778,7 +671,6 @@ def analyze_contracts(
     findings: List[Finding] = []
     canon = _Canon(modules)
     _check_api001(modules, canon, findings)
-    _check_bench001(modules, canon, findings)
     _check_opt001(modules, canon, findings)
     _check_opt002(modules, findings)
     return findings

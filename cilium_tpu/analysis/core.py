@@ -1,8 +1,7 @@
 """Shared infrastructure for the policyd-lint static analyzers.
 
 Everything here is pure stdlib (``ast`` + ``re``): the analyzers must
-run in CI contexts (and in bench --lint pre-flight) without importing
-jax or touching a device.
+run in CI contexts without importing jax or touching a device.
 
 Concepts
 --------

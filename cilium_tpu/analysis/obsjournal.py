@@ -3,7 +3,7 @@
 The lifecycle journal (observe/journal.py) is a fleet-wide wire
 surface: per-node journals are merged into one timeline across nodes
 running DIFFERENT commits, bugtool ``events.json`` archives are diffed
-offline, and bench --chaos asserts against specific kinds. A kind
+offline, and the tests assert against specific kinds. A kind
 literal that drifts from ``cilium_tpu.contracts.JOURNAL_KINDS`` is
 therefore worse than a typo — ``EventJournal.emit`` raises on it at
 runtime, from INSIDE a lifecycle callback (quarantine, drain, watchdog

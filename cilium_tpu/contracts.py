@@ -2,15 +2,14 @@
 
 One importable, pure-stdlib module holding every name/number the
 ROADMAP's standing contracts freeze: trace phase names, wire drop
-reasons, attribution codes, the bucket ladder, the bench --diff
-direction vocabulary, and the option↔DaemonConfig boot-field map.
+reasons, attribution codes, the bucket ladder, and the
+option↔DaemonConfig boot-field map.
 
 Two kinds of consumers:
 
 - runtime code imports what it can single-source directly (the
-  pipeline's ``BUCKET_LADDER`` and bench's ``--diff`` suffix tuples
-  live HERE and only here);
-- ``cilium_tpu.analysis.contracts`` (rules API001 / BENCH001 / OPT001)
+  pipeline's ``BUCKET_LADDER`` lives HERE and only here);
+- ``cilium_tpu.analysis.contracts`` (rules API001 / OPT001)
   machine-checks every *other* literal in the package against these
   tables at lint time, so wire constants that must stay put in their
   defining modules (monitor/events.py, ops/verdict.py) cannot drift
@@ -26,10 +25,11 @@ from __future__ import annotations
 from typing import Dict, Optional, Tuple
 
 # -- trace phases (observe/tracer.py) ---------------------------------
-# Phase names are a stable waterfall vocabulary: TRACES_PR*.md files
-# and bench --diff's phase comparison key on them across commits, so
-# renaming one is a breaking change (observe/README.md). API001 checks
-# every ``bt.phase("...")`` literal against this set.
+# Phase names are a stable waterfall vocabulary: the benchmark's span
+# readers (benchmark/metrics/, benchmark/scopes.py) key on the
+# ``policyd.<kind>.<phase>`` annotations built from them, so renaming
+# one is a breaking change (observe/README.md). API001 checks every
+# ``bt.phase("...")`` literal against this set.
 TRACE_PHASES: Tuple[str, ...] = (
     "rebuild",
     "prepare",
@@ -91,8 +91,9 @@ ATTR_CODE_NAMES: Dict[int, str] = {
 # -- dispatch bucket ladder (datapath/pipeline.py) --------------------
 # The fixed padded-shape set for chunked CT-miss dispatch. A rung
 # joins the jit cache per static-arg combination, so the ladder is a
-# compile-count contract: bench compile_s and the ≤ ladder×directions
-# program-count assertion both depend on it (policyd-autotune).
+# compile-count contract: the benchmark's compile_s and the ≤
+# ladder×directions program-count assertion both depend on it
+# (policyd-autotune).
 BUCKET_LADDER: Tuple[int, ...] = (1024, 2048, 4096, 8192)
 
 # Effective pipeline depth domain: the DispatchAutoTune controller
@@ -100,36 +101,6 @@ BUCKET_LADDER: Tuple[int, ...] = (1024, 2048, 4096, 8192)
 # config validator caps the static depth at MAX.
 PIPELINE_DEPTH_MIN = 1
 PIPELINE_DEPTH_MAX = 64
-
-# -- bench --diff direction vocabulary (bench.py) ---------------------
-# A metric key's unit suffix decides which direction is a regression.
-# Keys matching neither tuple are NOT compared — BENCH001 flags
-# computed measurements that would silently fall out of regression
-# coverage, and flags rate-shaped names (``*_per_s``, ``*_ops_s``)
-# whose ``_s`` suffix would be mis-read as a duration.
-DIFF_HIGHER_SUFFIXES: Tuple[str, ...] = (
-    "_vps", "_rps", "_lps", "_qps", "_ratio",
-)
-DIFF_LOWER_SUFFIXES: Tuple[str, ...] = ("_ms", "_us", "_ns", "_s", "_pct")
-
-# Environment/bookkeeping keys --diff must never fail a round on
-# (calib_*-prefixed keys are skipped separately: they ARE the
-# normalizers).
-DIFF_SKIP_KEYS: Tuple[str, ...] = (
-    "value", "vs_baseline", "build_s", "compile_s",
-    "host_cpus", "sample_every",
-)
-
-# Keys BENCH001 additionally accepts without a direction suffix:
-# scenario descriptors and diff-internal fields, not measurements a
-# regression gate should compare across rounds.
-BENCH_BOOKKEEPING_KEYS: Tuple[str, ...] = DIFF_SKIP_KEYS + (
-    # traffic-mix descriptors: they parameterize the scenario (a
-    # changed mix invalidates the round, it isn't a regression)
-    "allow_fraction", "deny_fraction", "shed_fraction",
-    # --diff's own verdict-entry fields
-    "prev", "cur", "threshold_pct",
-)
 
 # -- metric label cardinality (observe/, analysis OBS002) -------------
 # Label keys whose values are allowed to be INTERPOLATED at a metric
@@ -158,8 +129,8 @@ METRIC_BOUNDED_LABEL_KEYS: Tuple[str, ...] = (
 # -- lifecycle journal event kinds (observe/journal.py) ---------------
 # The structured lifecycle-event vocabulary (policyd-journal). STABLE:
 # fleet timelines are merged across nodes running different commits,
-# bugtool events.json archives are diffed offline, and bench --chaos
-# asserts against specific kinds — renaming one breaks all three.
+# bugtool events.json archives are diffed offline, and the tests
+# assert against specific kinds — renaming one breaks all three.
 # OBS003 checks every ``emit(kind="...")`` literal in the package
 # against this table (and flags stale rows no emitter references).
 JOURNAL_KINDS: Tuple[str, ...] = (

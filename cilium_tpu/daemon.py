@@ -1744,7 +1744,7 @@ class Daemon:
         # Materialized policymaps are NOT included: across a restart
         # identity numbering may differ, so the daemon path could not
         # soundly adopt them (the engine-level API still takes them for
-        # same-process restores, e.g. the bench restart measurement).
+        # same-process restores).
         self._save_compiled_snapshot()
         # CT snapshot beside it (policyd-survive): same debounce shape
         self._save_ct_snapshot()

@@ -905,8 +905,9 @@ class DatapathPipeline:
         # XDP maps. Updated together with self._tables.
         # REBUILD-INTERNAL: these two feed the _dp_state snapshot below
         # and are only safe to read directly in single-threaded contexts
-        # (tests, bench setup). Dispatch paths MUST read _dp_state — a
-        # separate-attribute read can pair a fresh flag with old tables.
+        # (tests, benchmark setup). Dispatch paths MUST read _dp_state —
+        # a separate-attribute read can pair a fresh flag with old
+        # tables.
         self._pf_empty: Tuple[bool, bool] = (True, True)
         self._v6_fused = False  # v6 merged deny+identity trie present
         # ATOMIC read snapshot for the lock-free dispatch paths:
@@ -1033,7 +1034,7 @@ class DatapathPipeline:
         # clean-batch streak clears them; at the threshold the ladder
         # descends one level. recover_after_clean clean batches at a
         # degraded level probe one level back up. Both knobs are plain
-        # attributes so tests/bench shrink the windows.
+        # attributes so tests shrink the windows.
         self.breaker_threshold = 3
         self.recover_after_clean = 32
         self._breaker_faults = 0
@@ -1275,7 +1276,7 @@ class DatapathPipeline:
         enqueue/complete timings; OFF restores the configured static
         depth and drops the tuner (the per-batch cost returns to one
         ``self._tuner is None`` read). ``epoch`` shrinks the decision
-        interval for tests/bench convergence runs."""
+        interval for tests' convergence runs."""
         if max_depth is not None:
             self.pipeline_max_depth = max(1, int(max_depth))
         if not on:
@@ -2695,8 +2696,8 @@ class DatapathPipeline:
             # transfer-ledger attribution for the column/row patches:
             # O(k) logical bytes where the dense re-place moved the
             # full [N, S/32] matrix (control-plane cadence, counted
-            # unconditionally — rebuilds are rare and the delta is the
-            # number the stretch bench diffs)
+            # unconditionally — rebuilds are rare and the delta is
+            # the O(k) transfer evidence)
             _metrics.device_transfer_bytes_total.inc(
                 {"direction": "h2d"}, float(nbytes)
             )
@@ -2907,7 +2908,7 @@ class DatapathPipeline:
             self._tries = None
 
     def wait_epoch_swap(self, timeout: float = 60.0) -> bool:
-        """Block until no shadow build is in flight (tests/bench
+        """Block until no shadow build is in flight (tests'
         convergence helper; the daemon never calls this). Returns False
         on timeout. The installed generation becomes dispatch-visible
         on the NEXT rebuild() — call it after this returns."""
@@ -3719,8 +3720,9 @@ class DatapathPipeline:
         ep_count = max(1, len(self._endpoints))
         spans = self._chunk_spans(b, bucketed=bucketed, ndev=ndev)
         # pad-lane accounting on EVERY dispatch path (bucketed rung pad
-        # and the unbucketed sharded ndev-rounding alike) — bench.py
-        # derives pad_waste_pct as pad / (live + pad)
+        # and the unbucketed sharded ndev-rounding alike) — the
+        # benchmark's verdict_kernel_roofline counts live lanes as
+        # lanes less pad
         pad_lanes = sum(p for _, _, p in spans) - b
         if pad_lanes:
             _metrics.dispatch_pad_lanes_total.inc(

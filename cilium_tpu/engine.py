@@ -703,17 +703,6 @@ class PolicyEngine:
         t.join(timeout)
         return not t.is_alive()
 
-    def wait_device(self) -> None:
-        """Block until every in-flight device update (row scatters,
-        sel_match windows, table patches) has completed. The refresh
-        path itself never calls this — updates stay enqueue-only — but
-        tests and the churn bench need a completion edge to measure the
-        true device RTT of a delta."""
-        with self._lock:
-            device = self._device
-        if device is not None:
-            jax.block_until_ready((device.id_bits, device.sel_match))
-
     # -- compiled-state snapshots (pinned-map persistence analog) -------
     def save_snapshot(self, path: str, mats=None) -> None:
         """Persist the compiled arrays (+ optional materialized

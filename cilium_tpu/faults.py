@@ -35,7 +35,7 @@ Taxonomy (mirrors how the pipeline classifies REAL errors):
   a bug; callers re-raise these raw.
 
 Stdlib-only by design: the registry must be importable (and armable)
-before jax, from the bench watchdog, and inside the proxy."""
+before jax and inside the proxy."""
 
 from __future__ import annotations
 
@@ -44,8 +44,8 @@ import threading
 import zlib
 from typing import Dict, Iterable, List, Optional, Tuple
 
-# Stable site names (wired into the hot path; bench --chaos and the
-# failsafe tests key on these)
+# Stable site names (wired into the hot path; the failsafe, overload
+# and survive tests key on these)
 SITE_H2D = "h2d"            # staging write + host→device upload
 SITE_DISPATCH = "dispatch"  # async XLA enqueue of the fused program
 SITE_COMPLETE = "complete"  # host pull of un-pulled device results
@@ -237,14 +237,14 @@ class FaultHub:
         if kind is None:
             return
         # metric outside the hub lock; imported lazily so the registry
-        # stays importable before the package (bench watchdog, proxy)
+        # stays importable before the package (proxy)
         from . import metrics as _metrics
 
         _metrics.pipeline_faults_total.inc({"site": site, "kind": kind})
         raise (PoisonedFault if kind == KIND_POISONED else TransientFault)(site)
 
     def snapshot(self) -> Dict:
-        """Introspection for /healthz, traces, and bench --chaos."""
+        """Introspection for /healthz, traces, and the tests."""
         with self._lock:
             return {
                 "active": self.active,

@@ -98,7 +98,7 @@ class ClusterIdentityAllocator(Allocator):
         # (API threads) — heartbeat's orphan sweep must not reap them
         self._inflight_reserves: Set[int] = set()
         # per-instance outcome counts: the metric family is process-
-        # global, but in-process multi-node tests/bench want per-node
+        # global, but in-process multi-node tests want per-node
         self._counts: dict = {}
         super().__init__(
             backend,

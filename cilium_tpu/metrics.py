@@ -528,9 +528,9 @@ ct_restored_entries_total = registry.counter(
 restart_downtime_seconds = registry.gauge(
     "cilium_tpu_restart_downtime_seconds",
     "Wall time from the start of restore_state() to the first verdict "
-    "batch completed after a restart (set once per process; the bench "
-    "--chaos restart round reports the same quantity cross-process as "
-    "restart_downtime_ms)",
+    "batch completed after a restart (set once per process; the "
+    "journal's restore_done event carries the same span as "
+    "downtime_ms)",
 )
 drain_seconds = registry.histogram(
     "cilium_tpu_drain_seconds",

@@ -246,7 +246,7 @@ def render_waterfall(
     ``phases`` is the trace's ordered (name, rel_start_ns, dur_ns)
     list; bars are positioned by start offset so overlap/ordering is
     visible at a glance. Phase names are a stable API (observe/
-    README.md) — bench rounds diff these waterfalls across commits.
+    README.md).
     """
     total = max(1, int(total_ns))
     name_w = max((len(p[0]) for p in phases), default=4)

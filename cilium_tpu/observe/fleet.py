@@ -270,8 +270,8 @@ class TelemetryExchange:
 
 
 def aggregate(frames: Mapping[str, Dict], *, now: Optional[float] = None) -> Dict:
-    """Fold live frames into the fleet scoreboard (the GET /fleet body
-    and the bench --fleetobs substrate). Refreshes the
+    """Fold live frames into the fleet scoreboard (the GET /fleet
+    body). Refreshes the
     ``fleet_nodes_reporting`` gauge as a side effect."""
     ref = time.time() if now is None else float(now)
     rows: List[Dict] = []

@@ -27,7 +27,7 @@ as a machine-checkable sequence. Three layers, bottom-up:
   ``CLUSTER_JOURNAL_PATH`` (the telemetry exchange's sibling);
   ``merge_timelines`` folds every live peer frame into one
   HLC-total-ordered fleet timeline (``cilium-tpu fleet timeline``,
-  ``GET /fleet/timeline``, bench --fleetobs ``timeline_merge_ok``).
+  ``GET /fleet/timeline``).
 
 This module is ONLY imported when the ``LifecycleJournal`` runtime
 option turns on — the daemon's OFF path never touches it (the

@@ -18,7 +18,8 @@ times nothing. When enabled, each completed trace feeds the per-phase
 latency histograms in metrics.py and (only while a monitor listener
 is attached) publishes one TraceSummary event through the hub.
 
-Phase names are a STABLE API: bench rounds compare waterfalls across
+Phase names are a STABLE API: the benchmark's span readers
+(``benchmark/metrics/``, ``benchmark/scopes.py``) read them across
 commits, so renaming a phase is a breaking change (observe/README.md).
 
 While a tracer is active every span and batch half is also mirrored as

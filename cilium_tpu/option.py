@@ -90,8 +90,8 @@ class DaemonConfig:
     # Sampling period of the DeviceProfiling runtime option (policyd-
     # prof): every Nth completed batch pays the block_until_ready
     # sandwiches that decompose dispatch RTT into h2d / device_compute
-    # / d2h. 1 = profile every batch (bench --prof); 64 keeps sampled
-    # overhead under the <2% budget on pipeline_e2e_vps.
+    # / d2h. 1 = profile every batch; 64 keeps sampled overhead under
+    # the <2% budget on pipeline_e2e_vps.
     profile_sample_every: int = 64
     # Boot-time values of the remaining datapath-gated runtime options.
     # Every OPTION_SPECS entry maps to exactly one of these fields (or

@@ -1,9 +1,9 @@
 """Test harness config.
 
 Tests run on a virtual 8-device CPU mesh so multi-chip sharding code
-paths execute without TPU hardware (chip_smoke.py and bench.py run on
-the real chip and must NOT import this). Env must be set before jax
-initializes its backends.
+paths execute without TPU hardware (chip_smoke.py and the benchmark
+run on the real chip and must NOT import this). Env must be set before
+jax initializes its backends.
 """
 
 import os

@@ -27,7 +27,7 @@ from cilium_tpu.federation import (
 )
 from cilium_tpu.kvstore.allocator import Allocator
 from cilium_tpu.kvstore.backend import InMemoryBackend, InMemoryStore
-from cilium_tpu.kvstore.filestore import FlakyBackend
+from cilium_tpu.kvstore.filestore import FileBackend, FlakyBackend
 from cilium_tpu.kvstore.paths import IDENTITIES_PATH
 from cilium_tpu.ops.lpm import ip_strings_to_u32
 from cilium_tpu.utils.backoff import Backoff
@@ -149,6 +149,83 @@ class TestClusterIdentityAllocator:
         assert st.get("retry", 0) >= 1 and st["adopted"] == 1
         assert flaky.op_errors >= 1
 
+    def test_three_nodes_on_filestore_converge_injective(self, tmp_path):
+        """Three nodes race allocate() over one key set on the durable
+        SQLite store, not the in-memory double: all three id maps
+        agree and no id is handed out twice."""
+        path = str(tmp_path / "kvstore.sqlite")
+        names = ["node-0", "node-1", "node-2"]
+        backends = [FileBackend(path, n, lease_ttl=60.0) for n in names]
+        allocs = [
+            ClusterIdentityAllocator(
+                be, IDENTITIES_PATH, node_name=n, min_id=256,
+                max_id=1 << 16, backoff_factory=_fast_backoff,
+            )
+            for be, n in zip(backends, names)
+        ]
+        keys = [f"k8s:app=fs-{i}" for i in range(16)]
+        got = [{} for _ in allocs]
+
+        def worker(i):
+            for k in keys:
+                got[i][k] = allocs[i].allocate(k)[0]
+
+        ts = [threading.Thread(target=worker, args=(i,))
+              for i in range(len(allocs))]
+        try:
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(30.0)
+            assert not any(t.is_alive() for t in ts)
+            assert got[0] == got[1] == got[2]
+            assert len(set(got[0].values())) == len(keys)
+        finally:
+            for a in allocs:
+                a.close()
+            for be in backends:
+                be.close()
+
+    def test_partition_and_lease_expiry_during_contention(self):
+        """Both faults land inside one two-node allocation storm: node
+        b is partitioned before it starts, node c (holding ids) dies
+        mid-storm. The maps still agree and stay injective, no id of
+        the dead node is reused, and GC reaps exactly its ids."""
+        store = InMemoryStore()
+        c = _alloc(store, "c")
+        c_ids = {c.allocate(f"k8s:app=ephemeral-{i}")[0] for i in range(8)}
+        a = _alloc(store, "a")
+        flaky = FlakyBackend(InMemoryBackend(store, "b"))
+        b = ClusterIdentityAllocator(
+            flaky, IDENTITIES_PATH, node_name="b",
+            min_id=256, max_id=4096, backoff_factory=_fast_backoff,
+        )
+        keys = [f"k8s:app=storm-{i}" for i in range(24)]
+        got = {"a": {}, "b": {}}
+
+        def worker(alloc, tag):
+            for k in keys:
+                got[tag][k] = alloc.allocate(k)[0]
+
+        flaky.fail(True)
+        ts = [threading.Thread(target=worker, args=(a, "a")),
+              threading.Thread(target=worker, args=(b, "b"))]
+        for t in ts:
+            t.start()
+        time.sleep(0.005)
+        store.revoke_lease(c.backend.lease_id)  # node c dies mid-storm
+        time.sleep(0.005)
+        flaky.fail(False)
+        for t in ts:
+            t.join(30.0)
+        assert not any(t.is_alive() for t in ts)
+        assert got["a"] == got["b"]
+        ids = set(got["a"].values())
+        assert len(ids) == len(keys)
+        assert not ids & c_ids
+        assert set(a.run_gc()) == c_ids
+        assert flaky.op_errors >= 1
+
     def test_backoff_exhausted_raises_federation_error(self):
         store = InMemoryStore()
         flaky = FlakyBackend(InMemoryBackend(store, "b"))
@@ -232,6 +309,39 @@ class TestEpochExchange:
             2, timeout=5.0, min_nodes=2,
             pump=lambda: (xb.publish(), xb.pump()),
         )
+
+    def test_filestore_fleet_epoch_barrier(self, tmp_path):
+        """Three nodes on the durable SQLite store publish a new policy
+        epoch; the barrier returns once the fleet minimum reaches it."""
+        path = str(tmp_path / "kvstore.sqlite")
+        names = ["node-0", "node-1", "node-2"]
+        backends = [FileBackend(path, n, lease_ttl=60.0) for n in names]
+        epochs = [{"v": 0} for _ in names]
+        xs = [
+            EpochExchange(be, n, cluster="t",
+                          epoch_source=(lambda e=e: e["v"]))
+            for be, n, e in zip(backends, names, epochs)
+        ]
+
+        def pump_all():
+            for x in xs:
+                x.publish()
+                x.pump()
+
+        try:
+            pump_all()
+            assert not xs[0].wait_cluster_epoch(
+                1, timeout=0.1, min_nodes=3, pump=pump_all)
+            for e in epochs:
+                e["v"] = 1
+            assert xs[0].wait_cluster_epoch(
+                1, timeout=10.0, min_nodes=3, pump=pump_all)
+            assert xs[0].cluster_epoch() == 1
+        finally:
+            for x in xs:
+                x.close()
+            for be in backends:
+                be.close()
 
     def test_dead_node_drops_from_view(self):
         store = InMemoryStore()

@@ -310,6 +310,42 @@ class TestTelemetryExchange:
         a.close()
         b.close()
 
+    def test_three_node_fleet_then_a_dead_node_ages_out(self):
+        """Three nodes publish over one store and a separate aggregator
+        folds them: every frame arrives and the fleet rate is the sum
+        of the nodes' rates. One node then dies without revoking its
+        lease; once its frame is stale the scoreboard and the
+        fleet_nodes_reporting gauge drop to the two survivors."""
+        store = InMemoryStore()
+        names = ["node-0", "node-1", "node-2"]
+        nodes = [
+            TelemetryExchange(InMemoryBackend(store, n[-1]), n, cluster="t")
+            for n in names
+        ]
+        agg = TelemetryExchange(
+            InMemoryBackend(store, "agg"), "agg", cluster="t")
+        try:
+            rates = [100.0, 200.0, 300.0]
+            for ex, vps in zip(nodes, rates):
+                assert ex.publish({"vps": vps}, ts=100.0)
+            agg.pump()
+            frames = agg.frames(now=101.0)
+            assert set(frames) == set(names)
+            out = aggregate(frames, now=101.0)
+            assert out["nodes_reporting"] == 3
+            assert out["fleet_vps"] == sum(rates)
+
+            for ex, vps in zip(nodes[:2], rates):
+                assert ex.publish({"vps": vps}, ts=104.0)
+            agg.pump()
+            out = aggregate(agg.frames(now=105.0, stale_s=3.0), now=105.0)
+            assert out["nodes_reporting"] == 2
+            assert {r["node"] for r in out["nodes"]} == set(names[:2])
+            assert metrics.fleet_nodes_reporting.get() == 2.0
+        finally:
+            for ex in nodes + [agg]:
+                ex.close()
+
     def test_version_mismatch_counted_rejected(self):
         a, b = self._pair()
         a.store.update_local_key_sync(

@@ -301,6 +301,39 @@ class TestExchangeAndPublisher:
             pa.stop()
             pb.stop()
 
+    def test_three_node_skewed_chain_merges_in_causal_order(self):
+        """Three journals whose wall clocks run 120s ahead, on time and
+        120s behind take turns in one causal chain. Every node hears
+        the fleet before its own step, so each node's merged timeline
+        replays the emission order across all three."""
+        store = InMemoryStore()
+        nodes = {
+            "node-a": self._node(store, "node-a", _Clock(1120.0)),
+            "node-b": self._node(store, "node-b", _Clock(1000.0)),
+            "node-c": self._node(store, "node-c", _Clock(880.0)),
+        }
+        chain = [
+            ("node-a", "drain_begin"), ("node-b", "boot"),
+            ("node-c", "ct_restore"), ("node-a", "drain_end"),
+            ("node-b", "rebuild"), ("node-c", "restore_done"),
+        ]
+        try:
+            for name, kind in chain:
+                for _j, pub in nodes.values():
+                    pub.publish_once()
+                nodes[name][0].emit(kind=kind)
+                nodes[name][1].publish_once()
+            for _j, pub in nodes.values():
+                pub.publish_once()
+            for _j, pub in nodes.values():
+                merged = pub.merged_timeline(limit=64)
+                assert {e["node"] for e in merged} == set(nodes)
+                assert [e["kind"] for e in merged] == [k for _, k in chain]
+                assert timeline_consistent(merged)
+        finally:
+            for _j, pub in nodes.values():
+                pub.stop()
+
     def test_frames_age_out_and_reject_drift(self):
         store = InMemoryStore()
         ja, pa = self._node(store, "node-a", _Clock(100.0))

@@ -344,6 +344,53 @@ class TestShedGate:
         monkeypatch.undo()
         p1.result()  # drain
 
+    def test_faulted_submit_storm_loses_no_verdict(self):
+        """An oversubscribed submit storm with queue_full and stall
+        faults injected: every submitted flow comes back with a
+        verdict, each DROP_PREFILTER lane is counted once under reason
+        144 (its admission or its prefilter producer), and clean
+        traffic afterwards walks the ladder back to sharded with the
+        pre-storm verdicts."""
+        gp, _base, idents = _gated_world(deadline_ms=250.0)
+        gp.set_attribution(True)  # counts the device producer's drops
+        gp.breaker_threshold = 2
+        gp.recover_after_clean = 3
+        gp.retry_min_s = gp.retry_max_s = 0.001
+        storm = [_flows(idents, 96, seed=31 + i) for i in range(4)]
+        v_warm, _ = gp.process(*storm[0])
+        gp._shed_walk(
+            ipv4_to_bytes(storm[0][0]), storm[0][2], storm[0][3], family=4
+        )
+        producers = ("admission", "prefilter")
+
+        def reason_144():
+            return sum(_m.drop_reasons_total.get(
+                {"reason": "prefilter", "producer": p}) for p in producers)
+
+        r0 = reason_144()
+        gp.set_stall_ms(100.0)
+        try:
+            _faults.hub.fail(
+                _faults.SITE_QUEUE_FULL, _faults.KIND_TRANSIENT, times=2)
+            _faults.hub.fail(
+                _faults.SITE_STALL, _faults.KIND_TRANSIENT, times=2)
+            pendings = [gp.submit(*bt) for bt in storm]
+            results = [p.result() for p in pendings]
+            submitted = sum(bt[0].shape[0] for bt in storm)
+            assert sum(v.shape[0] for v, _red in results) == submitted
+            dropped = sum(int((v == DROP_PREFILTER).sum()) for v, _ in results)
+            assert dropped > 0
+            assert reason_144() - r0 == dropped
+            rounds = 0
+            while gp.pipeline_mode != "sharded" and rounds < 64:
+                gp.process(*storm[rounds % 2])
+                rounds += 1
+        finally:
+            gp.set_stall_ms(0)
+        v_after, _ = gp.process(*storm[0])
+        assert gp.pipeline_mode == "sharded"
+        np.testing.assert_array_equal(v_after, v_warm)
+
     def test_shed_table_published_and_retracted(self):
         gp, _base, _idents = _gated_world()
         shed = gp._dp_state[7]

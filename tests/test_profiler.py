@@ -1,20 +1,17 @@
 """policyd-prof: device-time profiler cost contract, Histogram
 quantiles, registry concurrency, new-family exposition, the
-/profile + `cilium-tpu top` surfaces, and bench --diff verdicts.
+/profile + `cilium-tpu top` surfaces.
 
 The acceptance contract (ISSUE 13): disabled profiling costs one
 attribute read per batch (the exact pre-option programs); sampled
 batches decompose dispatch RTT into h2d/device_compute/d2h with rung
-occupancy notes; `bench.py --diff` exits non-zero past the threshold
-and passes a self-diff.
+occupancy notes.
 """
 
 from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import tarfile
 import threading
 
@@ -38,8 +35,6 @@ from cilium_tpu.policy.api import (
     rule,
 )
 from cilium_tpu.policy.repository import Repository
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _pipeline():
@@ -399,94 +394,3 @@ class TestSurfaces:
                 assert prof["enabled"] is False
         finally:
             d.shutdown()
-
-
-# ------------------------------------------------------ bench --diff
-
-
-def _artifact(tmp_path, name, **overrides):
-    rec = {
-        "metric": "policy verdicts/sec at 100 rules",
-        "value": 5.0e5,
-        "unit": "verdicts/s",
-        "backend": "cpu",
-        "host_cpus": 8,
-        "pipeline_e2e_vps": 500000.0,
-        "dispatch_rtt_ms": 2.0,
-        "calib_py_loops_per_s": 1.0e7,
-        "calib_sha256_mb_per_s": 900.0,
-    }
-    rec.update(overrides)
-    path = tmp_path / name
-    path.write_text(json.dumps(rec) + "\n")
-    return str(path)
-
-
-def _run_diff(prev, cur, *extra):
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    return subprocess.run(
-        [sys.executable, "bench.py", "--diff", prev, "--cur", cur, *extra],
-        capture_output=True, text=True, cwd=REPO, env=env,
-    )
-
-
-class TestBenchDiff:
-    def test_self_diff_passes_and_regression_exits_nonzero(self, tmp_path):
-        prev = _artifact(tmp_path, "prev.json")
-        same = _artifact(tmp_path, "same.json")
-        res = _run_diff(prev, same)
-        assert res.returncode == 0, res.stdout + res.stderr
-        verdict = json.loads(res.stdout.strip().splitlines()[-1])["diff"]
-        assert verdict["verdict"] == "pass"
-        assert verdict["compared"] >= 2
-        assert verdict["regressions"] == []
-
-        bad = _artifact(tmp_path, "bad.json", pipeline_e2e_vps=200000.0)
-        res = _run_diff(prev, bad)
-        assert res.returncode != 0, res.stdout + res.stderr
-        verdict = json.loads(res.stdout.strip().splitlines()[-1])["diff"]
-        assert verdict["verdict"] == "regression"
-        keys = {r["key"] for r in verdict["regressions"]}
-        assert "pipeline_e2e_vps" in keys
-
-    def test_diff_records_direction_threshold_and_backend(self, tmp_path):
-        """The in-process half: direction inference, threshold
-        boundaries, and the incomparable-backend escape."""
-        import bench
-
-        prev = bench._load_artifact(_artifact(tmp_path, "p.json"))
-        # a LOWER-is-better key regressing (latency up 2x)
-        cur = dict(prev)
-        cur["dispatch_rtt_ms"] = 4.0
-        assert bench._diff_records(prev, cur, 25.0) != 0
-        # inside the threshold → pass
-        cur["dispatch_rtt_ms"] = 2.2
-        assert bench._diff_records(prev, cur, 25.0) == 0
-        # higher-is-better improvement is never a regression
-        cur = dict(prev)
-        cur["pipeline_e2e_vps"] = 9.0e5
-        assert bench._diff_records(prev, cur, 25.0) == 0
-        # backend mismatch: incomparable, exit 0, no false verdict
-        cur = dict(prev)
-        cur["backend"] = "tpu"
-        cur["pipeline_e2e_vps"] = 1.0
-        assert bench._diff_records(prev, cur, 25.0) == 0
-
-    def test_host_key_normalization_on_cpu_count_change(self, tmp_path):
-        """Host-bound keys scale by the calibration ratio when
-        host_cpus differ — a faster diff host must not masquerade as a
-        workload improvement (or hide a regression)."""
-        import bench
-
-        prev = bench._load_artifact(_artifact(
-            tmp_path, "p.json", kafka_acl_rps=1000.0))
-        cur = dict(prev)
-        cur["host_cpus"] = 16
-        cur["calib_py_loops_per_s"] = 2.0e7  # 2x host
-        # 2x throughput on a 2x host = flat after normalization
-        cur["kafka_acl_rps"] = 2000.0
-        assert bench._diff_records(prev, cur, 25.0) == 0
-        # flat raw throughput on a 2x host = a 50% normalized loss
-        cur["kafka_acl_rps"] = 1000.0
-        assert bench._diff_records(prev, cur, 25.0) != 0

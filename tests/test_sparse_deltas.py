@@ -14,15 +14,10 @@ Reference analog: the ipcache BPF map's per-key upsert/delete
 
 from __future__ import annotations
 
-import json
-import os
 import random
-import subprocess
-import sys
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from cilium_tpu import metrics as _metrics
 from cilium_tpu.datapath import DatapathPipeline
@@ -51,8 +46,6 @@ from cilium_tpu.policy.api import (
     rule,
 )
 from cilium_tpu.policy.repository import Repository
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _canon(cidr: str) -> str:
@@ -565,58 +558,6 @@ class TestSparseCTReplay:
             vb = dense.process(s, e, d, pr, sports=sp)
             np.testing.assert_array_equal(va[0], vb[0])
             np.testing.assert_array_equal(va[1], vb[1])
-
-
-# ---------------------------------------------------------------------------
-# bench --stretch tier: one-line JSON schema regression
-
-
-class TestBenchStretchTier:
-    def test_stretch_emits_schema_complete_json(self):
-        """--stretch at toy scale must exit 0 with a single-line JSON
-        carrying the BENCH001 regression surface: direction-suffixed
-        top-level stretch sub-metrics, the sparse single-update
-        percentiles, the h2d byte attribution, and the 1M-rung record."""
-        env = dict(os.environ)
-        env.pop("XLA_FLAGS", None)  # production shape: real device count
-        env.update({
-            "JAX_PLATFORMS": "cpu",
-            "BENCH_STRETCH_RULES": "300",
-            "BENCH_STRETCH_IDS": "400",
-            "BENCH_STRETCH_1M_IDS": "500",
-            "BENCH_STRETCH_1M_RULES": "100",
-        })
-        res = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), "--stretch"],
-            capture_output=True, text=True, timeout=420, cwd=REPO, env=env,
-        )
-        assert res.returncode == 0, res.stdout + res.stderr
-        lines = [
-            ln for ln in res.stdout.strip().splitlines() if ln.startswith("{")
-        ]
-        assert lines, res.stdout + res.stderr
-        payload = json.loads(lines[-1])
-        assert payload["unit"] == "s"
-        for key in (
-            "stretch_100k_materialize_s", "stretch_100k_compile_s",
-            "stretch_100k_vps",
-            "sparse_update_ident_p50_ms", "sparse_update_ident_p99_ms",
-            "sparse_update_selector_p50_ms", "sparse_update_selector_p99_ms",
-            "sparse_update_trie_p50_ms", "sparse_update_trie_p99_ms",
-            "sparse_rebuild_phase_dense_ms", "sparse_rebuild_phase_ms",
-            "sparse_ident_h2d_bytes", "sparse_selector_h2d_bytes",
-            "sparse_trie_h2d_bytes", "sparse_trie_patches_applied",
-            "backend", "host_cpus", "build_s",
-        ):
-            assert key in payload, key
-        assert payload["stretch_100k"]["identities"] == 400
-        assert payload["stretch_100k"]["rules"] == 300
-        assert payload["stretch_1m"]["identities"] == 500
-        assert payload["value"] == payload["stretch_100k_materialize_s"]
-        # the trie leg must actually have taken the patch path
-        assert payload["sparse_trie_patches_applied"] > 0
-        assert payload["sparse_trie_h2d_bytes"] > 0
-        assert payload["sparse_update_trie_p50_ms"] > 0
 
 
 # ---------------------------------------------------------------------------

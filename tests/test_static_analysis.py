@@ -24,7 +24,6 @@ from cilium_tpu.analysis.core import Finding, ModuleSource
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PKG = os.path.join(REPO, "cilium_tpu")
-BENCH = os.path.join(REPO, "bench.py")
 FIXTURES = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "analysis_fixtures"
 )
@@ -52,10 +51,10 @@ def run_cli(*args, **popen):
 
 def test_package_clean_against_baseline():
     """THE gate: no analyzer finding outside the checked-in baseline —
-    and the whole-package + bench.py run stays under the 10s budget
-    that keeps it viable as a per-commit preflight."""
+    and the whole-package run stays under the 10s budget that keeps it
+    viable as a per-commit preflight."""
     t0 = time.monotonic()
-    findings = analyze_paths([PKG, BENCH])
+    findings = analyze_paths([PKG])
     elapsed = time.monotonic() - t0
     counts, _ = load_baseline(default_baseline_path())
     fresh = new_findings(findings, counts)
@@ -67,8 +66,8 @@ def test_package_clean_against_baseline():
     )
     assert elapsed < 10.0, (
         f"package-wide analysis took {elapsed:.1f}s — the <10s budget "
-        "is part of the policyd-contracts contract (bench --lint and "
-        "the CI gate run it on every commit)"
+        "is part of the policyd-contracts contract (the CI gate runs "
+        "it on every commit)"
     )
 
 
@@ -78,6 +77,22 @@ def test_cli_package_exits_zero():
     payload = json.loads(res.stdout)
     assert payload["tool"] == "policyd-lint"
     assert payload["new"] == 0
+
+
+def test_cli_no_paths_analyzes_the_package_alone(monkeypatch):
+    """With no paths the CLI lints the cilium_tpu package and no file
+    beside it."""
+    from cilium_tpu.analysis import __main__ as cli
+
+    seen = []
+
+    def record(paths, **_kw):
+        seen.append([os.path.realpath(p) for p in paths])
+        return []
+
+    monkeypatch.setattr(cli, "analyze_paths", record)
+    assert cli.main(["--format", "json"]) == 0
+    assert seen == [[os.path.realpath(PKG)]]
 
 
 def test_cli_seeded_violation_exits_nonzero(tmp_path):
@@ -562,34 +577,11 @@ def test_api001_fixture():
     assert all(x.severity == "error" for x in f)
 
 
-def test_bench001_fixture():
-    f = analyze_paths([fixture("benchdir/bench.py")])
-    assert lines_of(f, "BENCH001") == [11, 12, 19]
-    assert len(f) == 3  # suffixed / bookkeeping / calib_ / non-record silent
-    by_line = {x.line: x for x in f}
-    assert by_line[11].severity == "error"  # rate read as duration
-    assert "'fixture_ops_s' is a rate but ends in '_s'" in by_line[11].message
-    assert by_line[12].severity == "warning"
-    assert "no --diff direction suffix" in by_line[12].message
-    assert "'fixture_norm'" in by_line[19].message
-
-
-def test_bench001_scoped_to_bench_basename(tmp_path):
-    """The same source under any other filename is out of scope —
-    BENCH001 judges bench.py's artifact records only."""
-    with open(fixture(os.path.join("benchdir", "bench.py"))) as fh:
-        src = fh.read()
-    other = tmp_path / "perf.py"
-    other.write_text(src)
-    assert analyze_paths([str(other)]) == []
-
-
 def test_family_c_repo_stays_clean():
-    """The shipping package + bench.py satisfy every Family C contract
-    outright (no baseline entries, no suppressions)."""
-    f = analyze_paths([PKG, BENCH])
-    for rule in ("OPT001", "OPT002", "API001", "BENCH001", "OBS002",
-                 "OBS003"):
+    """The shipping package satisfies every Family C contract outright
+    (no baseline entries, no suppressions)."""
+    f = analyze_paths([PKG])
+    for rule in ("OPT001", "OPT002", "API001", "OBS002", "OBS003"):
         offenders = [x.render() for x in f if x.rule == rule]
         assert offenders == [], f"{rule} regressions:\n" + "\n".join(offenders)
 

@@ -103,6 +103,43 @@ class TestRestartContinuity:
         finally:
             _stop(dm2)
 
+    def test_journal_tells_the_same_restart_story(self, tmp_path):
+        """With LifecycleJournal on from boot, the restarted daemon's
+        journal agrees with the restore it records: ct_restore carries
+        ct_restore_info()'s basis verdict, and the boot -> restore_done
+        span covers the downtime window and lies inside the wall time
+        from Daemon() to the first verdict (1 ms for the two clocks)."""
+        from cilium_tpu.option import DaemonConfig, get_config, set_config
+
+        saved = get_config()
+        d = str(tmp_path)
+        dm2 = None
+        try:
+            set_config(DaemonConfig(lifecycle_journal=True))
+            dm = Daemon(state_dir=d)
+            _seed(dm)
+            _flows(dm)
+            dm.shutdown()
+
+            t0 = time.perf_counter()
+            dm2 = Daemon(state_dir=d)
+            _flows(dm2)
+            measured_ms = (time.perf_counter() - t0) * 1e3
+            first = {}
+            for e in dm2.events(limit=128)["events"]:
+                first.setdefault(e["kind"], e)
+            info = dm2.ct_restore_info()
+            assert info["basis_match"] is True
+            assert first["ct_restore"]["attrs"]["basis_match"] is True
+            boot, done = first["boot"], first["restore_done"]
+            journal_ms = (done["wall_ts"] - boot["wall_ts"]) * 1e3
+            assert 0.0 < done["attrs"]["downtime_ms"] <= journal_ms + 1.0
+            assert journal_ms <= measured_ms + 1.0
+        finally:
+            set_config(saved)
+            if dm2 is not None:
+                dm2.shutdown()
+
     def test_rule_change_before_first_batch_voids_hold(self, tmp_path):
         """A policy mutation landing after restore but before the first
         batch bumps the revision and voids the restore hold — the
