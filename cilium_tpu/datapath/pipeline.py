@@ -321,6 +321,23 @@ def _v6_lpm_stage(t, peer_bytes, levels: int, prefilter: bool, fused: bool):
     return denied_pf, hit
 
 
+def _flows_tail(t, denied_pf, hit, ep_idx, dport, proto, row_override,
+                ep_count, block, attrib, rule_tab, n_rules, ident_gather):
+    """Identity row from the LPM hit (world on a miss; a trusted overlay
+    row wins and skips the prefilter), then _verdict_tail — the body
+    every process_flows* entry shares."""
+    peer_row = jnp.where(hit > 0, hit - 1, t.world_row)
+    if row_override is not None:
+        trusted = row_override >= 0
+        peer_row = jnp.where(trusted, row_override, peer_row)
+        denied_pf = denied_pf & ~trusted
+    return _verdict_tail(
+        t.policymap, denied_pf, peer_row, ep_idx, dport, proto, ep_count,
+        block, attrib=attrib, rule_tab=rule_tab if attrib else None,
+        n_rules=n_rules, ident_gather=ident_gather,
+    )
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -365,15 +382,9 @@ def process_flows(
     scatter stays on the MXU.
     """
     denied_pf, hit = _v6_lpm_stage(t, peer_bytes, levels, prefilter, fused)
-    peer_row = jnp.where(hit > 0, hit - 1, t.world_row)
-    if row_override is not None:
-        trusted = row_override >= 0
-        peer_row = jnp.where(trusted, row_override, peer_row)
-        denied_pf = denied_pf & ~trusted
-    return _verdict_tail(
-        t.policymap, denied_pf, peer_row, ep_idx, dport, proto, ep_count,
-        block, attrib=attrib, rule_tab=rule_tab if attrib else None,
-        n_rules=n_rules, ident_gather=ident_gather,
+    return _flows_tail(
+        t, denied_pf, hit, ep_idx, dport, proto, row_override, ep_count,
+        block, attrib, rule_tab, n_rules, ident_gather,
     )
 
 
@@ -404,16 +415,145 @@ def process_flows_wide(
     process_flows(levels=4), including the overlay row_override and
     the attrib variant."""
     denied_pf, hit = _v4_lpm_stage(t, peer_u32, prefilter)
-    peer_row = jnp.where(hit > 0, hit - 1, t.world_row)
-    if row_override is not None:
-        trusted = row_override >= 0
-        peer_row = jnp.where(trusted, row_override, peer_row)
-        denied_pf = denied_pf & ~trusted
-    return _verdict_tail(
-        t.policymap, denied_pf, peer_row, ep_idx, dport, proto, ep_count,
-        block, attrib=attrib, rule_tab=rule_tab if attrib else None,
-        n_rules=n_rules, ident_gather=ident_gather,
+    return _flows_tail(
+        t, denied_pf, hit, ep_idx, dport, proto, row_override, ep_count,
+        block, attrib, rule_tab, n_rules, ident_gather,
     )
+
+
+# -- packed verdict chunks: one buffer up, one result down -----------------
+# A chunk travels as ONE int32 [rows, lanes] buffer, field-major so each
+# field is one lane-dense row: the peer address (v4: its host-order u32
+# word; v6: 16 byte rows), then ep_idx, dport and proto, then the
+# overlay path's row_override when it passes one (pad lanes -1). The
+# result is ONE int32 vector: a word a lane (the verdict in its low
+# byte, REDIRECT_BIT, with attribution L4_COVERED_BIT), the [EP, 3]
+# counters, and with attribution the [lanes] rule words and the hit
+# sums. Each transfer and each output buffer has a fixed cost on the
+# chip whatever its size, so a chunk pays it once each way.
+REDIRECT_BIT = 1 << 8
+L4_COVERED_BIT = 1 << 9
+
+
+def flow_rows(family: int, overlay: bool) -> int:
+    """Rows of a packed chunk: the address rows, ep, dport, proto, and
+    row_override when ``overlay``."""
+    return (1 if family == 4 else 16) + 3 + int(overlay)
+
+
+def pack_flow_chunk(buf: np.ndarray, family: int, peer_bytes, ep_idx,
+                    dport, proto, row_override=None) -> np.ndarray:
+    """Write one chunk's m flows into ``buf`` (int32 [flow_rows, lanes],
+    lanes ≥ m) and pad lanes m.. with 0 (row_override -1: a pad lane
+    derives by LPM, never trusts). Returns ``buf``."""
+    m = ep_idx.shape[0]
+    a = 1 if family == 4 else 16
+    if family == 4:
+        buf[0, :m] = _pack_v4_u32(peer_bytes).view(np.int32)
+    else:
+        buf[:a, :m] = peer_bytes.T
+    buf[a, :m] = ep_idx
+    buf[a + 1, :m] = dport
+    buf[a + 2, :m] = proto
+    buf[:, m:] = 0
+    if row_override is not None:
+        buf[a + 3, :m] = row_override
+        buf[a + 3, m:] = -1
+    return buf
+
+
+def unpack_flow_result(res: np.ndarray, lanes: int, ep_count: int,
+                       attrib: bool):
+    """One pulled chunk result → views (words [lanes], counters [EP, 3],
+    rule [lanes] or None, hits [R] or None)."""
+    c = lanes + 3 * ep_count
+    counters = res[lanes:c].reshape(ep_count, 3)
+    if not attrib:
+        return res[:lanes], counters, None, None
+    return res[:lanes], counters, res[c : c + lanes], res[c + lanes :]
+
+
+def _pack_result(out, sharding: Optional[NamedSharding]) -> jnp.ndarray:
+    """_verdict_tail's tuple → the chunk's one int32 result vector, each
+    part placed by ``sharding`` first when the chunk spans a mesh."""
+    verdict, redirect, counters = out[:3]
+    word = (verdict.astype(jnp.int32) & 0xFF) | (
+        redirect.astype(jnp.int32) * REDIRECT_BIT
+    )
+    parts = [word, counters.reshape(-1)]
+    if len(out) > 3:
+        rule, l4x, hits = out[3:]
+        word = word | (l4x.astype(jnp.int32) * L4_COVERED_BIT)
+        parts = [word, parts[1], rule, hits]
+    if sharding is not None:
+        parts = [jax.lax.with_sharding_constraint(x, sharding) for x in parts]
+    return jnp.concatenate(parts)
+
+
+def _packed_cols(packed: jnp.ndarray, a: int):
+    """(ep_idx, dport, proto, row_override or None) rows of a packed
+    chunk whose address takes ``a`` rows (static from its shape)."""
+    ro = packed[a + 3] if packed.shape[0] > a + 3 else None
+    return packed[a], packed[a + 1], packed[a + 2], ro
+
+
+@functools.partial(
+    jax.jit, static_argnames=("ep_count", "block", "prefilter", "attrib",
+                              "n_rules", "ident_gather", "out_sharding")
+)
+def process_flows_wide_packed(
+    t: WideDatapathTables,
+    packed: jnp.ndarray,  # [4 or 5, B] int32 (pack_flow_chunk, family 4)
+    ep_count: int = 1,
+    block: int = 16384,
+    prefilter: bool = True,
+    attrib: bool = False,
+    rule_tab: Optional[jnp.ndarray] = None,  # [N, C_pad] int32
+    n_rules: int = 0,
+    ident_gather: bool = False,
+    out_sharding: Optional[NamedSharding] = None,
+) -> jnp.ndarray:
+    """process_flows_wide on one packed v4 chunk → its int32 result
+    vector (unpack_flow_result), placed by ``out_sharding`` when the
+    chunk spans a mesh."""
+    peer_u32 = jax.lax.bitcast_convert_type(packed[0], jnp.uint32)
+    ep_idx, dport, proto, ro = _packed_cols(packed, 1)
+    denied_pf, hit = _v4_lpm_stage(t, peer_u32, prefilter)
+    return _pack_result(_flows_tail(
+        t, denied_pf, hit, ep_idx, dport, proto, ro, ep_count, block,
+        attrib, rule_tab, n_rules, ident_gather,
+    ), out_sharding)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "ep_count", "block", "levels", "prefilter", "fused", "attrib",
+        "n_rules", "ident_gather", "out_sharding",
+    ),
+)
+def process_flows_packed(
+    t: DatapathTables,
+    packed: jnp.ndarray,  # [levels + 3 or + 4, B] int32 (pack_flow_chunk)
+    ep_count: int = 1,
+    block: int = 16384,
+    levels: int = 16,
+    prefilter: bool = True,
+    fused: bool = False,
+    attrib: bool = False,
+    rule_tab: Optional[jnp.ndarray] = None,
+    n_rules: int = 0,
+    ident_gather: bool = False,
+    out_sharding: Optional[NamedSharding] = None,
+) -> jnp.ndarray:
+    """process_flows on one packed chunk → its int32 result vector."""
+    ep_idx, dport, proto, ro = _packed_cols(packed, levels)
+    denied_pf, hit = _v6_lpm_stage(t, packed[:levels].T, levels, prefilter,
+                                   fused)
+    return _pack_result(_flows_tail(
+        t, denied_pf, hit, ep_idx, dport, proto, ro, ep_count, block,
+        attrib, rule_tab, n_rules, ident_gather,
+    ), out_sharding)
 
 
 @functools.partial(
@@ -525,6 +665,21 @@ def _pack_v4_u32(peer_bytes: np.ndarray) -> np.ndarray:
     return (b[:, 0] << 24) | (b[:, 1] << 16) | (b[:, 2] << 8) | b[:, 3]
 
 
+@functools.lru_cache(maxsize=16)
+def _chunk_shardings(
+    flow_sharding: NamedSharding,
+) -> Tuple[NamedSharding, NamedSharding]:
+    """(packed chunk, result) shardings on a flow sharding's mesh: the
+    chunk's lanes (dim 1) split over its axis and its rows whole, so a
+    row the kernel takes is placed as a per-array P("flows") batch
+    was; the result replicated, so its one pull reads one device (the
+    lane words are gathered on the device, the counters already are
+    reduced)."""
+    mesh = flow_sharding.mesh
+    return (NamedSharding(mesh, P(None, *flow_sharding.spec)),
+            NamedSharding(mesh, P()))
+
+
 # -- policyd-overload: prefilter shed walk ---------------------------------
 # The coarse admission prefilter (PAPER.md layer 1's XDP prefilter
 # role, drop reason 144): ONE identity LPM walk + ONE gather from the
@@ -569,15 +724,12 @@ def shed_flows(
     return jnp.take(shed_tab.reshape(-1), row * N_SHED_CLASSES + cls) != 0
 
 
-def _pad_flows(pad: int, peer_bytes, *arrays, row_override=None):
-    """Zero-pad a flow batch's arrays to a shape bucket (row_override
-    pads with -1: padded lanes must derive-by-LPM, never trust)."""
+def _pad_flows(pad: int, peer_bytes, *arrays):
+    """Zero-pad a flow batch's arrays to a shape bucket."""
     if pad:
         peer_bytes = np.pad(peer_bytes, ((0, pad), (0, 0)))
         arrays = tuple(np.pad(a, (0, pad)) for a in arrays)
-        if row_override is not None:
-            row_override = np.pad(row_override, (0, pad), constant_values=-1)
-    return (peer_bytes, *arrays, row_override)
+    return (peer_bytes, *arrays)
 
 
 def _bucket_multiple(n: int, ndev: int, floor: int = 1024) -> int:
@@ -765,20 +917,20 @@ class _GatedPending(PendingBatch):
 
 
 class _Enqueued:
-    """Un-pulled device results of one dispatch: per-chunk (verdict,
-    redirect, counters) device arrays plus the spans that produced
-    them — (…, rule, l4_covered, hits) 6-tuples when ``attrib``.
-    ``exact`` marks device counters (and rule-hit sums) usable as-is
-    (no padded lanes polluted them)."""
+    """Un-pulled device results of one dispatch: one packed int32
+    result vector per chunk (unpack_flow_result: lane words, counters,
+    and with ``attrib`` rule words and hit sums) plus the spans that
+    produced them. ``exact`` marks device counters (and rule-hit sums)
+    usable as-is (no padded lanes polluted them)."""
 
     __slots__ = (
         "chunks", "spans", "b", "exact", "ndev", "attrib", "staging", "host",
-        "psample",
+        "psample", "ep_count",
     )
 
     def __init__(
         self, chunks, spans, b, exact, ndev, attrib=False, staging=(),
-        host=None, psample=None,
+        host=None, psample=None, ep_count=1,
     ) -> None:
         self.chunks = chunks
         self.spans = spans
@@ -786,7 +938,8 @@ class _Enqueued:
         self.exact = exact
         self.ndev = ndev
         self.attrib = attrib
-        # staging tuples pinned under this dispatch's device inputs;
+        self.ep_count = ep_count
+        # staging buffers pinned under this dispatch's device inputs;
         # released back to the pipeline's pool at the host pull
         self.staging = staging
         # ladder level 2 (host fallback): (verdict, redirect) computed
@@ -960,13 +1113,13 @@ class DatapathPipeline:
         # the first time a batch actually compiles/warms it)
         self._warm_buckets: set = set()
         # -- policyd-autotune: pre-pinned staging + depth tuner --------
-        # (rung, peer_width) → free-list of rung-sized int32 host
-        # staging tuples (peer_bytes, ep_idx, dports, protos, row_ov).
-        # The bucketed pad half writes into these instead of np.pad
-        # allocations per chunk. A tuple leaves the list at enqueue and
-        # returns at the batch's host pull — never earlier: JAX CPU can
-        # alias aligned numpy memory zero-copy, so reuse before
-        # completion could race the device program's reads.
+        # (rung, rows) → free-list of packed int32 [rows, rung] chunk
+        # buffers (pack_flow_chunk). The bucketed dispatch packs each
+        # chunk into one of these instead of a fresh allocation. A
+        # buffer leaves the list at enqueue and returns at the batch's
+        # host pull — never earlier: JAX CPU can alias aligned numpy
+        # memory zero-copy, so reuse before completion could race the
+        # device program's reads.
         self._staging: Dict[Tuple[int, int], list] = {}
         self._staging_lock = threading.Lock()
         # depth auto-tuner (DispatchAutoTune): OFF by default — the
@@ -1318,38 +1471,32 @@ class DatapathPipeline:
         return snap
 
     # -- policyd-autotune: pre-pinned staging --------------------------
-    # free-list bound per (rung, width): deeper queues keep more tuples
+    # free-list bound per (rung, rows): deeper queues keep more buffers
     # in flight, but depth × chunks stays small — beyond this the
     # allocations were a burst, not steady state, so let them collect
     _STAGING_FREE_CAP = 8
 
-    def _staging_acquire(self, rung: int, width: int):
-        """One rung-sized staging tuple (peer[rung, width], ep, dp, pr,
-        row_override — all int32, matching what prepare() coerces), off
-        the free-list or freshly allocated on first use of a rung."""
-        key = (rung, width)
+    def _staging_acquire(self, rung: int, rows: int) -> np.ndarray:
+        """One packed-chunk staging buffer (int32 [rows, rung], see
+        pack_flow_chunk), off the free-list or freshly allocated on
+        first use of a (rung, rows)."""
+        key = (rung, rows)
         with self._staging_lock:
             free = self._staging.get(key)
             if free:
                 return free.pop()
-        return (
-            np.empty((rung, width), np.int32),
-            np.empty(rung, np.int32),
-            np.empty(rung, np.int32),
-            np.empty(rung, np.int32),
-            np.empty(rung, np.int32),
-        )
+        return np.empty((rows, rung), np.int32)
 
-    def _staging_release(self, bufs_list) -> None:
-        """Return a completed batch's staging tuples to their
+    def _staging_release(self, bufs) -> None:
+        """Return a completed batch's staging buffers to their
         free-lists (called from the host-pull half only — see the
         aliasing note at _staging)."""
-        for bufs in bufs_list:
-            key = (bufs[0].shape[0], bufs[0].shape[1])
+        for buf in bufs:
+            key = (buf.shape[1], buf.shape[0])
             with self._staging_lock:
                 free = self._staging.setdefault(key, [])
                 if len(free) < self._STAGING_FREE_CAP:
-                    free.append(bufs)
+                    free.append(buf)
 
     def _refresh_mesh_locked(self) -> None:
         """Resolve the placement plan to match the sharding/2D requests
@@ -3447,49 +3594,34 @@ class DatapathPipeline:
         flow_sharding, rule_tab=None, n_rules=0, staging=None,
         ident_gather=False, psample=None,
     ):
-        """Pad + upload + enqueue ONE chunk; returns the UN-PULLED
-        device (verdict, redirect, counters) triple. Under sharding
-        the flow arrays are committed split over the mesh's "flows"
-        axis (the tests/test_multichip.py pattern) before the call.
-        ``staging`` (bucketed dispatches only) collects the pre-pinned
-        rung buffers the pad half wrote into, for release at the host
-        pull; padded rungs then cost four memcpys instead of four
-        np.pad allocations. ``psample`` (policyd-prof, the 1-in-N
-        sampled batch only) makes the upload an explicit synchronous
-        device_put so its wall time separates from the async program
-        enqueue — identical avals, so the compiled program is the same
-        one the unsampled path runs."""
+        """Pack + upload + enqueue ONE chunk; returns its UN-PULLED
+        device result vector (unpack_flow_result). The chunk's columns
+        go up as one packed int32 [rows, padded] buffer
+        (pack_flow_chunk); under sharding it is committed with its
+        lanes split over the mesh's "flows" axis, so every row the
+        kernel takes keeps the flows sharding. ``staging`` (bucketed
+        dispatches only) collects the pre-pinned rung buffers packed
+        into, for release at the host pull. ``psample`` (policyd-prof,
+        the 1-in-N sampled batch only) makes the upload an explicit
+        synchronous device_put so its wall time separates from the
+        async program enqueue — identical avals, so the compiled
+        program is the same one the unsampled path runs."""
         if _faults.hub.active:
             _faults.hub.check(_faults.SITE_H2D)
-        pb = peer_bytes[lo:hi]
-        ei = ep_idx[lo:hi]
-        dp = dports[lo:hi]
-        pr = protos[lo:hi]
-        ro = None if row_override is None else row_override[lo:hi]
-        pad = padded - (hi - lo)
-        if pad and staging is not None:
-            bufs = self._staging_acquire(padded, peer_bytes.shape[1])
-            spb, sei, sdp, spr, sro = bufs
-            m = hi - lo
-            spb[:m] = pb
-            spb[m:] = 0
-            sei[:m] = ei
-            sei[m:] = 0
-            sdp[:m] = dp
-            sdp[m:] = 0
-            spr[:m] = pr
-            spr[m:] = 0
-            pb, ei, dp, pr = spb, sei, sdp, spr
-            if ro is not None:
-                # padded lanes must derive-by-LPM, never trust (-1)
-                sro[:m] = ro
-                sro[m:] = -1
-                ro = sro
-            staging.append(bufs)
-        elif pad:
-            pb, ei, dp, pr, ro = _pad_flows(pad, pb, ei, dp, pr,
-                                            row_override=ro)
-        peer = _pack_v4_u32(pb) if family == 4 else pb
+        rows = flow_rows(family, row_override is not None)
+        if staging is not None:
+            buf = self._staging_acquire(padded, rows)
+            staging.append(buf)
+        else:
+            buf = np.empty((rows, padded), np.int32)
+        pack_flow_chunk(
+            buf, family, peer_bytes[lo:hi], ep_idx[lo:hi], dports[lo:hi],
+            protos[lo:hi],
+            None if row_override is None else row_override[lo:hi],
+        )
+        in_sharding = out_sharding = None
+        if flow_sharding is not None:
+            in_sharding, out_sharding = _chunk_shardings(flow_sharding)
         if psample is not None:
             # sampled h2d edge: upload explicitly and wait — the time
             # between here and the post-enqueue ready wait is then pure
@@ -3497,40 +3629,22 @@ class DatapathPipeline:
             # the default device; either way the avals (and therefore
             # the jit cache key / compiled program) are unchanged.
             _t0 = time.perf_counter()
-            peer, ei, dp, pr = jax.block_until_ready(
-                jax.device_put((peer, ei, dp, pr), flow_sharding)
-            )
-            if ro is not None:
-                ro = jax.block_until_ready(
-                    jax.device_put(ro, flow_sharding)
-                )
+            buf = jax.block_until_ready(jax.device_put(buf, in_sharding))
             psample.add_h2d(time.perf_counter() - _t0)
-        elif flow_sharding is not None:
-            peer, ei, dp, pr = jax.device_put(
-                (peer, ei, dp, pr), flow_sharding
-            )
-            if ro is not None:
-                ro = jax.device_put(ro, flow_sharding)
-        elif ro is not None:
-            ro = jnp.asarray(ro)
+        elif in_sharding is not None:
+            buf = jax.device_put(buf, in_sharding)
         attrib = rule_tab is not None
+        fkw = dict(
+            ep_count=ep_count, prefilter=pf_stage, attrib=attrib,
+            rule_tab=rule_tab, n_rules=n_rules, ident_gather=ident_gather,
+            out_sharding=out_sharding,
+        )
         if family == 4:
-            fn = process_flows_wide
-            fargs = (t, peer, ei, dp, pr)
-            fkw = dict(
-                ep_count=ep_count, prefilter=pf_stage, row_override=ro,
-                attrib=attrib, rule_tab=rule_tab, n_rules=n_rules,
-                ident_gather=ident_gather,
-            )
+            fn = process_flows_wide_packed
         else:
-            fn = process_flows
-            fargs = (t, peer, ei, dp, pr)
-            fkw = dict(
-                ep_count=ep_count, levels=16, prefilter=pf_stage,
-                fused=v6_fused, row_override=ro, attrib=attrib,
-                rule_tab=rule_tab, n_rules=n_rules,
-                ident_gather=ident_gather,
-            )
+            fn = process_flows_packed
+            fkw.update(levels=16, fused=v6_fused)
+        fargs = (t, buf)
         if psample is not None:
             prof = self.profiler
             if prof is not None:
@@ -3538,8 +3652,8 @@ class DatapathPipeline:
                 # this (site, stable ladder shape), recorded once
                 prof.note_jit_cost(
                     "dispatch",
-                    (family, padded, pf_stage, ep_count, ro is not None,
-                     v6_fused, attrib, ident_gather),
+                    (family, padded, pf_stage, ep_count, rows, v6_fused,
+                     attrib, ident_gather),
                     fn, fargs, fkw,
                 )
         return fn(*fargs, **fkw)
@@ -3756,22 +3870,19 @@ class DatapathPipeline:
                     _metrics.jit_shape_buckets_total.inc(
                         {"site": "dispatch", "result": "miss"}
                     )
-            # each logical upload is one per-device slice transfer per
-            # mesh device under sharding (P("flows") splits dim 0)
+            # one packed upload a chunk, counted once per mesh device
+            # under sharding (each device receives its lane slice)
+            nmesh = 1 if flow_sharding is None else flow_sharding.mesh.size
             _metrics.device_transfers_total.inc(
-                {"direction": "h2d"},
-                (4.0 + (row_override is not None)) * len(spans) * ndev,
+                {"direction": "h2d"}, float(len(spans) * nmesh)
             )
             # byte-ledger sibling (policyd-prof): logical upload bytes
-            # — v4 packs to one u32 lane, v6 ships the raw int32
-            # bytes; shard slices sum to the full array, so no ×ndev
-            peer_w = 4 if family == 4 else peer_bytes.shape[1] * 4
+            # — int32 rows × lanes; shard slices sum to the full
+            # array, so no ×nmesh
+            rows = flow_rows(family, row_override is not None)
             _metrics.device_transfer_bytes_total.inc(
                 {"direction": "h2d"},
-                float(sum(
-                    p * (peer_w + 12 + (4 if row_override is not None else 0))
-                    for _, _, p in spans
-                )),
+                float(sum(4 * rows * p for _, _, p in spans)),
             )
             bt.mark(
                 padded=int(sum(p for _, _, p in spans)), chunks=len(spans)
@@ -3830,7 +3941,8 @@ class DatapathPipeline:
         exact = all(hi - lo == padded for lo, hi, padded in spans)
         return _Enqueued(chunks, spans, b, exact, ndev,
                          attrib=rule_tab is not None,
-                         staging=staging or (), psample=psample)
+                         staging=staging or (), psample=psample,
+                         ep_count=ep_count)
 
     def _dispatch_complete(
         self, enq: _Enqueued, bt=_NOOP_BATCH
@@ -3864,61 +3976,49 @@ class DatapathPipeline:
             # retry soundness argument in _finish_guarded relies on the
             # transient window preceding any host-state mutation
             _faults.hub.check(_faults.SITE_COMPLETE)
-        if self.tracer.active:
-            _metrics.device_transfers_total.inc(
-                {"direction": "d2h"},
-                (6.0 if enq.attrib else 3.0) * len(enq.chunks) * enq.ndev,
-            )
-            # byte-ledger sibling (policyd-prof): logical bytes the
-            # pull below actually moves (counters/hits only when exact
-            # — the inexact path never reads them). .nbytes on an
-            # un-pulled device array is metadata, no sync.
-            nb = 0
-            for ch in enq.chunks:
-                nb += int(ch[0].nbytes) + int(ch[1].nbytes)
-                if enq.attrib:
-                    nb += int(ch[3].nbytes) + int(ch[4].nbytes)
-                if enq.exact:
-                    nb += int(ch[2].nbytes)
-                    if enq.attrib:
-                        nb += int(ch[5].nbytes)
-            _metrics.device_transfer_bytes_total.inc(
-                {"direction": "d2h"}, float(nb)
-            )
         ps = enq.psample
         _pt0 = time.perf_counter() if ps is not None else 0.0
         with bt.phase("host_sync"):
+            # one pull a chunk: its lane words, counters and attribution
+            # arrive in one buffer, and the rest are views of it
+            parts = [
+                unpack_flow_result(np.asarray(ch), padded, enq.ep_count,
+                                   enq.attrib)
+                for (_lo, _hi, padded), ch in zip(enq.spans, enq.chunks)
+            ]
             b = enq.b
-            rule = l4x = hits = None
-            if len(enq.chunks) == 1:
-                ch = enq.chunks[0]
-                verdict = np.asarray(ch[0])[:b]
-                redirect = np.asarray(ch[1])[:b]
-                if enq.attrib:
-                    rule = np.asarray(ch[3])[:b]
-                    l4x = np.asarray(ch[4])[:b]
+            if len(parts) == 1:
+                words, rule = parts[0][0][:b], parts[0][2]
+                rule = None if rule is None else rule[:b]
             else:
-                verdict = np.empty(b, np.int8)
-                redirect = np.empty(b, bool)
-                if enq.attrib:
-                    rule = np.empty(b, np.int32)
-                    l4x = np.empty(b, bool)
-                for (lo, hi, _padded), ch in zip(enq.spans, enq.chunks):
-                    verdict[lo:hi] = np.asarray(ch[0])[: hi - lo]
-                    redirect[lo:hi] = np.asarray(ch[1])[: hi - lo]
-                    if enq.attrib:
-                        rule[lo:hi] = np.asarray(ch[3])[: hi - lo]
-                        l4x[lo:hi] = np.asarray(ch[4])[: hi - lo]
+                words = np.concatenate([
+                    p[0][: hi - lo] for (lo, hi, _), p in zip(enq.spans, parts)
+                ])
+                rule = None if not enq.attrib else np.concatenate([
+                    p[2][: hi - lo] for (lo, hi, _), p in zip(enq.spans, parts)
+                ])
+            verdict = (words & 0xFF).astype(np.int8)
+            redirect = (words & REDIRECT_BIT) != 0
+            l4x = (words & L4_COVERED_BIT) != 0 if enq.attrib else None
+            counters = hits = None
             if enq.exact:
-                counters = np.asarray(enq.chunks[0][2])
-                for ch in enq.chunks[1:]:
-                    counters = counters + np.asarray(ch[2])
-                if enq.attrib:
-                    hits = np.asarray(enq.chunks[0][5])
-                    for ch in enq.chunks[1:]:
-                        hits = hits + np.asarray(ch[5])
-            else:
-                counters = None
+                counters = parts[0][1]
+                hits = parts[0][3]
+                for p in parts[1:]:
+                    counters = counters + p[1]
+                    if enq.attrib:
+                        hits = hits + p[3]
+        if self.tracer.active:
+            # a result is replicated on a mesh: its pull reads one device
+            _metrics.device_transfers_total.inc(
+                {"direction": "d2h"}, float(len(enq.chunks))
+            )
+            # byte-ledger sibling (policyd-prof): the pulled results'
+            # logical bytes
+            _metrics.device_transfer_bytes_total.inc(
+                {"direction": "d2h"},
+                float(sum(int(ch.nbytes) for ch in enq.chunks)),
+            )
         if ps is not None:
             # sampled d2h edge: the residual pull wait (compute already
             # completed at the enqueue half's ready sandwich)
@@ -4607,7 +4707,7 @@ class DatapathPipeline:
         pad = _bucket(b) - b
         valid = np.zeros(b + pad, bool)
         valid[:b] = True
-        peer_bytes, ep_idx, dports, protos, sports, _ = _pad_flows(
+        peer_bytes, ep_idx, dports, protos, sports = _pad_flows(
             pad, peer_bytes, ep_idx, dports, protos, sports
         )
         peer = _pack_v4_u32(peer_bytes) if family == 4 else peer_bytes

@@ -322,9 +322,11 @@ jit_shape_buckets_total = registry.counter(
 )
 device_transfers_total = registry.counter(
     "cilium_tpu_device_transfers_total",
-    "Host↔device array transfers on traced dispatches (label: direction; "
-    "under VerdictSharding each logical transfer counts once per mesh "
-    "device — the slices/gathers actually issued)",
+    "Host↔device array transfers on traced dispatches (label: direction): "
+    "one h2d per packed verdict-chunk upload, counted once per mesh "
+    "device under VerdictSharding (each device receives its lane slice), "
+    "and one d2h per chunk result pull (replicated, so one device is read); "
+    "table patches count one h2d each",
 )
 pipeline_inflight_depth = registry.gauge(
     "cilium_tpu_pipeline_inflight_depth",

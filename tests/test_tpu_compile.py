@@ -43,6 +43,7 @@ L7_LANES = 16384       # top L7 lane rung
 L7_LEN = 128           # top L7 length rung
 L7_STATES = 127        # the stride-2 table exists only up to 2^23 / 257^2 states
 CT_BITS = 20           # device conntrack slots (make_state default)
+V6_NODES = 8192        # v6 stride-8 trie nodes (assumed: ~2k /128 peers)
 
 
 @pytest.fixture(scope="module")
@@ -145,6 +146,59 @@ def _process_flows_wide_2x2(topo):
     )
 
 
+def _process_flows_wide_packed(topo):
+    """The served v4 program: one packed [4, lanes] chunk in, one int32
+    result out."""
+    from cilium_tpu.datapath.pipeline import flow_rows, process_flows_wide_packed
+
+    a = _one_chip(topo)
+    return process_flows_wide_packed.lower(
+        _wide_tables(a), a((flow_rows(4, False), BATCH), jnp.int32),
+        ep_count=ENDPOINTS, prefilter=True,
+    )
+
+
+def _process_flows_wide_packed_2x2(topo):
+    """The served v4 program on the 2x2 flows x ident plan: the chunk's
+    lanes split over "flows", the result replicated."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from cilium_tpu.datapath.pipeline import flow_rows, process_flows_wide_packed
+
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("flows", "ident"))
+    table = _sds(NamedSharding(mesh, P()))
+    lanes = _sds(NamedSharding(mesh, P(None, "flows")))
+    rows = _sds(NamedSharding(mesh, P("ident", None)))
+    return process_flows_wide_packed.lower(
+        _wide_tables(table, rows), lanes((flow_rows(4, True), BATCH), jnp.int32),
+        ep_count=ENDPOINTS, prefilter=True, ident_gather=True,
+        out_sharding=NamedSharding(mesh, P()),
+    )
+
+
+def _process_flows_packed_v6(topo):
+    """The served v6 program (fused deny+identity walk): one packed
+    [19, lanes] chunk in, one int32 result out."""
+    from cilium_tpu.datapath.pipeline import (
+        DatapathTables, flow_rows, process_flows_packed,
+    )
+
+    a = _one_chip(topo)
+    i32 = jnp.int32
+    trie = lambda n: (a((n, 256), i32), a((n, 256), i32), a((2,), i32))  # noqa: E731
+    (pc, pi, pcm), (ic, ii, icm), (mc, mi, mcm) = trie(V6_NODES), trie(V6_NODES), trie(V6_NODES)
+    t = DatapathTables(
+        pf_child=pc, pf_info=pi, pf_common=pcm, ip_child=ic, ip_info=ii, ip_common=icm,
+        merged_child=mc, merged_info=mi, merged_common=mcm, world_row=a((), i32),
+        policymap=_policymap(a),
+    )
+    return process_flows_packed.lower(
+        t, a((flow_rows(6, False), BATCH), i32), ep_count=ENDPOINTS, levels=16,
+        prefilter=True, fused=True,
+    )
+
+
 def _sweep_device_matrix(topo):
     from cilium_tpu.ops.materialize import _MATRIX_NBLOCK, _sweep_device_matrix
     from cilium_tpu.ops.verdict import DevicePolicy, DeviceTables
@@ -215,6 +269,8 @@ def _ct_step(topo):
     _sweep_device_matrix, _dfa_pair_walk, _ct_step,
     _dfa_packed_walk("dfa_match_packed_pair", 257 * 257),
     _dfa_packed_walk("dfa_match_packed_fused", 256),
+    _process_flows_wide_packed, _process_flows_wide_packed_2x2,
+    _process_flows_packed_v6,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_compiles_for_v5e(chip, lower):
     lowered = lower(chip)
